@@ -8,8 +8,13 @@ report per phase (the README numbers in BASELINE.md come from this
 shape of run: 20 clients x 5000 requests).
 
 Usage:
-    python -m dbeel_tpu.server.run --dir /tmp/bb --shards 4 &
+    python -m dbeel_tpu.server.run --dir /tmp/bb --shards 4 \
+        --compaction-backend native &
     python blackbox_bench.py --clients 20 --requests 5000
+
+The operator starts the node(s).  Several nodes on one host all get
+``--compaction-backend native``: a chip belongs to one process, and
+every figure this generator has reported was taken on host merges.
 """
 
 import argparse
@@ -949,12 +954,7 @@ async def main_cas(args):
     acked increment, and the acked p99 of the WHOLE retry cycle (the
     price a real hot-key workload pays).  Correctness is asserted in
     passing: the hot counter's final value must equal total acked
-    increments.  --json-out writes the BENCH_r19.json artifact.
-
-    One opportunistic device_capture probe rides the phase (the
-    tunnel-proof benching discipline)."""
-    import subprocess
-
+    increments.  --json-out writes the BENCH_r19.json artifact."""
     from dbeel_tpu.errors import (
         CasConflict,
         CollectionAlreadyExists,
@@ -978,29 +978,6 @@ async def main_cas(args):
         "clients": args.clients,
         "value_size": args.value_size,
     }
-
-    probe = {}
-    if os.environ.get("DBEEL_BENCH_NO_PROBE"):
-        probe["skipped"] = True
-    else:
-        try:
-            env = dict(os.environ)
-            env.pop("JAX_PLATFORMS", None)
-            rc = subprocess.call(
-                [
-                    sys.executable, "device_capture.py",
-                    "--probe-timeout", "45",
-                ],
-                cwd=os.path.dirname(os.path.abspath(__file__)),
-                env=env,
-                timeout=900,
-            )
-            probe["rc"] = rc
-            probe["tunnel"] = "alive" if rc == 0 else "dead"
-        except Exception as e:  # pragma: no cover - best-effort
-            probe["error"] = str(e)[:200]
-            probe["tunnel"] = "dead"
-    report["device_probe"] = probe
 
     value = {"blob": "x" * args.value_size}
     # Fresh keys per run: expect_absent creates and the final-count
@@ -1162,13 +1139,8 @@ async def main_scan_filter_indexed(args):
     its non-indexed twin before its timing counts.  Acceptance:
     indexed keys-matched/s >= 10x scan-everything at 0.1%
     selectivity, read_amplification ~1.0 (index maintenance added
-    zero extra data reads), maintenance amplification reported.
-
-    One opportunistic device_capture probe rides the phase (the
-    tunnel-proof benching discipline): a wake persists
-    DEVICE_LAST_GOOD.json via bench.py's own artifact writer."""
+    zero extra data reads), maintenance amplification reported."""
     import shutil
-    import subprocess
     import tempfile
 
     import msgpack
@@ -1187,37 +1159,6 @@ async def main_scan_filter_indexed(args):
         "value_size": args.value_size,
         "selectivity": {},
     }
-
-    # One opportunistic device probe (one-shot device_capture.py: it
-    # probes, captures if the tunnel answers, and bench.py persists
-    # DEVICE_LAST_GOOD.json on a byte-identical capture).  The child
-    # must NOT inherit this process's JAX_PLATFORMS=cpu, or the probe
-    # trivially passes on the CPU backend and a full capture launches.
-    # DBEEL_BENCH_NO_PROBE skips it entirely: on a CPU-only CI runner
-    # the stripped-env probe would trivially pass on the cpu backend
-    # and launch a full (hour-scale) capture inside the smoke gate.
-    probe = {}
-    if os.environ.get("DBEEL_BENCH_NO_PROBE"):
-        probe["skipped"] = True
-    else:
-        try:
-            env = dict(os.environ)
-            env.pop("JAX_PLATFORMS", None)
-            rc = subprocess.call(
-                [
-                    sys.executable, "device_capture.py",
-                    "--probe-timeout", "45",
-                ],
-                cwd=os.path.dirname(os.path.abspath(__file__)),
-                env=env,
-                timeout=900,
-            )
-            probe["rc"] = rc
-            probe["tunnel"] = "alive" if rc == 0 else "dead"
-        except Exception as e:  # pragma: no cover - best-effort
-            probe["error"] = str(e)[:200]
-            probe["tunnel"] = "dead"
-    report["device_probe"] = probe
 
     tree = LSMTree.open_or_create(
         d + "/t",
@@ -1382,11 +1323,7 @@ async def main_watch(args):
     long-poll parks, per-collection wakeups) — not event fan-out.
     Phase C: the interference gate — the SAME closed-loop set
     workload as A with the 1024 idle watchers still parked.
-    Acceptance: goodput within 10%% of the no-watcher baseline.
-
-    One opportunistic device_capture probe rides the phase (the
-    tunnel-proof benching discipline)."""
-    import subprocess
+    Acceptance: goodput within 10%% of the no-watcher baseline."""
     import time as _time
 
     from dbeel_tpu.errors import CollectionAlreadyExists
@@ -1412,29 +1349,6 @@ async def main_watch(args):
         "value_size": args.value_size,
         "idle_poll": {"wait_ms": 1000, "interval_s": "6-10 jittered"},
     }
-
-    probe = {}
-    if os.environ.get("DBEEL_BENCH_NO_PROBE"):
-        probe["skipped"] = True
-    else:
-        try:
-            env = dict(os.environ)
-            env.pop("JAX_PLATFORMS", None)
-            rc = subprocess.call(
-                [
-                    sys.executable, "device_capture.py",
-                    "--probe-timeout", "45",
-                ],
-                cwd=os.path.dirname(os.path.abspath(__file__)),
-                env=env,
-                timeout=900,
-            )
-            probe["rc"] = rc
-            probe["tunnel"] = "alive" if rc == 0 else "dead"
-        except Exception as e:  # pragma: no cover - best-effort
-            probe["error"] = str(e)[:200]
-            probe["tunnel"] = "dead"
-    report["device_probe"] = probe
 
     # Pre-suspend the hot collection's native plane: one throwaway
     # watch chunk is enough (sticky), so phase A's writes take the

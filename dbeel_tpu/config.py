@@ -19,6 +19,11 @@ DEFAULT_REMOTE_SHARD_PORT = 20000
 DEFAULT_GOSSIP_PORT = 30000
 
 
+# Compaction backends whose merges run on the accelerator: the process
+# that uses one acquires the device (dbeel_tpu/device.py).
+DEVICE_BACKENDS = ("device", "device_full", "coalesced", "distributed")
+
+
 @dataclass
 class Config:
     name: str = "dbeel"
@@ -191,7 +196,8 @@ class Config:
     shards: int = 0  # 0 = one shard per online CPU core.
     # auto | device | distributed | coalesced | device_full | cpu |
     # heap | native.  auto → distributed on a multi-chip mesh, device on
-    # one accelerator, native on CPU-only hosts.
+    # one accelerator, native where JAX reports the cpu and under
+    # --processes (shard processes never touch JAX).
     compaction_backend: str = "auto"
     memtable_capacity: int = 0  # 0 = storage.DEFAULT_TREE_CAPACITY
     # sorted | hash (device flush sort) | arena (C++ rbtree arena)
@@ -557,7 +563,15 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def parse_args(argv: Optional[Sequence[str]] = None) -> Config:
-    ns = build_parser().parse_args(argv)
+    parser = build_parser()
+    ns = parser.parse_args(argv)
+    if ns.processes and ns.compaction_backend in DEVICE_BACKENDS:
+        parser.error(
+            f"--processes with --compaction-backend "
+            f"{ns.compaction_backend}: a chip belongs to one process "
+            "and per-shard processes cannot share it; the "
+            "single-process node is the device deployment"
+        )
     return Config(
         name=ns.name,
         seed_nodes=list(ns.seed_nodes),

@@ -14,7 +14,10 @@ from typing import Tuple
 import numpy as np
 
 from ..storage import columnar
-from ..storage.compaction import ColumnarMergeStrategy
+from ..storage.compaction import (
+    ColumnarMergeStrategy,
+    compaction_stats,
+)
 from .bitonic import device_merge_prefix_order, device_merge_sorted_runs
 
 
@@ -39,9 +42,14 @@ class DeviceMergeStrategy(ColumnarMergeStrategy):
     # SSTables warm in cache when a cache is supplied).  Larger merges
     # go through the O_DIRECT native pipeline, which handles tie-heavy
     # keyspaces internally (vectorized fixup) and declines (None) only
-    # when no native lib/jax or an equal-prefix group exceeds the
-    # kernel rows.
+    # when an equal-prefix group exceeds the kernel rows.
     PIPELINE_MIN_BYTES = 64 << 20
+
+    def __init__(self, mesh=None) -> None:
+        # A 1-D mesh shards the pipeline's launch batch over its
+        # devices (what ``auto`` passes on a multi-chip host);
+        # single-shot merges stay on one device either way.
+        self.mesh = mesh
 
     def merge(
         self,
@@ -56,16 +64,27 @@ class DeviceMergeStrategy(ColumnarMergeStrategy):
         O_DIRECT reads, per-partition kernel launches, C++ gather +
         O_DIRECT streaming writes, all stages overlapped); otherwise
         the single-shot path with per-run upload/read overlap."""
-        total = sum(getattr(s, "data_size", 0) for s in sources)
-        if total >= self.PIPELINE_MIN_BYTES:
-            from .pipeline import pipeline_merge
+        from .pipeline import max_partition_rows, pipeline_merge
 
+        total = sum(getattr(s, "data_size", 0) for s in sources)
+        # The single-shot kernel takes whole runs as its rows, so a
+        # merge of many tiny records can ask for more rows than one
+        # launch may hold however few its bytes: the pipeline cuts
+        # those into partitions too.
+        longest = max(
+            (getattr(s, "entry_count", 0) for s in sources), default=0
+        )
+        if (
+            total >= self.PIPELINE_MIN_BYTES
+            or longest > max_partition_rows(len(sources))
+        ):
             result = pipeline_merge(
                 sources,
                 dir_path,
                 output_index,
                 keep_tombstones,
                 bloom_min_size,
+                mesh=self.mesh,
                 throttle=self.throttle,
                 tombstone_drop_before=self.tombstone_drop_before,
             )
@@ -107,11 +126,13 @@ class DeviceMergeStrategy(ColumnarMergeStrategy):
                 cols.timestamp[perm],
                 self.tombstone_drop_before,
             )
-        return write_output_columnar(
+        result = write_output_columnar(
             cols, perm[keep], dir_path, output_index, cache,
             bloom_min_size, throttle=self.throttle,
             index_fields=self.index_fields,
         )
+        compaction_stats.note_path("single_shot")
+        return result
 
     def _refine(self, cols, perm):
         if len(cols) > 1:
@@ -146,6 +167,7 @@ class DeviceFullMergeStrategy(ColumnarMergeStrategy):
     shared 8-byte prefixes."""
 
     name = "device_full"
+    path = "device_full"
 
     def sort_and_dedup(
         self, cols: columnar.MergeColumns

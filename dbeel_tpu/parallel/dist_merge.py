@@ -27,12 +27,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # newer jax exports shard_map at top level...
-    from jax import shard_map
-except ImportError:  # ...older releases keep it in experimental
-    from jax.experimental.shard_map import shard_map
 
 from ..storage import columnar
 from ..ops import bitonic
@@ -172,8 +168,9 @@ def distributed_sort_dedup(
     capacity_factor: float = 2.0,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Multi-device merge: returns (perm, same) like
-    ops.merge.device_sort_dedup.  Falls back to the single-device kernel
-    if bucket skew overflows the exchange capacity."""
+    ops.merge.device_sort_dedup.  When bucket skew overflows the
+    exchange capacity the single-device kernel orders the rows instead
+    — a selection on the data, counted as ``distributed_overflow``."""
     n = len(cols)
     n_dev = mesh.devices.size
     if n == 0 or n_dev == 1:
@@ -185,7 +182,7 @@ def distributed_sort_dedup(
         stack, mesh=mesh, capacity=capacity, n_dev=n_dev
     )
     if int(np.asarray(overflow).sum()) > 0:
-        return _single_device_fallback(cols)
+        return _overflowed(cols)
     out = np.asarray(out)
     same = np.asarray(same)
     # Per-device blocks are disjoint ascending key ranges: concatenate
@@ -201,9 +198,17 @@ def distributed_sort_dedup(
     perm = np.concatenate(perms)
     same_np = np.concatenate(sames)
     if perm.size != n:
-        # Defensive: anything unexpected (shouldn't happen) → fallback.
-        return _single_device_fallback(cols)
+        # Rows went missing in the exchange without the overflow
+        # counter seeing them: same decline, same count.
+        return _overflowed(cols)
     return perm, same_np
+
+
+def _overflowed(cols: columnar.MergeColumns):
+    from ..storage.compaction import compaction_stats
+
+    compaction_stats.note_path("distributed_overflow")
+    return _single_device_fallback(cols)
 
 
 def _single_device_fallback(cols: columnar.MergeColumns):
@@ -221,6 +226,7 @@ def DistributedMergeStrategy(mesh: Mesh):
 
     class _DistributedMergeStrategy(ColumnarMergeStrategy):
         name = "distributed"
+        path = "distributed"
 
         # Mirrors DeviceMergeStrategy.PIPELINE_MIN_BYTES: big merges
         # take the partitioned native pipeline with the launch-batch
@@ -253,6 +259,7 @@ def DistributedMergeStrategy(mesh: Mesh):
                     bloom_min_size,
                     mesh=self.mesh,
                     throttle=self.throttle,
+                    tombstone_drop_before=self.tombstone_drop_before,
                 )
                 if result is not None:
                     return result

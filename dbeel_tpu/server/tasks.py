@@ -292,11 +292,12 @@ def _leading_zeros64(n: int) -> int:
 
 async def compact_tree(
     tree, compaction_factor: int, scheduler=None
-) -> None:
+) -> int:
     """Size-tiered grouping by size order (leading_zeros) with cascade
     merge of adjacent orders (compaction.rs:35-102).  Each merge is one
     background unit under the share scheduler: while serving is busy,
-    consecutive merges are spaced to the fg/bg share ratio."""
+    consecutive merges are spaced to the fg/bg share ratio.  Returns
+    the number of merges that completed."""
     indices_and_sizes = tree.sstable_indices_and_sizes()
 
     odd = [i for i, _ in indices_and_sizes if i % 2 != 0]
@@ -316,6 +317,7 @@ async def compact_tree(
         target = min(estimated, size_order)
         optimized.setdefault(target, []).extend(items)
 
+    merged = 0
     for i, items in enumerate(optimized.values()):
         if len(items) < MIN_COMPACTION_FACTOR or len(
             items
@@ -335,9 +337,26 @@ async def compact_tree(
                 await tree.compact(
                     indices, index_to_compact, keep_tombstones
                 )
+            merged += 1
         except Exception as e:
             log.error("failed to compact files: %s", e)
         index_to_compact += 2
+    return merged
+
+
+async def compact_until_settled(
+    tree, compaction_factor: int, scheduler=None
+) -> None:
+    """Passes of ``compact_tree`` until one merges nothing.  A pass
+    groups the tables it finds when it starts; tables flushed while
+    its merges ran — many of them where a merge first has to compile
+    its kernel for tens of seconds — wait for the next pass, and
+    without one the tree rests with that debt until some later flush
+    (which holds the governor at soft overload and parks every scan
+    chunk).  Each completed merge removes at least one table, so this
+    ends."""
+    while await compact_tree(tree, compaction_factor, scheduler):
+        pass
 
 
 async def run_compaction_loop(my_shard: MyShard) -> None:
@@ -357,7 +376,9 @@ async def run_compaction_loop(my_shard: MyShard) -> None:
     # Compact once on startup (crash may have left ungrouped files).
     await asyncio.gather(
         *[
-            compact_tree(t, compaction_factor, my_shard.scheduler)
+            compact_until_settled(
+                t, compaction_factor, my_shard.scheduler
+            )
             for t in trees
         ]
     )
@@ -378,7 +399,7 @@ async def run_compaction_loop(my_shard: MyShard) -> None:
         for i, fut in enumerate(listeners):
             if fut.done():
                 listeners[i] = trees[i].flush_done_event.listen()
-                await compact_tree(
+                await compact_until_settled(
                     trees[i], compaction_factor, my_shard.scheduler
                 )
 

@@ -27,6 +27,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..config import DEVICE_BACKENDS
 from . import checksums, columnar
 from .bloom import BloomFilter
 from .entry import (
@@ -49,6 +50,26 @@ class MergeResult:
     entry_count: int
     data_size: int
     wrote_bloom: bool
+
+
+# Every way a merge output gets produced.  Device paths: ``pipeline``
+# (ops/pipeline.py), ``single_shot`` (one prefix-kernel launch),
+# ``coalesced`` (server/coalescer.py batch launch), ``device_full``
+# and ``distributed`` (mesh sample sort); ``distributed_overflow``
+# counts those of the ``distributed`` outputs whose rows the
+# single-device kernel ordered after bucket skew overflowed the
+# exchange.  Host paths: ``native``, ``columnar``, ``heap``.
+MERGE_PATHS = (
+    "pipeline",
+    "single_shot",
+    "coalesced",
+    "device_full",
+    "distributed",
+    "distributed_overflow",
+    "native",
+    "columnar",
+    "heap",
+)
 
 
 class CompactionStats:
@@ -82,6 +103,34 @@ class CompactionStats:
         # read_amplification stays a pure data-plane measure; their
         # cost is reported as index_maintenance_amplification.
         self.index_bytes_written = 0
+        # Merge passes by the path that produced the output — how a
+        # client tells that the device did the work (and how often a
+        # data-dependent decline sent a merge elsewhere).
+        self.paths = {name: 0 for name in MERGE_PATHS}
+        # Big merges the pipeline declined on their data (prefix
+        # skew) and another device path then produced.
+        self.pipeline_declines = 0
+        # Merges between start and end right now (compile included),
+        # and merges that raised.
+        self.merges_running = 0
+        self.merges_failed = 0
+
+    def note_path(self, path: str) -> None:
+        """One merge output produced by ``path`` (a MERGE_PATHS name)."""
+        with self._lock:
+            self.paths[path] += 1
+
+    def note_merge_running(self, delta: int) -> None:
+        with self._lock:
+            self.merges_running += delta
+
+    def note_merge_failed(self) -> None:
+        with self._lock:
+            self.merges_failed += 1
+
+    def note_pipeline_decline(self) -> None:
+        with self._lock:
+            self.pipeline_declines += 1
 
     def note_merge(
         self, input_bytes: int, bytes_written: int
@@ -120,8 +169,10 @@ class CompactionStats:
             self.index_bytes_written += int(nbytes)
 
     def stats(self) -> dict:
+        from .. import device
         from . import native as native_mod
 
+        held = device.held() or {}
         with self._lock:
             amp = (
                 round(
@@ -149,6 +200,15 @@ class CompactionStats:
                 "read_amplification": amp,
                 "index_bytes_written": self.index_bytes_written,
                 "index_maintenance_amplification": idx_amp,
+                "paths": dict(self.paths),
+                "pipeline_declines": self.pipeline_declines,
+                "merges_running": self.merges_running,
+                "merges_failed": self.merges_failed,
+                # The device this process holds (None: it holds none
+                # and every merge above ran on the host).
+                "platform": held.get("platform"),
+                "device_kind": held.get("device_kind"),
+                "device_count": held.get("count"),
             }
         overlap = native_mod.read_overlap_stats()
         block["overlapped_read_passes"] = overlap[0]
@@ -301,6 +361,7 @@ class HeapMergeStrategy(CompactionStrategy):
                 idx_rows,
                 compact=True,
             )
+        compaction_stats.note_path("heap")
         return MergeResult(writer.entries_written, data_size, wrote_bloom)
 
 
@@ -309,6 +370,8 @@ class ColumnarMergeStrategy(CompactionStrategy):
     in (it overrides ``sort_and_dedup``)."""
 
     name = "columnar"
+    # MERGE_PATHS name counted per output of the template ``merge``.
+    path = "columnar"
 
     def sort_and_dedup(
         self, cols: columnar.MergeColumns
@@ -337,10 +400,12 @@ class ColumnarMergeStrategy(CompactionStrategy):
                 self.tombstone_drop_before,
             )
         order = perm[keep]
-        return write_output_columnar(
+        result = write_output_columnar(
             cols, order, dir_path, output_index, cache, bloom_min_size,
             throttle=self.throttle, index_fields=self.index_fields,
         )
+        compaction_stats.note_path(self.path)
+        return result
 
 
 def drop_tombstones_mask(
@@ -484,26 +549,15 @@ def _write_bloom(
     return blob
 
 
-def _jax_marked_dead(backend: str) -> bool:
-    """True when the server's startup probe (utils/jax_gate) found the
-    jax backend wedged/dead — device strategies must then degrade to
-    host merges instead of hanging the compaction worker."""
-    from ..utils.jax_gate import jax_marked_dead
-
-    if not jax_marked_dead():
-        return False
-    import logging
-
-    logging.getLogger(__name__).warning(
-        "compaction_backend=%s: jax backend marked dead by the "
-        "startup probe; using the host merge path",
-        backend,
-    )
-    return True
-
-
 def get_strategy(name: str) -> CompactionStrategy:
-    """Resolve a strategy by config name (config.compaction_backend)."""
+    """Resolve a strategy by config name (config.compaction_backend).
+
+    A device backend acquires the accelerator (``device.acquire()``)
+    and needs the native library for its pipeline; either failing
+    RAISES — a node configured for the device never carries on with a
+    host merge in its place.  ``auto`` is selection, not fallback: the
+    device family where the platform JAX reports is an accelerator,
+    ``native`` where it is the cpu."""
     if name == "heap":
         return HeapMergeStrategy()
     if name == "cpu" or name == "columnar":
@@ -516,70 +570,50 @@ def get_strategy(name: str) -> CompactionStrategy:
         if native_available():
             return NativeMergeStrategy()
         return ColumnarMergeStrategy()
-    if name == "device":
-        if _jax_marked_dead("device"):
-            return ColumnarMergeStrategy()
-        try:
+    if name in DEVICE_BACKENDS:
+        from .. import device
+        from . import native
+
+        held = device.acquire()
+        native.require()
+        if name == "device":
             from ..ops.device_compaction import DeviceMergeStrategy
-        except ImportError:
-            return ColumnarMergeStrategy()
-        return DeviceMergeStrategy()
-    if name == "coalesced":
-        if _jax_marked_dead("coalesced"):
-            return ColumnarMergeStrategy()
-        try:
-            from ..server.coalescer import CoalescedDeviceMergeStrategy
-        except ImportError:
-            return ColumnarMergeStrategy()
-        return CoalescedDeviceMergeStrategy()
-    if name == "device_full":
-        if _jax_marked_dead("device_full"):
-            return ColumnarMergeStrategy()
-        try:
+
+            return DeviceMergeStrategy()
+        if name == "device_full":
             from ..ops.device_compaction import DeviceFullMergeStrategy
-        except ImportError:
-            return ColumnarMergeStrategy()
-        return DeviceFullMergeStrategy()
-    if name == "distributed":
-        # Multi-chip sample sort over the whole mesh (BASELINE config 5).
-        # Falls back to the single-device kernel on a 1-chip host and to
-        # the host path when jax is unavailable — loudly, so an operator
-        # who configured the mesh backend can see it didn't engage.
-        if _jax_marked_dead("distributed"):
-            return ColumnarMergeStrategy()
-        try:
-            import jax
 
-            from ..parallel.dist_merge import DistributedMergeStrategy
-            from ..parallel.mesh import shard_mesh
+            return DeviceFullMergeStrategy()
+        if name == "coalesced":
+            from ..server.coalescer import CoalescedDeviceMergeStrategy
 
-            devices = jax.devices()
-        except Exception as e:
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "compaction_backend=distributed unavailable (%r); "
-                "falling back to the host columnar merge",
-                e,
-            )
-            return ColumnarMergeStrategy()
-        if len(devices) <= 1:
+            return CoalescedDeviceMergeStrategy()
+        # Multi-chip sample sort over the whole mesh (BASELINE config
+        # 5); one chip is the single-device strategy's.
+        if held["count"] <= 1:
             return get_strategy("device")
+        from ..parallel.dist_merge import DistributedMergeStrategy
+        from ..parallel.mesh import shard_mesh
+
         return DistributedMergeStrategy(shard_mesh())
     if name == "auto":
-        try:
-            if _jax_marked_dead("auto"):
-                raise RuntimeError("jax marked dead by startup probe")
-            import jax
+        from .. import device
 
-            platform = jax.default_backend()
-            n_devices = len(jax.devices())
-        except Exception:
-            platform = "cpu"
-            n_devices = 1
-        if platform != "cpu":
-            if n_devices > 1:
-                return get_strategy("distributed")
-            return get_strategy("device")
-        return get_strategy("native")
+        held = device.acquire()
+        if held["platform"] == "cpu":
+            return get_strategy("native")
+        if held["count"] > 1:
+            # Big merges shard the pipeline's launch batch over the
+            # mesh; flush-sized ones stay on one device.  Not the
+            # ``distributed`` sample sort: it is a full bitonic sort
+            # compiled anew for every distinct row count (a v5e 2x2
+            # compile: 20 s at 2^12 rows per device, 104 s at 2^18),
+            # and no two flush-sized merges have the same.
+            from . import native
+            from ..ops.device_compaction import DeviceMergeStrategy
+            from ..parallel.mesh import shard_mesh
+
+            native.require()
+            return DeviceMergeStrategy(mesh=shard_mesh())
+        return get_strategy("device")
     raise ValueError(f"unknown compaction backend {name!r}")

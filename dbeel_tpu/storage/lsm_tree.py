@@ -1306,9 +1306,12 @@ class LSMTree:
         # cache-free sstable handles (the page cache is loop-owned).
         # Strategies exposing merge_async (the coalescer) coordinate on
         # the loop instead and offload their heavy stages themselves.
+        from .compaction import compaction_stats
+
         inputs_nocache = [
             SSTable(self.dir_path, t.index, None) for t in inputs
         ]
+        compaction_stats.note_merge_running(1)
         try:
             throttle = getattr(self.strategy, "throttle", None)
             if throttle is not None:
@@ -1360,8 +1363,15 @@ class LSMTree:
             )
             if victim is not None:
                 self._handle_table_corruption(victim, e)
+            compaction_stats.note_merge_failed()
+            raise
+        except Exception:
+            # The compaction loop logs and carries on; the count is
+            # how a client sees that merges are failing.
+            compaction_stats.note_merge_failed()
             raise
         finally:
+            compaction_stats.note_merge_running(-1)
             for t in inputs_nocache:
                 t.close()
 

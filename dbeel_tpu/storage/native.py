@@ -698,6 +698,20 @@ def native_available() -> bool:
     return _load() is not None
 
 
+def require() -> ctypes.CDLL:
+    """The native library, built from ``native/src`` on first use
+    (``native/build/`` is not committed).  Raises when it cannot be
+    built or loaded: the device pipeline has no other reader, decoder
+    or gather-writer, so a device backend must not start without it."""
+    lib = _load()
+    if lib is None:
+        raise RuntimeError(
+            "the native library could not be built or loaded from "
+            f"{_NATIVE_DIR} (needs make and a C++17 compiler)"
+        )
+    return lib
+
+
 def load_if_built() -> Optional[ctypes.CDLL]:
     """Return the lib only if already built — never runs make (safe to
     call from latency-sensitive / event-loop contexts).  An explicit
@@ -1131,4 +1145,7 @@ class NativeMergeStrategy(CompactionStrategy):
                 compact=True,
             )
 
+        from .compaction import compaction_stats
+
+        compaction_stats.note_path("native")
         return MergeResult(int(n_out), int(data_size), wrote_bloom)

@@ -20,8 +20,8 @@ staged snapshot:
   set (ints beyond 2^53, byte values that the S dtype would alias)
   is re-evaluated through the golden scalar path, so the combined
   mask is byte-identical to the golden walk on EVERY input, not just
-  typical ones.  Numeric float64 leaves can run on the jitted device
-  twins (ops/query_kernels.py) when that gate is open.
+  typical ones.  Numeric leaves run on the exact device lane
+  (ops/query_kernels.py) where the process holds an accelerator.
 * ``agg_partial_for(stage, positions, agg)`` — columnar aggregate
   reduction over accepted rows only: exact int-lane sums, exact
   Shewchuk float partials, first-achiever min/max, group-by-key-
@@ -72,6 +72,7 @@ class FieldCol:
         "fix",
         "fixvals",
         "valid",
+        "staged",
     )
 
     def __init__(self, n: int, width: int) -> None:
@@ -86,6 +87,16 @@ class FieldCol:
         self.fix = np.zeros(n, dtype=bool)
         self.fixvals: dict = {}
         self.valid = np.zeros(n, dtype=bool)
+        self.staged: Optional[query_kernels.StagedColumn] = None
+
+    def device_column(self) -> query_kernels.StagedColumn:
+        """The numeric lane staged for the device mask kernels, built
+        on first use (one conversion pass per column lifetime)."""
+        if self.staged is None:
+            self.staged = query_kernels.StagedColumn(
+                self.f64, self.is_num
+            )
+        return self.staged
 
     def typed_at(self, p: int) -> Any:
         """The exact typed value at row p (None = no comparable
@@ -318,12 +329,11 @@ def _num_cmp_mask(
             )
             mask[r] = Q._leaf_cmp(x, op, operand)
         return mask
-    dev = query_kernels.eval_cmp_f64(
-        col.f64, col.is_num, float(operand), op
-    )
-    if dev is not None:
+    if query_kernels.serves(col.is_num.size):
         counters["device"] += 1
-        return dev
+        return query_kernels.eval_cmp(
+            col.device_column(), float(operand), op
+        )
     counters["host"] += 1
     return _NP_CMP[op](col.f64, float(operand)) & col.is_num
 
@@ -379,19 +389,13 @@ def _field_leaf_mask(
         big = (
             isinstance(lo, int) and abs(lo) > _F53
         ) or (isinstance(hi, int) and abs(hi) > _F53)
-        dev = (
-            None
-            if big
-            else query_kernels.eval_range_f64(
-                col.f64,
-                col.is_num,
+        if not big and query_kernels.serves(col.is_num.size):
+            counters["device"] += 1
+            mask = query_kernels.eval_range(
+                col.device_column(),
                 None if lo is None else float(lo),
                 None if hi is None else float(hi),
             )
-        )
-        if dev is not None:
-            counters["device"] += 1
-            mask = dev
         elif big:
             n = col.is_num.size
             mask = np.zeros(n, dtype=bool)

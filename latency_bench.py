@@ -13,6 +13,11 @@ evidence (odd-index output present).  Usage:
 
     python latency_bench.py [--keys 10000000] [--runs 8] \
         [--backend native] [--port 12600] [--duration 8]
+
+Every node is started with an explicit ``--compaction-backend``
+(``--backend``, default ``native``): the cluster shape starts several
+nodes on this host, a chip belongs to one process, and host merges are
+what this bench has measured all along.
 """
 
 import argparse

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import asyncio
 import itertools
+import os
 from typing import List, Optional
 
 from dbeel_tpu.config import Config
@@ -20,20 +21,49 @@ from dbeel_tpu.server.shard import MyShard
 
 _port_block = itertools.count(0)
 
+# Port plan: 64 blocks of 192 ports (db / remote / gossip sub-blocks
+# of 64) from 3700 up to 15988.  Each pytest-xdist worker owns 8 of
+# them, so workers running test files side by side never open the
+# same listener; without xdist the process is worker 0.
+_PORT_BASE = 3700
+_BLOCK_PORTS = 192
+_BLOCKS_PER_WORKER = 8
+_MAX_WORKERS = 8
+
+
+def _worker_index() -> int:
+    """This process's xdist worker number (``gw3`` -> 3), 0 without
+    xdist."""
+    worker = os.environ.get("PYTEST_XDIST_WORKER", "")
+    digits = "".join(ch for ch in worker if ch.isdigit())
+    index = int(digits) if digits else 0
+    if index >= _MAX_WORKERS:
+        raise RuntimeError(
+            f"the harness has port blocks for {_MAX_WORKERS} xdist "
+            f"workers; {worker!r} would share another worker's"
+        )
+    return index
+
+
+def port_block(n: int, worker: Optional[int] = None) -> int:
+    """First port of this worker's ``n``-th block (cycling through the
+    worker's own blocks: tests of one worker run one after another,
+    so a reused block only ever meets closed listeners)."""
+    if worker is None:
+        worker = _worker_index()
+    slot = worker * _BLOCKS_PER_WORKER + n % _BLOCKS_PER_WORKER
+    return _PORT_BASE + slot * _BLOCK_PORTS
+
 
 def make_config(tmp_dir: str, **kw) -> Config:
     """Fresh config with a unique port block (peace between tests).
 
-    Every listen port stays BELOW the container's ephemeral range
-    (/proc/sys/net/ipv4/ip_local_port_range starts at 16000 here):
-    the old +20000/+40000 scheme put the remote and gossip listeners
-    right inside it, so any outgoing connection's kernel-chosen
-    source port could squat a later test's listener — observed as a
-    mid-suite EADDRINUSE "shard task died during startup" flake.
-    26 blocks of 192 ports (db / remote / gossip sub-blocks of 64)
-    cycle; tier-1 runs tests sequentially (-p no:xdist), so reuse 26
-    tests later only ever meets closed listeners."""
-    block = 11000 + (next(_port_block) % 26) * 192
+    Every listen port stays below 16000, out of the kernel's range of
+    source ports for outgoing connections: a listener inside that
+    range can be squatted by any connection's source port, which
+    showed as a mid-suite EADDRINUSE "shard task died during
+    startup"."""
+    block = port_block(next(_port_block))
     defaults = dict(
         name="dbeel-test",
         dir=f"{tmp_dir}/db",

@@ -95,8 +95,7 @@ def test_pack_jobs_vmap_shape_and_dryrun_parity(tmp_dir):
     """The vmap-ready packing (ISSUE 15): pack_jobs pads every job to
     one common pow2 (K, P) stack — the single compiled batch shape —
     and the coalesced permutation per job equals the
-    DeviceMergeStrategy twin's (executed via the CPU path today, the
-    dryrun-parity contract for a future device wake)."""
+    DeviceMergeStrategy twin's (executed on the tests' CPU backend)."""
     import numpy as np
 
     from dbeel_tpu.ops.device_compaction import DeviceMergeStrategy

@@ -59,7 +59,6 @@ def _start(cfg, log_path):
             if os.environ.get("PYTHONPATH")
             else ""
         ),
-        "DBEEL_JAX_PROBED": "fail",
     }
     # Popen dups the fd; close ours right after so nothing leaks.
     log_fd = os.open(
@@ -81,6 +80,10 @@ def _start(cfg, log_path):
             str(cfg.gossip_port),
             "--shards",
             "1",
+            # This drill is about the WAL, not the merge backend:
+            # host merges keep JAX out of every restart.
+            "--compaction-backend",
+            "native",
             "--wal-sync",
             "--memtable-capacity",
             "48",

@@ -1047,51 +1047,157 @@ def test_vectorized_filter_byte_identical_to_golden(tmp_dir):
     run(main(), 120)
 
 
-def test_device_kernel_parity_and_last_good_artifact(tmp_dir):
-    # The jitted device twins (forced onto the jax CPU backend) must
-    # agree with the numpy lane bit-for-bit, and a successful device
-    # evaluation must persist the working config to the
-    # DEVICE_LAST_GOOD artifact (the device-capture discipline).
-    import importlib
-    import json
-    import os
+def _adversarial_f64():
+    # One definition, shared with the chip's own check of the lane.
+    from chip_smoke import adversarial_f64
 
+    return adversarial_f64()
+
+
+@pytest.mark.parametrize("op", ["==", "!=", "<", "<=", ">", ">="])
+def test_device_filter_lane_is_exact_cmp(op, monkeypatch):
+    # The jitted lane (forced onto the jax CPU backend) returns the
+    # numpy float64 mask bit for bit — including 16777217.0 >
+    # 16777216.0, which a float32 compare answers False.
     import numpy as np
 
     import dbeel_tpu.ops.query_kernels as qk
 
-    artifact = tmp_dir + "/DEVICE_LAST_GOOD.json"
-    os.environ["DBEEL_QUERY_DEVICE"] = "cpu_ok"
-    os.environ["DBEEL_DEVICE_LAST_GOOD"] = artifact
-    importlib.reload(qk)
-    try:
-        assert qk.available()
-        rng = np.random.default_rng(7)
-        vals = rng.normal(size=8192).astype(np.float64)
-        valid = rng.random(8192) < 0.8
-        for op in ("==", "!=", "<", "<=", ">", ">="):
-            dev = qk.eval_cmp_f64(vals, valid, 0.25, op)
-            assert dev is not None
-            host = {
-                "==": vals == 0.25,
-                "!=": vals != 0.25,
-                "<": vals < 0.25,
-                "<=": vals <= 0.25,
-                ">": vals > 0.25,
-                ">=": vals >= 0.25,
-            }[op] & valid
-            assert (dev == host).all(), op
-        dev = qk.eval_range_f64(vals, valid, -0.5, 0.5)
-        host = valid & (vals >= -0.5) & (vals < 0.5)
-        assert (dev == host).all()
-        with open(artifact) as f:
-            data = json.load(f)
-        assert data["query_filter"]["platform"] == "cpu"
-        assert data["query_filter"]["rows"] >= 4096
-    finally:
-        os.environ.pop("DBEEL_QUERY_DEVICE", None)
-        os.environ.pop("DBEEL_DEVICE_LAST_GOOD", None)
-        importlib.reload(qk)
+    monkeypatch.setenv("DBEEL_QUERY_DEVICE", "cpu_ok")
+    special, vals, valid = _adversarial_f64()
+    col = qk.StagedColumn(vals, valid)
+    assert qk.serves(col.n)
+    host_op = {
+        "==": np.equal, "!=": np.not_equal, "<": np.less,
+        "<=": np.less_equal, ">": np.greater, ">=": np.greater_equal,
+    }[op]
+    with np.errstate(invalid="ignore"):
+        for operand in [*special.tolist(), 0.25, 3.0]:
+            dev = qk.eval_cmp(col, operand, op)
+            host = host_op(vals, operand) & valid
+            assert dev.shape == host.shape
+            assert (dev == host).all(), (operand, op)
+    if op == ">":
+        at = int(np.flatnonzero(vals == 16777217.0)[0])
+        probe = qk.StagedColumn(
+            np.full(qk.MIN_DEVICE_ROWS, vals[at]),
+            np.ones(qk.MIN_DEVICE_ROWS, dtype=bool),
+        )
+        assert qk.eval_cmp(probe, 16777216.0, ">").all()
+
+
+def test_device_filter_lane_is_exact_range(monkeypatch):
+    import numpy as np
+
+    import dbeel_tpu.ops.query_kernels as qk
+
+    monkeypatch.setenv("DBEEL_QUERY_DEVICE", "cpu_ok")
+    _special, vals, valid = _adversarial_f64()
+    col = qk.StagedColumn(vals, valid)
+    with np.errstate(invalid="ignore"):
+        for lo in (None, -0.5, 0.0, -0.0, 16777217.0, np.nan):
+            for hi in (None, 0.5, 16777217.0, np.inf, np.nan):
+                if lo is None and hi is None:
+                    continue
+                host = valid.copy()
+                if lo is not None:
+                    host &= vals >= lo
+                if hi is not None:
+                    host &= vals < hi
+                dev = qk.eval_range(col, lo, hi)
+                assert (dev == host).all(), (lo, hi)
+
+
+def test_device_filter_lane_opens_only_on_a_held_accelerator(
+    monkeypatch,
+):
+    # No probe and no JAX from the serving path: the lane follows what
+    # the process acquired (or the tests' explicit force).
+    import dbeel_tpu.ops.query_kernels as qk
+    from dbeel_tpu import device
+
+    monkeypatch.delenv("DBEEL_QUERY_DEVICE", raising=False)
+    monkeypatch.setattr(device, "_held", None)
+    assert not qk.available()
+    monkeypatch.setattr(
+        device,
+        "_held",
+        {"platform": "cpu", "device_kind": "cpu", "count": 8},
+    )
+    assert not qk.available()
+    monkeypatch.setattr(
+        device,
+        "_held",
+        {"platform": "tpu", "device_kind": "TPU v5 lite", "count": 1},
+    )
+    assert qk.available() and qk.serves(qk.MIN_DEVICE_ROWS)
+    assert not qk.serves(qk.MIN_DEVICE_ROWS - 1)
+    monkeypatch.setenv("DBEEL_QUERY_DEVICE", "off")
+    assert not qk.available()
+
+
+def test_staged_filter_through_device_lane_matches_golden(
+    tmp_dir, monkeypatch
+):
+    # End to end: a tree big enough for the device lane (>= 4096
+    # staged rows) holding integers above 2^24 answers a predicate
+    # whose operand float32 cannot hold exactly like the golden
+    # per-entry walk, and the evaluation is counted as a device one.
+    import dbeel_tpu.storage.scan_stage as ss
+    from dbeel_tpu import query as Q
+    from dbeel_tpu.storage.lsm_tree import LSMTree
+
+    monkeypatch.setenv("DBEEL_QUERY_DEVICE", "cpu_ok")
+    base = 1 << 24
+    n = 5000
+
+    async def main():
+        tree = LSMTree.open_or_create(tmp_dir + "/t", capacity=8192)
+        for i in range(n):
+            await tree.set_with_timestamp(
+                msgpack.packb(f"k{i:05d}"),
+                msgpack.packb({"big": base + i, "frac": i + 0.1}),
+                1000 + i,
+            )
+        await tree.flush()
+
+        async def count(where):
+            total, sa, paths = 0, None, set()
+            while True:
+                (
+                    es, more, cover, _sr, _sb, _partial, path,
+                ) = await tree.scan_filter_page(
+                    0, 0, sa, None, 1 << 20, 1 << 26, True,
+                    where, None, Q.MODE_DROP,
+                )
+                total += len(es)
+                paths.add(path)
+                if not more:
+                    return total, paths
+                sa = cover
+
+        for where, want in (
+            (["cmp", "big", ">", base + 1], n - 2),
+            (["cmp", "big", "==", base + 1], 1),
+            (["range", "big", base + 1, base + 3], 2),
+            (["cmp", "frac", "<=", 7.1], 8),
+        ):
+            where = Q.validate_where(where)
+            got, paths = await count(where)
+            assert got == want, where
+            assert paths == {"device"}, (where, paths)
+            old = ss.MIN_VECTORIZED_ENTRIES
+            ss.MIN_VECTORIZED_ENTRIES = 10**9
+            tree._drop_scan_stage()
+            try:
+                golden, _ = await count(where)
+            finally:
+                ss.MIN_VECTORIZED_ENTRIES = old
+                tree._drop_scan_stage()
+            assert golden == want, where
+        tree.close()
+
+    run(main(), 120)
 
 
 def test_traced_filtered_scan_marks_filter_stage(tmp_dir):
