@@ -17,8 +17,10 @@ serve   one node through ``python -m dbeel_tpu.server.run`` (2 shards, one
         it loads YCSB-shaped documents (10 fields x 100 B plus one integer
         above 2^24), reads a seeded sample back against a model, overwrites
         and deletes some, checks ``count()``, checks filtered ``count`` and
-        ``scan`` whose operands float32 cannot hold, and reads
-        ``get_stats`` to see that the device did the merges and the masks.
+        ``scan`` whose operands float32 cannot hold (on a client stamped
+        ``interactive``), reports what one unstamped count met from the
+        governor, and reads ``get_stats`` to see that the device did the
+        merges and the masks.
 major   the library surface at BASELINE config 2 (8 runs, 16 B keys, 64 B
         values): ``get_strategy("device")`` against ``get_strategy(
         "native")``, SHA-256 of the output triplet equal; then the filter
@@ -62,6 +64,7 @@ PORT_BLOCKS = range(17000, 19000, 16)
 LOAD_BUDGET_S = 300.0
 IDLE_BUDGET_S = 540.0
 SCAN_BUDGET_S = 240.0
+PROBE_BUDGET_S = 45.0
 PIPELINE_MIN_BYTES = 64 << 20  # DeviceMergeStrategy.PIPELINE_MIN_BYTES
 
 _MASK = (1 << 64) - 1
@@ -73,6 +76,16 @@ def say(msg: str) -> None:
 
 class SmokeFailure(Exception):
     pass
+
+
+def sizes(tiny: bool, chips: int) -> tuple:
+    """(documents the serve phase loads, keys the merge phase builds):
+    a function of the two options alone, so the parent and its
+    children agree without handing numbers to each other."""
+    if chips == 4:
+        # ~1M keys: what the sample sort's compile time allows.
+        return 0, 32_768 if tiny else 1 << 20
+    return (12_000, 40_000) if tiny else (1_000_000, 10_000_000)
 
 
 def check(cond, what: str) -> None:
@@ -163,7 +176,7 @@ def run_child(phase: str, args, work: str) -> dict:
     argv = [
         sys.executable, os.path.abspath(__file__),
         "--child", phase, "--work", work,
-        "--seed", str(args.seed), "--keys", str(args.keys),
+        "--seed", str(args.seed), "--chips", str(args.chips),
     ]
     if args.tiny:
         argv.append("--tiny")
@@ -247,19 +260,24 @@ def compile_lines(log_path: str) -> list:
     return out
 
 
+def filter_operand(docs: Docs, loaded: int, version, deleted):
+    """(n of every document, which are live, the operand): a stored
+    value, odd and above 2^25, which float32 would move two or three
+    integers away."""
+    live = ~deleted
+    n_all = docs.n_of(np.arange(loaded), version)
+    odd = np.flatnonzero(live & (n_all % 2 == 1) & (n_all > 1 << 25))
+    return n_all, live, int(n_all[odd[len(odd) // 2]])
+
+
 async def scan_checks(col, docs: Docs, loaded: int, version, deleted):
     """count(), and a filtered count and two filtered scans whose
     operands float32 cannot hold, against the model."""
-    live = ~deleted
+    n_all, live, t_val = filter_operand(docs, loaded, version, deleted)
     check(
         await col.count() == int(live.sum()),
         f"count() == {int(live.sum())} (the model)",
     )
-    n_all = docs.n_of(np.arange(loaded), version)
-    odd = np.flatnonzero(live & (n_all % 2 == 1) & (n_all > 1 << 25))
-    # An operand that IS a stored value, odd and above 2^25: float32
-    # would move it two or three integers away.
-    t_val = int(n_all[odd[len(odd) // 2]])
     check(
         float(np.float32(t_val)) != float(t_val),
         f"filter operand {t_val} is not exact in float32",
@@ -292,6 +310,79 @@ async def scan_checks(col, docs: Docs, loaded: int, version, deleted):
         got_rng == want_rng,
         f"scan({lo_v} <= n < {hi_v}) returns the model's "
         f"{len(want_rng)} keys",
+    )
+
+
+async def shard_stats(client, db_port: int) -> dict:
+    """get_stats of both shards, by port (scan and overload counters
+    are per shard; compaction's are the process's)."""
+    return {
+        port: await client.get_stats("127.0.0.1", port)
+        for port in (db_port, db_port + 1)
+    }
+
+
+def scan_counters(shards: dict) -> dict:
+    out = {}
+    for shard in shards.values():
+        for name, value in shard["scan"]["filter"].items():
+            out[name] = out.get(name, 0) + value
+        for name in ("chunks", "paced", "paced_s"):
+            out[name] = out.get(name, 0) + shard["scan"][name]
+    return out
+
+
+async def default_client_probe(
+    client, db_port: int, docs: Docs, loaded, version, deleted, before
+) -> None:
+    """One filtered count as an ordinary client sends it — unstamped,
+    so batch-class — with what the node's governor made of it.  A
+    report, not a check of speed: the governor's reading of a resting
+    node is the QoS plane's policy, and this script only shows it.  The
+    answer, where one comes inside the budget, is checked."""
+    for port, shard in (await shard_stats(client, db_port)).items():
+        say(
+            f"  shard :{port} at rest: overload.signals="
+            f"{json.dumps(shard['overload']['signals'], sort_keys=True)} "
+            f"batch-class level="
+            f"{shard['qos']['classes']['batch']['level']}"
+        )
+    n_all, live, t_val = filter_operand(docs, loaded, version, deleted)
+    plain = await DbeelClient.from_seed_nodes(
+        [("127.0.0.1", db_port)], op_deadline_s=120.0
+    )
+    t0 = time.time()
+    try:
+        got = await asyncio.wait_for(
+            plain.collection("usertable").count(
+                filter=["cmp", "n", ">=", t_val]
+            ),
+            PROBE_BUDGET_S,
+        )
+    except asyncio.TimeoutError:
+        got = None
+    finally:
+        plain.close()
+    after = scan_counters(await shard_stats(client, db_port))
+    met = (
+        f"{after['chunks'] - before['chunks']} chunks, "
+        f"{after['paced'] - before['paced']} of them paced for "
+        f"{after['paced_s'] - before['paced_s']:.1f}s"
+    )
+    if got is None:
+        say(
+            f"PACED: default (batch-class) client: count(n >= {t_val}) "
+            f"not done in {PROBE_BUDGET_S:.0f}s ({met}); the interactive "
+            "client's same count is checked above"
+        )
+        return
+    say(
+        f"  default (batch-class) client: count(n >= {t_val}) in "
+        f"{time.time() - t0:.1f}s wall ({met}) [smoke]"
+    )
+    check(
+        got == int((live & (n_all >= t_val)).sum()),
+        "the default client's filtered count equals the model",
     )
 
 
@@ -414,10 +505,11 @@ async def serve_checks(
             f"{json.dumps(comp, sort_keys=True)}"
         )
         # ---- count and filtered count/scan --------------------------
-        # Stamped interactive: an unstamped scan is batch-class, and a
-        # resting tree of more than 8 tables (half of
-        # --overload-compaction-debt) reads as soft overload for that
-        # class, which parks every one of its chunks for 2 s.
+        # Stamped interactive.  An unstamped scan is batch-class, the
+        # class the governor paces first, and a freshly loaded node
+        # rests above that class's soft bars (memtable fill over 42.5 %,
+        # or more than 8 tables): default_client_probe below shows what
+        # such a scan meets.
         scans = await DbeelClient.from_seed_nodes(
             [("127.0.0.1", db_port)],
             op_deadline_s=120.0,
@@ -432,8 +524,7 @@ async def serve_checks(
                 SCAN_BUDGET_S,
             )
         except asyncio.TimeoutError:
-            for port in (db_port, db_port + 1):
-                shard = await client.get_stats("127.0.0.1", port)
+            for port, shard in (await shard_stats(client, db_port)).items():
                 say(
                     f"  shard :{port} overload={json.dumps(shard['overload'])} "
                     f"scan={json.dumps(shard['scan'])}"
@@ -444,17 +535,12 @@ async def serve_checks(
             )
         finally:
             scans.close()
-        stats = await client.get_stats("127.0.0.1", db_port)
-        comp = stats["compaction"]
-        # Scan counters are per shard (compaction's are the process's).
-        filt = {}
-        for port in (db_port, db_port + 1):
-            shard = await client.get_stats("127.0.0.1", port)
-            for name, value in shard["scan"]["filter"].items():
-                filt[name] = filt.get(name, 0) + value
-            for name in ("chunks", "paced", "paced_s"):
-                filt[name] = filt.get(name, 0) + shard["scan"][name]
+        comp = (await client.get_stats("127.0.0.1", db_port))["compaction"]
+        filt = scan_counters(await shard_stats(client, db_port))
         say(f"  scan.filter, both shards: {json.dumps(filt, sort_keys=True)}")
+        await default_client_probe(
+            client, db_port, docs, loaded, version, deleted, filt
+        )
         return held, comp, filt, loaded
     finally:
         client.close()
@@ -474,6 +560,7 @@ def _node_preexec() -> None:
 def phase_serve(args, work: str, rehearsal: bool) -> dict:
     say("== serve ==")
     docs = Docs(args.seed)
+    n_docs, _keys = sizes(args.tiny, args.chips)
     node_dir = os.path.join(work, "node")
     log_path = os.path.join(work, "node.log")
     db_port = free_port_block()
@@ -508,7 +595,7 @@ def phase_serve(args, work: str, rehearsal: bool) -> dict:
         # Cold start: JAX initialises and native/ is built from source.
         wait_port(db_port + 1, proc, 300)
         held, comp, filt, loaded = asyncio.run(
-            serve_checks(args, docs, args.docs, rehearsal, db_port)
+            serve_checks(args, docs, n_docs, rehearsal, db_port)
         )
         proc.send_signal(signal.SIGINT)
         rc = proc.wait(timeout=120)
@@ -692,7 +779,7 @@ def child_major(args, work: str) -> dict:
     say(
         f"  device: {held}; compile cache at {device.compile_cache_dir()}"
     )
-    keys = args.keys
+    keys = sizes(args.tiny, args.chips)[1]
     if args.tiny:
         # Steered here, not by an option of the program: the tiny input
         # must still take the pipeline.
@@ -753,7 +840,7 @@ def child_mesh(args, work: str) -> dict:
     check(held["count"] == 4, "the process holds four devices")
     if args.tiny:
         DeviceMergeStrategy.PIPELINE_MIN_BYTES = 1 << 20
-    keys = args.keys
+    keys = sizes(args.tiny, args.chips)[1]
     indices = bench.build_runs(work, keys, 8, seed=args.seed)
     mesh = shard_mesh()
 
@@ -889,19 +976,10 @@ def main() -> int:
                     help="tiny sizes (the CPU rehearsal of tests/)")
     ap.add_argument("--rehearsal", action="store_true",
                     help="a cpu run is expected; the result says so")
-    ap.add_argument("--docs", type=int, default=None)
-    ap.add_argument("--keys", type=int, default=None)
     ap.add_argument("--child", choices=("major", "mesh"),
                     help=argparse.SUPPRESS)
     ap.add_argument("--work", help=argparse.SUPPRESS)
     args = ap.parse_args()
-    if args.docs is None:
-        args.docs = 12_000 if args.tiny else 1_000_000
-    if args.keys is None:
-        if args.chips == 4:
-            args.keys = 32_768 if args.tiny else 1 << 20
-        else:
-            args.keys = 40_000 if args.tiny else 10_000_000
 
     if args.child:
         try:
@@ -912,7 +990,12 @@ def main() -> int:
         print(json.dumps(report), flush=True)
         return 0 if report["ok"] else 1
 
-    say(f"chip_smoke: {vars(args)}")
+    docs, keys = sizes(args.tiny, args.chips)
+    say(
+        f"chip_smoke: chips={args.chips} seed={args.seed} "
+        f"tiny={args.tiny} rehearsal={args.rehearsal}; "
+        f"{docs} documents, {keys} keys"
+    )
     work = tempfile.mkdtemp(prefix="dbeel_smoke_")
     device = None
     try:

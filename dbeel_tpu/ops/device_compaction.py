@@ -60,10 +60,36 @@ class DeviceMergeStrategy(ColumnarMergeStrategy):
         keep_tombstones,
         bloom_min_size,
     ):
-        """Partitioned native pipeline for big merges (ops/pipeline.py:
-        O_DIRECT reads, per-partition kernel launches, C++ gather +
-        O_DIRECT streaming writes, all stages overlapped); otherwise
-        the single-shot path with per-run upload/read overlap."""
+        """Partitioned native pipeline for big merges; otherwise the
+        single-shot path with per-run upload/read overlap."""
+        result = self.merge_pipeline(
+            sources, dir_path, output_index, keep_tombstones,
+            bloom_min_size,
+        )
+        if result is not None:
+            return result
+        return self._merge_single_shot(
+            sources,
+            dir_path,
+            output_index,
+            cache,
+            keep_tombstones,
+            bloom_min_size,
+        )
+
+    def merge_pipeline(
+        self,
+        sources,
+        dir_path,
+        output_index,
+        keep_tombstones,
+        bloom_min_size,
+    ):
+        """The one place a merge is sent to the partitioned native
+        pipeline (ops/pipeline.py: O_DIRECT reads, per-partition kernel
+        launches, C++ gather + O_DIRECT streaming writes, all stages
+        overlapped).  None where the merge is below the threshold, or
+        the pipeline declines on the data (counted there)."""
         from .pipeline import max_partition_rows, pipeline_merge
 
         total = sum(getattr(s, "data_size", 0) for s in sources)
@@ -75,28 +101,19 @@ class DeviceMergeStrategy(ColumnarMergeStrategy):
             (getattr(s, "entry_count", 0) for s in sources), default=0
         )
         if (
-            total >= self.PIPELINE_MIN_BYTES
-            or longest > max_partition_rows(len(sources))
+            total < self.PIPELINE_MIN_BYTES
+            and longest <= max_partition_rows(len(sources))
         ):
-            result = pipeline_merge(
-                sources,
-                dir_path,
-                output_index,
-                keep_tombstones,
-                bloom_min_size,
-                mesh=self.mesh,
-                throttle=self.throttle,
-                tombstone_drop_before=self.tombstone_drop_before,
-            )
-            if result is not None:
-                return result
-        return self._merge_single_shot(
+            return None
+        return pipeline_merge(
             sources,
             dir_path,
             output_index,
-            cache,
             keep_tombstones,
             bloom_min_size,
+            mesh=self.mesh,
+            throttle=self.throttle,
+            tombstone_drop_before=self.tombstone_drop_before,
         )
 
     def _merge_single_shot(

@@ -81,6 +81,12 @@ _MAX_P2 = 1 << 17
 # in flight fit — and refuses (4, 64, 2^17) outright at 36 GB
 # (tests/test_tpu_compile.py holds both ends).
 _MAX_KP = 1 << 20
+# Launches dispatched and not yet read back, over EVERY merge of the
+# process — what the "two in flight" above is held to.  The chip
+# deployment runs each shard's merges at once in threads of one
+# process, and a bound per merge would let two shards' big merges put
+# four such programs on the one chip.
+_LAUNCH_SLOTS = threading.BoundedSemaphore(2)
 # Per-partition row target used to pick the partition count.
 _PAD_WASTE_LIMIT = 0.12
 # A shifted-u32 partition whose within-run duplicate excess (collisions
@@ -672,6 +678,18 @@ def _pipeline_merge_impl(
     kernel_q: "queue.Queue" = queue.Queue()
     order_q: "queue.Queue" = queue.Queue()
     stop = threading.Event()
+    # One token per _LAUNCH_SLOTS permit this merge holds: the
+    # downloader returns a permit when its launch has been read back,
+    # and whatever an abort leaves is returned after the joins below.
+    held_slots: list = []
+
+    def _release_slot() -> bool:
+        try:
+            held_slots.pop()
+        except IndexError:
+            return False
+        _LAUNCH_SLOTS.release()
+        return True
 
     def _launch_batch(metas, hosts, mode32):
         """One vmapped launch over up to ``launch_j`` same-mode
@@ -688,6 +706,10 @@ def _pipeline_merge_impl(
         for slot, (meta, host) in enumerate(zip(metas, hosts)):
             stack[slot] = host
             counts[slot] = meta[1]
+        while not _LAUNCH_SLOTS.acquire(timeout=0.25):
+            if stop.is_set():
+                return
+        held_slots.append(None)
         _ev(f"launch batch parts={[m[0] for m in metas]} mode32={mode32}")
         sharding = shard32 if mode32 else shard64
         if sharding is not None:
@@ -775,6 +797,7 @@ def _pipeline_merge_impl(
                 if out is not None:
                     _ev(f"d2h start parts={[m[0] for m in metas]}")
                     words = np.asarray(out)  # d2h (bit-packed rids)
+                    _release_slot()
                     _ev(f"d2h done parts={[m[0] for m in metas]}")
                     for slot, meta in enumerate(metas):
                         in_flight.release()
@@ -1090,6 +1113,8 @@ def _pipeline_merge_impl(
         _ev("joining threads")
         t_up.join(timeout=60)
         t_down.join(timeout=60)
+        while _release_slot():
+            pass
 
     sync_done.set()
     if t_sync is not None:

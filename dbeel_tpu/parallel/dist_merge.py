@@ -222,23 +222,20 @@ def DistributedMergeStrategy(mesh: Mesh):
     """CompactionStrategy running the sort across the whole mesh.
     Factory (rather than top-level subclass) so this module stays
     importable without dragging the storage stack in at import time."""
+    from ..ops.device_compaction import DeviceMergeStrategy
     from ..storage.compaction import ColumnarMergeStrategy
 
-    class _DistributedMergeStrategy(ColumnarMergeStrategy):
+    class _DistributedMergeStrategy(DeviceMergeStrategy):
+        """The device strategy with its mesh, and the sample sort in
+        place of the single-device launch: big merges are the parent's
+        (one threshold, one call to the mesh-sharded pipeline); below
+        it the columnar merge stages the whole input and
+        ``sort_and_dedup`` orders it across the mesh."""
+
         name = "distributed"
         path = "distributed"
 
-        # Mirrors DeviceMergeStrategy.PIPELINE_MIN_BYTES: big merges
-        # take the partitioned native pipeline with the launch-batch
-        # axis sharded over the mesh (O_DIRECT reads, per-device
-        # keyspace partitions, native gather-write) — NOT the serial
-        # load-everything host path (round-2 VERDICT weak #2).
-        PIPELINE_MIN_BYTES = 64 << 20
-
-        def __init__(self, mesh_: Mesh) -> None:
-            self.mesh = mesh_
-
-        def merge(
+        def _merge_single_shot(
             self,
             sources,
             dir_path,
@@ -247,23 +244,8 @@ def DistributedMergeStrategy(mesh: Mesh):
             keep_tombstones,
             bloom_min_size,
         ):
-            total = sum(getattr(s, "data_size", 0) for s in sources)
-            if total >= self.PIPELINE_MIN_BYTES:
-                from ..ops.pipeline import pipeline_merge
-
-                result = pipeline_merge(
-                    sources,
-                    dir_path,
-                    output_index,
-                    keep_tombstones,
-                    bloom_min_size,
-                    mesh=self.mesh,
-                    throttle=self.throttle,
-                    tombstone_drop_before=self.tombstone_drop_before,
-                )
-                if result is not None:
-                    return result
-            return super().merge(
+            return ColumnarMergeStrategy.merge(
+                self,
                 sources,
                 dir_path,
                 output_index,
@@ -275,7 +257,7 @@ def DistributedMergeStrategy(mesh: Mesh):
         def sort_and_dedup(self, cols):
             perm, same = distributed_sort_dedup(cols, self.mesh)
             # Long keys: host fixes order + dedup (see
-            # DeviceMergeStrategy).
+            # DeviceFullMergeStrategy).
             if (cols.key_size > columnar.KEY_PREFIX_BYTES).any():
                 perm = columnar.fixup_long_key_ties(cols, perm)
                 return perm, columnar.dedup_mask(cols, perm)

@@ -218,20 +218,28 @@ class CoalescedDeviceMergeStrategy:
     # same duck-typing story: LSMTree.compact() stamps it, but a
     # directly-constructed strategy must default to "keep tombstones".
     tombstone_drop_before = None
+    # Index DDL (see CompactionStrategy.index_fields), likewise.
+    index_fields = None
 
     def __init__(
         self, coalescer: Optional[CompactionCoalescer] = None
     ) -> None:
         self.coalescer = coalescer or default_coalescer()
 
-    # Sync fallback (e.g. recovery paths before a loop exists).
-    def merge(self, *args, **kwargs):
+    def _single(self):
+        """The one-merge device strategy, carrying what
+        LSMTree.compact stamped on this one."""
         from ..ops.device_compaction import DeviceMergeStrategy
 
         s = DeviceMergeStrategy()
         s.throttle = self.throttle
         s.tombstone_drop_before = self.tombstone_drop_before
-        return s.merge(*args, **kwargs)
+        s.index_fields = self.index_fields
+        return s
+
+    # Sync fallback (e.g. recovery paths before a loop exists).
+    def merge(self, *args, **kwargs):
+        return self._single().merge(*args, **kwargs)
 
     async def merge_async(
         self,
@@ -242,7 +250,6 @@ class CoalescedDeviceMergeStrategy:
         keep_tombstones,
         bloom_min_size,
     ):
-        from ..ops.device_compaction import DeviceMergeStrategy
         from ..storage.compaction import (
             compaction_stats,
             write_output_columnar,
@@ -253,24 +260,17 @@ class CoalescedDeviceMergeStrategy:
         # Big merges: the partitioned native pipeline (off-loop) beats
         # any coalesced single-shot launch; the coalescer exists for
         # many small concurrent per-shard merges.
-        total = sum(getattr(s, "data_size", 0) for s in sources)
-        if total >= DeviceMergeStrategy.PIPELINE_MIN_BYTES:
-            from ..ops.pipeline import pipeline_merge
-
-            result = await loop.run_in_executor(
-                None,
-                lambda: pipeline_merge(
-                    sources,
-                    dir_path,
-                    output_index,
-                    keep_tombstones,
-                    bloom_min_size,
-                    throttle=self.throttle,
-                    tombstone_drop_before=self.tombstone_drop_before,
-                ),
-            )
-            if result is not None:
-                return result
+        result = await loop.run_in_executor(
+            None,
+            self._single().merge_pipeline,
+            sources,
+            dir_path,
+            output_index,
+            keep_tombstones,
+            bloom_min_size,
+        )
+        if result is not None:
+            return result
 
         cols = await loop.run_in_executor(
             None, columnar.load_columns, sources

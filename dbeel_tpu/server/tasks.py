@@ -297,7 +297,8 @@ async def compact_tree(
     merge of adjacent orders (compaction.rs:35-102).  Each merge is one
     background unit under the share scheduler: while serving is busy,
     consecutive merges are spaced to the fg/bg share ratio.  Returns
-    the number of merges that completed."""
+    the number of merges that were committed: one that failed, or that
+    the tree declined (its ENOSPC back-off), is not progress."""
     indices_and_sizes = tree.sstable_indices_and_sizes()
 
     odd = [i for i, _ in indices_and_sizes if i % 2 != 0]
@@ -330,14 +331,14 @@ async def compact_tree(
         try:
             if scheduler is not None:
                 async with scheduler.bg_slice():
-                    await tree.compact(
+                    done = await tree.compact(
                         indices, index_to_compact, keep_tombstones
                     )
             else:
-                await tree.compact(
+                done = await tree.compact(
                     indices, index_to_compact, keep_tombstones
                 )
-            merged += 1
+            merged += bool(done)
         except Exception as e:
             log.error("failed to compact files: %s", e)
         index_to_compact += 2
@@ -353,8 +354,10 @@ async def compact_until_settled(
     its kernel for tens of seconds — wait for the next pass, and
     without one the tree rests with that debt until some later flush
     (which holds the governor at soft overload and parks every scan
-    chunk).  Each completed merge removes at least one table, so this
-    ends."""
+    chunk).  Each committed merge removes at least one table, and a
+    pass that commits none ends the loop — a merge that fails or
+    backs off for disk space is retried on the next flush event, as
+    it always was — so this ends."""
     while await compact_tree(tree, compaction_factor, scheduler):
         pass
 

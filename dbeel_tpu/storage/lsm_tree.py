@@ -1276,7 +1276,11 @@ class LSMTree:
         indices: Sequence[int],
         output_index: int,
         keep_tombstones: bool,
-    ) -> None:
+    ) -> bool:
+        """Merge the tables at ``indices`` into ``output_index``.
+        True when the merge was committed; False when nothing was
+        merged (no inputs, or the ENOSPC back-off below), so a caller
+        that loops on progress can tell the two apart."""
         index_set = set(indices)
         inputs = [
             t for t in self._sstables.tables if t.index in index_set
@@ -1287,7 +1291,7 @@ class LSMTree:
                 f"{[t.index for t in self._sstables.tables]}"
             )
         if not inputs:
-            return
+            return False
 
         # ENOSPC back-off: the merge output peaks at roughly the sum
         # of its inputs before the old files are deleted — refuse up
@@ -1300,7 +1304,7 @@ class LSMTree:
                 self.dir_path,
                 needed,
             )
-            return
+            return False
 
         # Merge runs off-loop so reads/writes stay responsive; it gets
         # cache-free sstable handles (the page cache is loop-owned).
@@ -1597,6 +1601,7 @@ class LSMTree:
             None, _dispose_inputs
         )
         self.flow.notify(flow_events.FlowEvent.COMPACTION_DONE)
+        return True
 
     # ------------------------------------------------------------------
     # Iteration (lsm_tree.rs:141-282) — sstables oldest→newest, then the

@@ -67,6 +67,9 @@ def test_tiny_rehearsal_runs_serve_and_major_and_counts_paths(rehearsal):
         "ok: no host-merge passes:",
         "ok: no merge failed",
         "ok: filter masks evaluated on the device:",
+        # What an unstamped scan met is reported, in either outcome.
+        "batch-class level=",
+        "default (batch-class) client: count(n >= ",
         "device output triplet SHA-256 equals native",
         "'pipeline': 2",
         "16777217.0 > 16777216.0 is True on the device lane",
@@ -138,6 +141,16 @@ def test_without_rehearsal_a_cpu_host_fails(tmp_dir):
     assert result["ok"] is False
     assert "no accelerator" in result["error"]
     assert '"tpu"' not in out.stdout
+
+
+@pytest.mark.parametrize("option", ["--docs", "--keys"])
+def test_sizes_are_not_options(tmp_dir, option):
+    # Sizes follow from --tiny and --chips alone (chip_smoke.sizes); a
+    # cut on a slow host is the script's own printed "CUT:" line.
+    out, lines = _smoke(["--tiny", option, "5"], tmp_dir + "/cache")
+    assert out.returncode == 2
+    assert "unrecognized arguments" in out.stderr
+    assert lines == []
 
 
 def test_outside_the_repository_it_prints_no_result(tmp_dir):

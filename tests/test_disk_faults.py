@@ -209,6 +209,43 @@ def test_flush_backs_off_below_free_space_floor(tmp_dir):
     run(main(), timeout=30)
 
 
+def test_compaction_backs_off_and_settles_below_free_space_floor(tmp_dir):
+    """A merge the tree declines for disk space is not progress: the
+    settle loop must return (and leave the retry to the next flush
+    event), not re-issue the same refused merge forever on the
+    shards' one event loop."""
+    from dbeel_tpu.server.tasks import compact_tree, compact_until_settled
+
+    async def main():
+        d = os.path.join(tmp_dir, "t")
+        tree = LSMTree.open_or_create(
+            d, capacity=1 << 20, memtable_kind="sorted"
+        )
+        for t in range(4):
+            for i in range(32):
+                await tree.set_with_timestamp(
+                    b"key%04d" % i, b"value-%d-%04d" % (t, i), 1000 + t
+                )
+            await tree.flush()
+        before = tree.sstable_indices_and_sizes()
+        assert len(before) == 4
+        file_io.set_fault(d, file_io.FAULT_NO_SPACE)
+        indices = [i for i, _ in before]
+        assert await tree.compact(indices, 7, False) is False
+        assert await compact_tree(tree, 2) == 0
+        await asyncio.wait_for(compact_until_settled(tree, 2), 5)
+        assert tree.sstable_indices_and_sizes() == before
+        assert not tree.read_only  # a declined merge degrades nothing
+        # Space is back: the next event's settle merges everything.
+        file_io.clear_faults()
+        await asyncio.wait_for(compact_until_settled(tree, 2), 30)
+        assert len(tree.sstable_indices_and_sizes()) == 1
+        assert await tree.get(b"key0003") == b"value-3-0003"
+        tree.close()
+
+    run(main(), timeout=60)
+
+
 # ----------------------------------------------------------------------
 # Satellite: table retirement must invalidate cached pages
 # ----------------------------------------------------------------------

@@ -329,3 +329,138 @@ def test_rid_pack_roundtrip():
         )
         out = bitonic.unpack_rids(words, bits, n)
         assert (out == rids).all()
+
+
+def _write_random_runs(d, seed, nruns=4, npr=600):
+    rng = random.Random(seed)
+    for r in range(nruns):
+        entries = {}
+        for _ in range(npr):
+            entries[rng.randbytes(rng.randint(8, 16))] = (
+                rng.randbytes(rng.randint(0, 24)),
+                900 + r,
+            )
+        write_sstable_fixture(
+            d,
+            r * 2,
+            [(k, v, ts) for k, (v, ts) in sorted(entries.items())],
+        )
+    return [r * 2 for r in range(nruns)]
+
+
+def _merge(name, d, idxs, oi):
+    srcs = [SSTable(d, i, None) for i in idxs]
+    try:
+        get_strategy(name).merge(srcs, d, oi, None, False, 1)
+    finally:
+        for s in srcs:
+            s.close()
+    return _sha_triplet(d, oi)
+
+
+def test_launch_slots_bound_every_merge_of_the_process(
+    tmp_dir, monkeypatch
+):
+    """_MAX_KP is sized for two launches on the chip, and one node runs
+    every shard's merges at once in threads of one process: launches
+    dispatched and not yet read back are counted here at the kernel
+    and at the read-back, over two concurrent merges of several
+    launches each, and never exceed two; every permit comes back."""
+    import threading
+    import time
+
+    import numpy as np
+
+    from dbeel_tpu.ops import bitonic
+    from dbeel_tpu.ops import pipeline as pipeline_mod
+
+    monkeypatch.setattr(DeviceMergeStrategy, "PIPELINE_MIN_BYTES", 0)
+    # Several launches per merge at test sizes.
+    monkeypatch.setattr(pipeline_mod, "_MULTIBATCH_MIN_ROWS", 0)
+    lock = threading.Lock()
+    seen = {"unread": 0, "peak": 0, "launches": 0}
+
+    class Unread:
+        def __init__(self, out):
+            self.out = out
+
+        def __array__(self, dtype=None, copy=None):
+            words = np.asarray(self.out)
+            time.sleep(0.02)  # a read-back slower than a dispatch
+            with lock:
+                seen["unread"] -= 1
+            return words
+
+    def counting(kernel):
+        def call(dev, counts, pack_bits):
+            with lock:
+                seen["unread"] += 1
+                seen["launches"] += 1
+                seen["peak"] = max(seen["peak"], seen["unread"])
+            return Unread(kernel(dev, counts, pack_bits))
+
+        return call
+
+    for name in (
+        "merge_runs_prefix32_packed_batch_kernel",
+        "merge_runs_prefix64_packed_batch_kernel",
+    ):
+        monkeypatch.setattr(bitonic, name, counting(getattr(bitonic, name)))
+
+    dirs = [os.path.join(tmp_dir, n) for n in ("a", "b")]
+    idxs = []
+    for seed, d in enumerate(dirs):
+        os.makedirs(d)
+        idxs.append(_write_random_runs(d, 40 + seed))
+    got, errors = {}, []
+
+    def one(d, ix):
+        try:
+            got[d] = _merge("device", d, ix, 103)
+        except BaseException as e:  # surfaced below
+            errors.append(e)
+
+    threads = [
+        threading.Thread(target=one, args=(d, ix))
+        for d, ix in zip(dirs, idxs)
+    ]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(120)
+    assert not errors, errors
+    for d, ix in zip(dirs, idxs):
+        assert got[d] == _merge("heap", d, ix, 101)
+    assert seen["launches"] >= 4, seen  # two or more per merge
+    assert seen["peak"] <= 2 and seen["unread"] == 0, seen
+    # Every permit is back: both can be taken without waiting.
+    assert pipeline_mod._LAUNCH_SLOTS.acquire(timeout=0)
+    assert pipeline_mod._LAUNCH_SLOTS.acquire(timeout=0)
+    pipeline_mod._LAUNCH_SLOTS.release()
+    pipeline_mod._LAUNCH_SLOTS.release()
+
+
+def test_launch_slots_come_back_from_a_failed_merge(tmp_dir, monkeypatch):
+    """A launch that raises (the chip out of memory, a compile refused)
+    fails its merge; its permit must not stay taken, or every later
+    big merge of the process would wait for ever."""
+    from dbeel_tpu.ops import bitonic
+    from dbeel_tpu.ops import pipeline as pipeline_mod
+
+    monkeypatch.setattr(DeviceMergeStrategy, "PIPELINE_MIN_BYTES", 0)
+
+    def refuse(dev, counts, pack_bits):
+        raise RuntimeError("RESOURCE_EXHAUSTED: injected")
+
+    for name in (
+        "merge_runs_prefix32_packed_batch_kernel",
+        "merge_runs_prefix64_packed_batch_kernel",
+    ):
+        monkeypatch.setattr(bitonic, name, refuse)
+    idxs = _write_random_runs(tmp_dir, 50)
+    with pytest.raises(RuntimeError, match="injected"):
+        _merge("device", tmp_dir, idxs, 103)
+    assert pipeline_mod._LAUNCH_SLOTS.acquire(timeout=0)
+    assert pipeline_mod._LAUNCH_SLOTS.acquire(timeout=0)
+    pipeline_mod._LAUNCH_SLOTS.release()
+    pipeline_mod._LAUNCH_SLOTS.release()
