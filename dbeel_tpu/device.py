@@ -12,11 +12,24 @@ that did not acquire a device (every ``--processes`` shard) has none.
 from __future__ import annotations
 
 import os
+import threading
 from typing import Optional
 
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _held: Optional[dict] = None
+
+# What JAX reported since ``acquire()``: backend compilations, their
+# seconds, and the persistent cache's hits and misses.  Compiles run on
+# whichever thread first calls a shape, two shards' merges at once.
+_listening = False
+_compiles_lock = threading.Lock()
+_compiles = {
+    "compiles": 0,
+    "compile_s": 0.0,
+    "compile_cache_hits": 0,
+    "compile_cache_misses": 0,
+}
 
 
 def compile_cache_dir() -> str:
@@ -55,6 +68,7 @@ def acquire() -> dict:
         place_compile_cache()
         import jax
 
+        _listen_for_compiles()
         devices = jax.devices()
         _held = {
             "platform": devices[0].platform,
@@ -62,6 +76,45 @@ def acquire() -> dict:
             "count": len(devices),
         }
     return _held
+
+
+def _listen_for_compiles() -> None:
+    """Once per process: JAX keeps a listener for good."""
+    global _listening
+    if not _listening:
+        import jax
+
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_duration
+        )
+        jax.monitoring.register_event_listener(_on_event)
+        _listening = True
+
+
+def _on_duration(name: str, secs: float, **_kw) -> None:
+    if name.endswith("backend_compile_duration"):
+        with _compiles_lock:
+            _compiles["compiles"] += 1
+            _compiles["compile_s"] += secs
+
+
+def _on_event(name: str, **_kw) -> None:
+    if name.endswith("compilation_cache/cache_hits"):
+        key = "compile_cache_hits"
+    elif name.endswith("compilation_cache/cache_misses"):
+        key = "compile_cache_misses"
+    else:
+        return
+    with _compiles_lock:
+        _compiles[key] += 1
+
+
+def compile_counters() -> dict:
+    """Compilations JAX has reported since ``acquire()``
+    (``get_stats.compaction.compiles`` and beside it).  All zero in a
+    process that holds no device.  Never touches JAX."""
+    with _compiles_lock:
+        return dict(_compiles)
 
 
 def held() -> Optional[dict]:

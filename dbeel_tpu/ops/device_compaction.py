@@ -19,6 +19,7 @@ from ..storage.compaction import (
     compaction_stats,
 )
 from .bitonic import device_merge_prefix_order, device_merge_sorted_runs
+from .spans import Stages
 
 
 class DeviceMergeStrategy(ColumnarMergeStrategy):
@@ -130,24 +131,32 @@ class DeviceMergeStrategy(ColumnarMergeStrategy):
         from ..storage.compaction import write_output_columnar
         from .bitonic import device_merge_prefix_order_pipelined
 
-        perm, pieces = device_merge_prefix_order_pipelined(sources)
-        cols = columnar.assemble_columns(pieces)
-        self._tick()
-        perm, keep = self._refine(cols, perm)
-        self._tick()
-        if not keep_tombstones:
-            from ..storage.compaction import drop_tombstones_mask
+        # Sequential stages (ops/spans.py), under
+        # ``get_stats.compaction.stages.single_shot``: ``order`` is
+        # read + upload + kernel + read-back, ``write`` the output
+        # triplet with its bloom and sidecar.
+        with Stages("single_shot", "order") as at:
+            perm, pieces = device_merge_prefix_order_pipelined(sources)
+            at.to("assemble")
+            cols = columnar.assemble_columns(pieces)
+            self._tick()
+            at.to("refine")
+            perm, keep = self._refine(cols, perm)
+            self._tick()
+            if not keep_tombstones:
+                from ..storage.compaction import drop_tombstones_mask
 
-            keep = keep & ~drop_tombstones_mask(
-                cols.is_tombstone[perm],
-                cols.timestamp[perm],
-                self.tombstone_drop_before,
+                keep = keep & ~drop_tombstones_mask(
+                    cols.is_tombstone[perm],
+                    cols.timestamp[perm],
+                    self.tombstone_drop_before,
+                )
+            at.to("write")
+            result = write_output_columnar(
+                cols, perm[keep], dir_path, output_index, cache,
+                bloom_min_size, throttle=self.throttle,
+                index_fields=self.index_fields,
             )
-        result = write_output_columnar(
-            cols, perm[keep], dir_path, output_index, cache,
-            bloom_min_size, throttle=self.throttle,
-            index_fields=self.index_fields,
-        )
         compaction_stats.note_path("single_shot")
         return result
 

@@ -42,8 +42,8 @@ tests enforce it).
 
 from __future__ import annotations
 
-import contextlib
 import ctypes
+import itertools
 import logging
 import os
 import queue
@@ -66,6 +66,7 @@ from ..storage.entry import (
     ENTRY_HEADER_SIZE,
     file_name,
 )
+from .spans import Stages
 
 log = logging.getLogger(__name__)
 
@@ -369,17 +370,17 @@ def pipeline_merge(
     exchange (contrast the reference's single-core heap loop,
     /root/reference/src/tasks/compaction.rs:104-137).
 
-    Set ``DBEEL_PROFILE_DIR`` to capture a JAX profiler trace of the
-    device stages (viewable in TensorBoard/XProf) — the SURVEY §5
-    observability improvement over the reference's logs-only stance."""
-    profile_dir = os.environ.get("DBEEL_PROFILE_DIR")
-    if profile_dir:
-        import jax
-
-        tracing = jax.profiler.trace(profile_dir)
-    else:
-        tracing = contextlib.nullcontext()
-    with tracing:
+    Every stage boundary below is a span (ops/spans.py): seconds and
+    counts under ``get_stats.compaction.stages.pipeline``, and
+    ``dbeel.pipeline.*`` events in whatever profile is running.  The
+    calling thread's stages — ``read_stage``, ``plan``, ``wait_device``,
+    ``decode``, ``wait_writer`` (``throttle`` where a throttle is
+    attached), ``bloom``, ``close_wait``, ``sidecar`` — partition the
+    outer span ``merge``; the other threads' (``read_run``,
+    ``operand``, ``slot_wait``, ``h2d_dispatch``, ``d2h``,
+    ``gather_write``, ``fsync``) and the nested ``stage_prefixes``
+    overlap them and say what the caller was waiting on."""
+    with Stages("pipeline", "read_stage") as at:
         result = _pipeline_merge_impl(
             sources,
             dir_path,
@@ -389,6 +390,7 @@ def pipeline_merge(
             mesh,
             throttle,
             tombstone_drop_before,
+            at=at,
         )
     # Counted here, once, for every caller of the pipeline.
     if result is None:
@@ -529,6 +531,8 @@ def _pipeline_merge_impl(
     mesh=None,
     throttle=None,
     tombstone_drop_before: "int | None" = None,
+    *,
+    at: Stages,
 ) -> Optional[MergeResult]:
     from ..storage import native as native_mod
 
@@ -542,24 +546,7 @@ def _pipeline_merge_impl(
         unpack_rids,
     )
 
-    import os as _os
-    import sys as _sys
-    import time as _time
-
-    _dbg = bool(_os.environ.get("DBEEL_PIPE_DEBUG"))
-    _t0 = _time.perf_counter()
-
-    def _ev(msg):
-        # Stage-event tracing (DBEEL_PIPE_DEBUG=1): timestamps for
-        # read/stage, launches, d2h, per-partition consume, writer
-        # puts, background syncs and close — the observability that
-        # found the round-3 bottlenecks.
-        if _dbg:
-            print(
-                f"[pipe {_time.perf_counter() - _t0:7.3f}] {msg}",
-                file=_sys.stderr,
-                flush=True,
-            )
+    span = at.span  # this merge's stages on its other threads
 
     # ---- host staging (index columns + O_DIRECT data reads) ---------
     # IO threads read ahead (O_DIRECT, GIL released inside the C
@@ -570,15 +557,22 @@ def _pipeline_merge_impl(
     from concurrent.futures import ThreadPoolExecutor
 
     n_readers = max(
-        1, int(_os.environ.get("DBEEL_PIPE_READERS", "2") or 2)
+        1, int(os.environ.get("DBEEL_PIPE_READERS", "2") or 2)
     )
+
+    def read_run(i, source):
+        with span("read_run", run=i):
+            return _read_run(lib, source)
+
     with ThreadPoolExecutor(max_workers=n_readers) as io:
-        futs = [io.submit(_read_run, lib, s) for s in sources]
+        futs = [io.submit(read_run, i, s) for i, s in enumerate(sources)]
         runs = []
-        for f in futs:
+        for i, f in enumerate(futs):
             r = f.result()
-            _stage_prefixes(r, lib)
+            with span("stage_prefixes", run=i):
+                _stage_prefixes(r, lib)
             runs.append(r)
+    at.to("plan")
     # Mesh mode: widen the launch batch to a device multiple and shard
     # the batch axis — each device merges its own keyspace partitions.
     # Computed BEFORE partitioning: the multi-batch preference must
@@ -602,7 +596,6 @@ def _pipeline_merge_impl(
     if chosen is None:
         return None
     _splitters, bounds, p2 = chosen
-    _ev("prologue done (read+stage+choose)")
     n_parts = (bounds[0].size - 1) if bounds is not None else 0
     k2 = _pow2(max(1, len(runs)))
     pack_bits = rid_pack_bits(k2)
@@ -691,43 +684,49 @@ def _pipeline_merge_impl(
         _LAUNCH_SLOTS.release()
         return True
 
+    launches = itertools.count()
+
     def _launch_batch(metas, hosts, mode32):
         """One vmapped launch over up to ``launch_j`` same-mode
         partitions, empty-slot padded to a single compiled shape; the
         batch axis shards over the mesh when one is supplied."""
         j = launch_j
-        if mode32:
-            stack = np.full((j, k2, p2), SENTINEL, dtype=np.uint32)
-        else:
-            stack = np.full(
-                (j, k2, p2, 2), SENTINEL, dtype=np.uint32
-            )
-        counts = np.zeros((j, k2), dtype=np.uint32)
-        for slot, (meta, host) in enumerate(zip(metas, hosts)):
-            stack[slot] = host
-            counts[slot] = meta[1]
-        while not _LAUNCH_SLOTS.acquire(timeout=0.25):
-            if stop.is_set():
-                return
+        # One launch's spans share ``launch`` (its ordinal in the
+        # merge) and ``part`` (its first partition).
+        ids = {"launch": next(launches), "part": metas[0][0]}
+        with span("operand", **ids):
+            if mode32:
+                stack = np.full((j, k2, p2), SENTINEL, dtype=np.uint32)
+            else:
+                stack = np.full(
+                    (j, k2, p2, 2), SENTINEL, dtype=np.uint32
+                )
+            counts = np.zeros((j, k2), dtype=np.uint32)
+            for slot, (meta, host) in enumerate(zip(metas, hosts)):
+                stack[slot] = host
+                counts[slot] = meta[1]
+        with span("slot_wait", **ids):
+            while not _LAUNCH_SLOTS.acquire(timeout=0.25):
+                if stop.is_set():
+                    return
         held_slots.append(None)
-        _ev(f"launch batch parts={[m[0] for m in metas]} mode32={mode32}")
-        sharding = shard32 if mode32 else shard64
-        if sharding is not None:
-            dev = jax.device_put(stack, sharding)
-            cnt = jax.device_put(counts, shard_counts)
-        else:
-            dev = jax.device_put(stack)
-            cnt = counts
-        if mode32:
-            out = merge_runs_prefix32_packed_batch_kernel(
-                dev, cnt, pack_bits
-            )
-        else:
-            out = merge_runs_prefix64_packed_batch_kernel(
-                dev, cnt, pack_bits
-            )
-        _ev(f"dispatched batch parts={[m[0] for m in metas]}")
-        kernel_q.put((metas, out))
+        with span("h2d_dispatch", **ids):
+            sharding = shard32 if mode32 else shard64
+            if sharding is not None:
+                dev = jax.device_put(stack, sharding)
+                cnt = jax.device_put(counts, shard_counts)
+            else:
+                dev = jax.device_put(stack)
+                cnt = counts
+            if mode32:
+                out = merge_runs_prefix32_packed_batch_kernel(
+                    dev, cnt, pack_bits
+                )
+            else:
+                out = merge_runs_prefix64_packed_batch_kernel(
+                    dev, cnt, pack_bits
+                )
+        kernel_q.put((metas, out, ids))
 
     def upload():
         try:
@@ -745,21 +744,23 @@ def _pipeline_merge_impl(
                 # Timed acquire + stop checks: if the downloader dies
                 # it can never release permits, and this thread must
                 # not park forever pinning the run buffers.
-                while not in_flight.acquire(timeout=0.25):
-                    if stop.is_set():
-                        return
+                with span("slot_wait", part=p):
+                    while not in_flight.acquire(timeout=0.25):
+                        if stop.is_set():
+                            return
                 if stop.is_set():
                     return
-                host, counts, los, mode32, minpf, shift = (
-                    _partition_operand(runs, bounds, p, k2, p2)
-                )
+                with span("operand", part=p):
+                    host, counts, los, mode32, minpf, shift = (
+                        _partition_operand(runs, bounds, p, k2, p2)
+                    )
                 if host is None:
                     # Keep strict partition order: launch whatever is
                     # pending first, THEN the empty marker (the
                     # downloader releases this partition's permit).
                     flush()
                     kernel_q.put(
-                        ([(p, counts, los, True, 0, 0)], None)
+                        ([(p, counts, los, True, 0, 0)], None, None)
                     )
                     continue
                 if metas and mode32 != batch_mode:
@@ -793,12 +794,13 @@ def _pipeline_merge_impl(
                     stop.set()
                     order_q.put(item)
                     return
-                metas, out = item
+                metas, out, ids = item
                 if out is not None:
-                    _ev(f"d2h start parts={[m[0] for m in metas]}")
-                    words = np.asarray(out)  # d2h (bit-packed rids)
+                    # The kernel's completion + the d2h of its
+                    # bit-packed run-ids.
+                    with span("d2h", **ids):
+                        words = np.asarray(out)
                     _release_slot()
-                    _ev(f"d2h done parts={[m[0] for m in metas]}")
                     for slot, meta in enumerate(metas):
                         in_flight.release()
                         order_q.put((meta, words[slot]))
@@ -835,8 +837,9 @@ def _pipeline_merge_impl(
                     continue
                 if job is None:
                     return
-                sel_sz, args, nbytes, _arrays = job
-                rc = lib.dbeel_writer_put(handle, run_ptrs, *args)
+                sel_sz, args, nbytes, _arrays, p = job
+                with span("gather_write", part=p):
+                    rc = lib.dbeel_writer_put(handle, run_ptrs, *args)
                 if rc != 0:
                     writer_state["error"] = _PipelineError(
                         "native gather-write failed"
@@ -845,7 +848,6 @@ def _pipeline_merge_impl(
                     return
                 writer_state["wrote"] += sel_sz
                 writer_state["bytes"] += nbytes
-                _ev(f"writer put done ({writer_state['bytes']>>20}MB)")
         except BaseException as e:
             writer_state["error"] = e
             stop.set()
@@ -859,9 +861,9 @@ def _pipeline_merge_impl(
         while not sync_done.wait(0.2):
             b = writer_state["bytes"]
             if b - last >= _SYNC_STRIDE:
-                lib.dbeel_writer_sync(handle)
+                with span("fsync"):
+                    lib.dbeel_writer_sync(handle)
                 last = b
-                _ev(f"bg sync at {b>>20}MB")
 
     t_write = threading.Thread(target=writer, daemon=True)
     t_write.start()
@@ -879,6 +881,7 @@ def _pipeline_merge_impl(
             # without ever feeding order_q (it is not part of the
             # upload->download chain), so an untimed get could park
             # this thread forever on e.g. a full disk.
+            at.to("wait_device")
             while True:
                 try:
                     item = order_q.get(timeout=0.25)
@@ -893,7 +896,7 @@ def _pipeline_merge_impl(
             if isinstance(item, BaseException):
                 raise item
             (p, counts, los, mode32, minpf, shift), packed = item
-            _ev(f"consume start p={p}")
+            at.to("decode", part=p)
             if writer_state["error"] is not None:
                 raise writer_state["error"]
             assert p == expected
@@ -1068,7 +1071,9 @@ def _pipeline_merge_impl(
                 args,
                 nbytes,
                 (src_run, src_off, ks_sel, fs_sel),
+                p,
             )
+            at.to("wait_writer", part=p)
             while True:
                 try:
                     write_q.put(job, timeout=0.25)
@@ -1078,13 +1083,14 @@ def _pipeline_merge_impl(
                         raise writer_state["error"] or _PipelineError(
                             "writer stopped"
                         )
-            _ev(f"consume done p={p}")
             if throttle is not None:
                 # Latency class: one partition is the consume quantum —
                 # pay back CPU to serving between partitions.
+                at.to("throttle", part=p)
                 throttle.tick()
             if collect_bloom:
                 bloom_sel.append(sel)
+        at.to("wait_writer")
         write_q.put(None)
         t_write.join(timeout=600)
         if writer_state["error"] is not None:
@@ -1110,7 +1116,6 @@ def _pipeline_merge_impl(
             _unlink_quiet(data_path, index_path)
         raise
     finally:
-        _ev("joining threads")
         t_up.join(timeout=60)
         t_down.join(timeout=60)
         while _release_slot():
@@ -1133,7 +1138,7 @@ def _pipeline_merge_impl(
     # reads only the INPUT runs — never the output file — and the
     # entry/byte counts are already known from the writer's own
     # accounting, so nothing here depends on close completing.
-    _ev("writer close (async)")
+    at.to("bloom")
     data_size = ctypes.c_uint64(0)
     close_ret = {"entries": -1, "crcs": None}
     # CRC handoff caps: the merged output can never exceed the sum of
@@ -1142,21 +1147,23 @@ def _pipeline_merge_impl(
     _icap = int(run_base[-1]) * 16 // 4096 + 2
 
     def _close():
+        # Inside either call: the final fdatasync + truncate.
         if writer_crcs:
             dcrc = (ctypes.c_uint32 * _dcap)()
             icrc = (ctypes.c_uint32 * _icap)()
             nd = ctypes.c_uint64(0)
             ni = ctypes.c_uint64(0)
-            rc = lib.dbeel_writer_close2(
-                handle,
-                ctypes.byref(data_size),
-                dcrc,
-                _dcap,
-                icrc,
-                _icap,
-                ctypes.byref(nd),
-                ctypes.byref(ni),
-            )
+            with span("fsync"):
+                rc = lib.dbeel_writer_close2(
+                    handle,
+                    ctypes.byref(data_size),
+                    dcrc,
+                    _dcap,
+                    icrc,
+                    _icap,
+                    ctypes.byref(nd),
+                    ctypes.byref(ni),
+                )
             if rc == -2:
                 # Triplet closed fine; only the CRC handoff was
                 # refused — the LSM's counted post-hoc sidecar
@@ -1171,9 +1178,10 @@ def _pipeline_merge_impl(
                         list(icrc[: ni.value]),
                     )
         else:
-            close_ret["entries"] = lib.dbeel_writer_close(
-                handle, ctypes.byref(data_size)
-            )
+            with span("fsync"):
+                close_ret["entries"] = lib.dbeel_writer_close(
+                    handle, ctypes.byref(data_size)
+                )
 
     t_close = threading.Thread(target=_close, daemon=True)
     t_close.start()
@@ -1238,8 +1246,8 @@ def _pipeline_merge_impl(
             _unlink_quiet(data_path, index_path, bloom_path)
         raise
 
+    at.to("close_wait")
     t_close.join(timeout=600)
-    _ev("writer closed")
     if t_close.is_alive():
         log.error(
             "pipeline writer close wedged; leaking native writer "
@@ -1252,6 +1260,7 @@ def _pipeline_merge_impl(
     assert close_ret["entries"] == entries
     assert int(data_size.value) == writer_state["bytes"]
 
+    at.to("sidecar")
     if close_ret["crcs"] is not None:
         # Single-pass sidecar: the per-page CRCs streamed out of the
         # gather writer; the bloom blob is still in RAM.  Written

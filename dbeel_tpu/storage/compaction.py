@@ -114,6 +114,21 @@ class CompactionStats:
         # and merges that raised.
         self.merges_running = 0
         self.merges_failed = 0
+        # Seconds and count per stage of the device merge paths
+        # (ops/spans.py): path -> stage -> [seconds, count].  Each
+        # path's ``merge`` is its outer span, which the calling
+        # thread's stages partition; the other threads' stages overlap
+        # them.
+        self.stages: dict = {}
+
+    def note_stage(self, path: str, stage: str, seconds: float) -> None:
+        """One span of ``stage`` inside a merge on ``path`` ended."""
+        with self._lock:
+            rec = self.stages.setdefault(path, {}).setdefault(
+                stage, [0.0, 0]
+            )
+            rec[0] += seconds
+            rec[1] += 1
 
     def note_path(self, path: str) -> None:
         """One merge output produced by ``path`` (a MERGE_PATHS name)."""
@@ -173,6 +188,7 @@ class CompactionStats:
         from . import native as native_mod
 
         held = device.held() or {}
+        compiled = device.compile_counters()
         with self._lock:
             amp = (
                 round(
@@ -204,6 +220,25 @@ class CompactionStats:
                 "pipeline_declines": self.pipeline_declines,
                 "merges_running": self.merges_running,
                 "merges_failed": self.merges_failed,
+                "stages": {
+                    path: {
+                        stage: {"s": secs, "n": n}
+                        for stage, (secs, n) in by_stage.items()
+                    }
+                    for path, by_stage in self.stages.items()
+                },
+                # The pipeline's outer spans (ops/spans.py OUTER):
+                # what its calling thread's stages sum to.
+                "pipeline_wall_s": self.stages.get("pipeline", {}).get(
+                    "merge", (0.0, 0)
+                )[0],
+                # Backend compilations since this process acquired its
+                # device (0 in one that holds none), their seconds,
+                # and the persistent cache's hits and misses.
+                "compiles": compiled["compiles"],
+                "compile_s": compiled["compile_s"],
+                "compile_cache_hits": compiled["compile_cache_hits"],
+                "compile_cache_misses": compiled["compile_cache_misses"],
                 # The device this process holds (None: it holds none
                 # and every merge above ran on the host).
                 "platform": held.get("platform"),

@@ -464,3 +464,225 @@ def test_launch_slots_come_back_from_a_failed_merge(tmp_dir, monkeypatch):
     assert pipeline_mod._LAUNCH_SLOTS.acquire(timeout=0)
     pipeline_mod._LAUNCH_SLOTS.release()
     pipeline_mod._LAUNCH_SLOTS.release()
+
+
+# ---- stage spans and stage-second counters (ops/spans.py) ------------
+
+# The calling thread's stages: sequential, so they partition the outer
+# span ``merge``.  ``throttle`` joins them where a throttle is attached.
+CALLER_STAGES = (
+    "read_stage", "plan", "wait_device", "decode", "wait_writer",
+    "bloom", "close_wait", "sidecar",
+)
+# The other threads' stages, which overlap those.
+THREAD_STAGES = (
+    "read_run", "operand", "slot_wait", "h2d_dispatch", "d2h",
+    "gather_write", "fsync",
+)
+
+
+def _pipeline_stages():
+    """(seconds, count) per stage so far in this process, and the sum
+    of the pipeline's outer spans."""
+    from dbeel_tpu.storage.compaction import compaction_stats
+
+    block = compaction_stats.stats()
+    stages = block["stages"].get("pipeline", {})
+    return (
+        {k: (v["s"], v["n"]) for k, v in stages.items()},
+        block["pipeline_wall_s"],
+    )
+
+
+def _stage_deltas(before, after):
+    return {
+        k: (s - before.get(k, (0.0, 0))[0], n - before.get(k, (0.0, 0))[1])
+        for k, (s, n) in after.items()
+    }
+
+
+def test_caller_stages_partition_the_pipelines_wall(tmp_dir, monkeypatch):
+    """After one pipeline merge every stage of the pipeline has been
+    counted, and the calling thread's stages sum to the merge's wall:
+    "where did the wall go" has one exact answer."""
+    monkeypatch.setattr(DeviceMergeStrategy, "PIPELINE_MIN_BYTES", 0)
+    idxs = _write_random_runs(tmp_dir, 60)
+    before, wall_before = _pipeline_stages()
+    assert _merge("device", tmp_dir, idxs, 103) == _merge(
+        "heap", tmp_dir, idxs, 101
+    )
+    after, wall_after = _pipeline_stages()
+    got = _stage_deltas(before, after)
+    wall = wall_after - wall_before
+    for name in CALLER_STAGES + THREAD_STAGES:
+        assert got[name][1] >= 1, (name, got)
+        assert got[name][0] >= 0.0
+    assert got["merge"][1] == 1 and got["merge"][0] == pytest.approx(wall)
+    assert got["read_run"][1] == len(idxs)
+    assert got["d2h"][1] == got["h2d_dispatch"][1]
+    assert got["gather_write"][1] == got["decode"][1]
+    assert "throttle" not in got  # none attached
+    caller = sum(got[name][0] for name in CALLER_STAGES)
+    assert wall > 0 and abs(caller - wall) <= 0.02 * wall, (caller, wall)
+
+
+def test_a_failed_merge_leaves_no_span_open(tmp_dir, monkeypatch):
+    """A merge that raises mid-way (a refused launch) still closes
+    every stage it opened: its own stages sum to its wall, and so do
+    the next merge's."""
+    from dbeel_tpu.ops import bitonic
+
+    monkeypatch.setattr(DeviceMergeStrategy, "PIPELINE_MIN_BYTES", 0)
+    idxs = _write_random_runs(tmp_dir, 61)
+
+    def refuse(dev, counts, pack_bits):
+        raise RuntimeError("RESOURCE_EXHAUSTED: injected")
+
+    def closes(before, wall_before):
+        after, wall_after = _pipeline_stages()
+        got = _stage_deltas(before, after)
+        wall = wall_after - wall_before
+        caller = sum(got.get(n, (0.0, 0))[0] for n in CALLER_STAGES)
+        assert got["merge"][1] == 1
+        assert wall > 0 and abs(caller - wall) <= 0.02 * wall, got
+
+    before = _pipeline_stages()
+    with monkeypatch.context() as m:
+        for name in (
+            "merge_runs_prefix32_packed_batch_kernel",
+            "merge_runs_prefix64_packed_batch_kernel",
+        ):
+            m.setattr(bitonic, name, refuse)
+        with pytest.raises(RuntimeError, match="injected"):
+            _merge("device", tmp_dir, idxs, 103)
+    closes(*before)
+    before = _pipeline_stages()
+    assert _merge("device", tmp_dir, idxs, 105) == _merge(
+        "heap", tmp_dir, idxs, 101
+    )
+    closes(*before)
+
+
+def test_two_merges_at_once_both_leave_spans_in_one_profile(
+    tmp_dir, monkeypatch
+):
+    """A 2-shard node runs two pipeline merges at once in one process,
+    and JAX allows one profile at a time: the spans only annotate
+    whatever profile is running, so both merges succeed inside it and
+    its host planes hold ``dbeel.pipeline.*`` events of both, each with
+    its merge's id."""
+    import glob
+    import threading
+
+    import jax
+    from jax.profiler import ProfileData
+
+    monkeypatch.setattr(DeviceMergeStrategy, "PIPELINE_MIN_BYTES", 0)
+    dirs = [os.path.join(tmp_dir, n) for n in ("a", "b")]
+    idxs = []
+    for seed, d in enumerate(dirs):
+        os.makedirs(d)
+        idxs.append(_write_random_runs(d, 70 + seed))
+    got, errors = {}, []
+
+    def one(d, ix):
+        try:
+            got[d] = _merge("device", d, ix, 103)
+        except BaseException as e:  # surfaced below
+            errors.append(e)
+
+    threads = [
+        threading.Thread(target=one, args=(d, ix))
+        for d, ix in zip(dirs, idxs)
+    ]
+    trace_dir = os.path.join(tmp_dir, "trace")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(100)
+    finally:
+        jax.profiler.stop_trace()
+    assert not errors, errors
+    assert not any(t.is_alive() for t in threads)
+    for d, ix in zip(dirs, idxs):
+        assert got[d] == _merge("heap", d, ix, 101)
+
+    (path,) = glob.glob(
+        os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")
+    )
+    by_merge: dict = {}  # merge id -> {event name: lines it is on}
+    for plane in ProfileData.from_file(path).planes:
+        for li, line in enumerate(plane.lines):
+            for ev in line.events:
+                if not ev.name.startswith("dbeel.pipeline."):
+                    continue
+                stats = dict(ev.stats)
+                assert ev.duration_ns >= 0 and "merge" in stats, ev.name
+                by_merge.setdefault(stats["merge"], {}).setdefault(
+                    ev.name[len("dbeel.pipeline."):], set()
+                ).add((plane.name, li))
+    assert len(by_merge) == 2, by_merge
+    for events in by_merge.values():
+        assert set(events) >= set(CALLER_STAGES + THREAD_STAGES + ("merge",))
+        # One merge's caller stages share its thread's line; the
+        # upload, download and writer threads have lines of their own.
+        caller_line = events["merge"]
+        assert len(caller_line) == 1
+        for name in CALLER_STAGES:
+            assert events[name] == caller_line, name
+        for name in ("operand", "d2h", "gather_write"):
+            assert not events[name] & caller_line, name
+
+
+def test_the_program_counts_its_own_compilations():
+    """``get_stats.compaction.compiles``: an operator sees a node
+    compiling without the benchmark's listener beside it."""
+    import numpy as np
+
+    from dbeel_tpu import device
+    from dbeel_tpu.ops import bitonic
+    from dbeel_tpu.storage.compaction import compaction_stats
+
+    device.acquire()
+    before = compaction_stats.stats()
+    # A launch shape no merge of this suite asks for; the suite runs
+    # with the compilation cache off.
+    vals = np.full((3, 2, 8), 0xFFFFFFFF, dtype=np.uint32)
+    vals[:, :, :2] = [[1, 5], [2, 3]]
+    counts = np.full((3, 2), 2, dtype=np.uint32)
+    out = bitonic.merge_runs_prefix32_packed_batch_kernel(vals, counts, 1)
+    assert bitonic.unpack_rids(np.asarray(out)[0], 1, 4).tolist() == [
+        0, 1, 1, 0,
+    ]
+    after = compaction_stats.stats()
+    assert after["compiles"] >= before["compiles"] + 1
+    assert after["compile_s"] > before["compile_s"]
+    for key in ("compile_cache_hits", "compile_cache_misses"):
+        assert after[key] >= before[key] >= 0
+
+
+def test_compaction_module_and_its_stats_never_import_jax():
+    """``--processes`` shards export the same block and never touch
+    JAX: the span helper lives in ops/, the counters in storage/."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from dbeel_tpu.storage.compaction import compaction_stats\n"
+        "compaction_stats.note_stage('pipeline', 'decode', 0.5)\n"
+        "b = compaction_stats.stats()\n"
+        "assert b['stages'] == {'pipeline': {'decode': {'s': 0.5, 'n': 1}}}\n"
+        "assert b['pipeline_wall_s'] == 0.0 and b['compiles'] == 0\n"
+        "assert 'jax' not in sys.modules, 'jax imported'\n"
+    )
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, "-c", code], cwd=repo, capture_output=True,
+        text=True, timeout=60,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
