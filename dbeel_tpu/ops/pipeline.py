@@ -55,7 +55,9 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 from ..storage import columnar
+from ..storage.bloom import _SEED1, _SEED2, BloomFilter
 from ..storage.compaction import (
+    COMPACT_BLOOM_FILE_EXT,
     MergeResult,
     _write_bloom,
     compaction_stats,
@@ -378,8 +380,18 @@ def pipeline_merge(
     attached), ``bloom``, ``close_wait``, ``sidecar`` — partition the
     outer span ``merge``; the other threads' (``read_run``,
     ``operand``, ``slot_wait``, ``h2d_dispatch``, ``d2h``,
-    ``gather_write``, ``fsync``) and the nested ``stage_prefixes``
-    overlap them and say what the caller was waiting on."""
+    ``gather_write``, ``fsync``, ``bloom_hash``, ``bloom_set``) and the
+    nested ``stage_prefixes`` overlap them and say what the caller was
+    waiting on.
+
+    The bloom filter is built beside the stream, not after it: a bloom
+    thread hashes each partition's keys while the writer gather-writes
+    them (``bloom_hash``), and sets the bits and writes and fsyncs the
+    bloom file (``bloom_set``) from the moment the last partition is
+    queued and the entry count known — under the writer's final join
+    and the close's fdatasync.  The caller's ``bloom`` stage starts the
+    close and joins that thread; ``close_wait`` is what then remains
+    of the close's flush."""
     with Stages("pipeline", "read_stage") as at:
         result = _pipeline_merge_impl(
             sources,
@@ -634,6 +646,7 @@ def _pipeline_merge_impl(
 
     data_path = f"{dir_path}/{file_name(output_index, COMPACT_DATA_FILE_EXT)}"
     index_path = f"{dir_path}/{file_name(output_index, COMPACT_INDEX_FILE_EXT)}"
+    bloom_path = f"{dir_path}/{file_name(output_index, COMPACT_BLOOM_FILE_EXT)}"
     # Single-pass sidecar (ISSUE 15): arm the gather writer's inline
     # page-CRC accumulators so the .sums sidecar is written from the
     # bytes AS they streamed through — no post-hoc triplet re-read.
@@ -652,8 +665,6 @@ def _pipeline_merge_impl(
         return None
 
     total_input = int(sum(r.size for r in runs))
-    collect_bloom = total_input >= bloom_min_size
-    bloom_sel: List[np.ndarray] = []
 
     run_ptrs = (ctypes.POINTER(ctypes.c_uint8) * max(1, len(runs)))(
         *[
@@ -865,8 +876,85 @@ def _pipeline_merge_impl(
                     lib.dbeel_writer_sync(handle)
                 last = b
 
+    # Bloom thread.  The filter's size follows from the FINAL entry
+    # count, a key's two hashes do not: each partition's keys are
+    # hashed here as it is queued for the writer (the writer job's own
+    # arrays, the same run pointers), and once the caller has posted
+    # the count the bits are set from the stored pairs and the bloom
+    # file is written and fsynced — under the writer's last partitions
+    # and the close's fdatasync, not after them.  Hashing is
+    # speculative: only a merge whose INPUT passes bloom_min_size can
+    # end with an output that does.  The queue is unbounded: write_q
+    # paces the caller, and a partition hashes several times faster
+    # than it gather-writes.
+    bloom_q: "queue.Queue" = queue.Queue()
+    bloom_state = {"blob": None, "error": None}
+
+    def bloomer(runs):
+        # ``runs``: the raw pointers in run_ptrs are only as alive as
+        # these buffers, whatever becomes of the caller's frame.
+        try:
+            u32p = ctypes.POINTER(ctypes.c_uint32)
+            u64p = ctypes.POINTER(ctypes.c_uint64)
+            pairs = np.empty(2 * int(run_base[-1]), dtype=np.uint32)
+            hashed = 0
+            while True:
+                # Timed get + stop check, as the writer's.
+                if stop.is_set():
+                    return
+                try:
+                    item = bloom_q.get(timeout=0.25)
+                except queue.Empty:
+                    continue
+                if not isinstance(item, tuple):
+                    break
+                p, src_run, src_off, ks_sel = item
+                with span("bloom_hash", part=p):
+                    lib.dbeel_bloom_hash_gather(
+                        run_ptrs,
+                        src_run.ctypes.data_as(u32p),
+                        src_off.ctypes.data_as(u64p),
+                        ks_sel.ctypes.data_as(u32p),
+                        src_run.size,
+                        ENTRY_HEADER_SIZE,
+                        _SEED1,
+                        _SEED2,
+                        pairs[2 * hashed :].ctypes.data_as(u32p),
+                    )
+                hashed += src_run.size
+            # ``item`` is the output's entry count, or 0 where the
+            # output ended under bloom_min_size: the hashes are dropped.
+            if not item:
+                return
+            assert item == hashed
+            with span("bloom_set"):
+                bloom = BloomFilter.with_capacity(hashed)
+                lib.dbeel_bloom_set_hashes(
+                    bloom.bits.ctypes.data_as(
+                        ctypes.POINTER(ctypes.c_uint8)
+                    ),
+                    bloom.num_bits,
+                    bloom.num_hashes,
+                    pairs.ctypes.data_as(u32p),
+                    hashed,
+                )
+                bloom_state["blob"] = _write_bloom(
+                    dir_path, output_index, bloom
+                )
+        except BaseException as e:  # re-raised by the caller's join
+            bloom_state["error"] = e
+
     t_write = threading.Thread(target=writer, daemon=True)
     t_write.start()
+    t_bloom = None
+    if total_input >= bloom_min_size:
+        t_bloom = threading.Thread(
+            target=bloomer,
+            args=(runs,),
+            name="dbeel-pipeline-bloom",
+            daemon=True,
+        )
+        t_bloom.start()
     t_sync = None
     if _SYNC_STRIDE <= 0:
         have_sync = False  # disabled: one flush at close only
@@ -874,6 +962,7 @@ def _pipeline_merge_impl(
         t_sync = threading.Thread(target=syncer, daemon=True)
         t_sync.start()
 
+    queued = queued_bytes = 0
     try:
         expected = 0
         while True:
@@ -1073,6 +1162,10 @@ def _pipeline_merge_impl(
                 (src_run, src_off, ks_sel, fs_sel),
                 p,
             )
+            queued += int(sel.size)
+            queued_bytes += nbytes
+            if t_bloom is not None:
+                bloom_q.put((p, src_run, src_off, ks_sel))
             at.to("wait_writer", part=p)
             while True:
                 try:
@@ -1088,8 +1181,12 @@ def _pipeline_merge_impl(
                 # pay back CPU to serving between partitions.
                 at.to("throttle", part=p)
                 throttle.tick()
-            if collect_bloom:
-                bloom_sel.append(sel)
+        # The last partition is queued, so the output's entry count
+        # and size are known while the writer still has its queue to
+        # write: the set phase starts here.
+        wants_bloom = queued > 0 and queued_bytes >= bloom_min_size
+        if t_bloom is not None:
+            bloom_q.put(queued if wants_bloom else 0)
         at.to("wait_writer")
         write_q.put(None)
         t_write.join(timeout=600)
@@ -1098,6 +1195,11 @@ def _pipeline_merge_impl(
     except BaseException:
         stop.set()
         t_write.join(timeout=60)
+        # Joined before ``runs`` can go: it hashes through run_ptrs.
+        if t_bloom is not None:
+            t_bloom.join(timeout=60)
+            if not t_bloom.is_alive():
+                _unlink_quiet(bloom_path)
         sync_done.set()
         if t_sync is not None:
             t_sync.join(timeout=60)
@@ -1132,12 +1234,16 @@ def _pipeline_merge_impl(
             "native writer handle for %s", data_path
         )
         raise _PipelineError("writer thread wedged")
-    # Close (final fdatasync + truncate) runs on a thread so the
-    # bloom build overlaps the device write-cache flush (VERDICT r3
-    # #7: the close flush was ~0.5-1s of serial tail).  The bloom
-    # reads only the INPUT runs — never the output file — and the
-    # entry/byte counts are already known from the writer's own
-    # accounting, so nothing here depends on close completing.
+    # Close (final fdatasync + truncate) runs on its own thread: the
+    # entry and byte counts are known from the writer's own
+    # accounting, so nothing below depends on its completing.  By now
+    # the bloom thread has had the count since the last partition was
+    # queued (the writer's final join ago), so the caller's ``bloom``
+    # stage is a join: what the set phase and the bloom file's fsync
+    # have left over runs beside the close's device write-cache flush
+    # (VERDICT r3 #7: that flush was ~0.5-1s of serial tail), and the
+    # caller then waits in ``close_wait`` for whatever of the flush
+    # remains.
     at.to("bloom")
     data_size = ctypes.c_uint64(0)
     close_ret = {"entries": -1, "crcs": None}
@@ -1187,55 +1293,16 @@ def _pipeline_merge_impl(
     t_close.start()
 
     entries = writer_state["wrote"]
-    wrote_bloom = False
+    assert (entries, writer_state["bytes"]) == (queued, queued_bytes)
     bloom_blob = None
-    from ..storage.compaction import COMPACT_BLOOM_FILE_EXT
-
-    bloom_path = (
-        f"{dir_path}/{file_name(output_index, COMPACT_BLOOM_FILE_EXT)}"
-    )
     try:
-        if writer_state["bytes"] >= bloom_min_size and entries > 0:
-            from ..storage.bloom import BloomFilter, _SEED1, _SEED2
-
-            bloom = BloomFilter.with_capacity(int(entries))
-            all_sel = (
-                np.concatenate(bloom_sel)
-                if bloom_sel
-                else np.zeros(0, np.int64)
-            )
-            for ri, r in enumerate(runs):
-                mask = (all_sel >= run_base[ri]) & (
-                    all_sel < run_base[ri + 1]
-                )
-                if not mask.any():
-                    continue
-                sel_r = all_sel[mask]
-                offs = np.ascontiguousarray(
-                    off_cat[sel_r] + np.uint64(ENTRY_HEADER_SIZE)
-                )
-                lens = np.ascontiguousarray(ks_cat[sel_r])
-                lib.dbeel_bloom_add_batch(
-                    bloom.bits.ctypes.data_as(
-                        ctypes.POINTER(ctypes.c_uint8)
-                    ),
-                    ctypes.c_uint64(bloom.num_bits),
-                    ctypes.c_uint32(bloom.num_hashes),
-                    r.data.ctypes.data_as(
-                        ctypes.POINTER(ctypes.c_uint8)
-                    ),
-                    offs.ctypes.data_as(
-                        ctypes.POINTER(ctypes.c_uint64)
-                    ),
-                    lens.ctypes.data_as(
-                        ctypes.POINTER(ctypes.c_uint32)
-                    ),
-                    ctypes.c_uint64(sel_r.size),
-                    ctypes.c_uint32(_SEED1),
-                    ctypes.c_uint32(_SEED2),
-                )
-            bloom_blob = _write_bloom(dir_path, output_index, bloom)
-            wrote_bloom = True
+        if t_bloom is not None:
+            t_bloom.join(timeout=600)
+            if t_bloom.is_alive():
+                raise _PipelineError("bloom thread wedged")
+            if bloom_state["error"] is not None:
+                raise bloom_state["error"]
+            bloom_blob = bloom_state["blob"]
     except BaseException:
         # The merge's contract is the whole triplet: a failed bloom
         # build (ENOSPC, MemoryError) must not leave the data/index
@@ -1243,8 +1310,12 @@ def _pipeline_merge_impl(
         # unlink under a live fdatasync/truncate.
         t_close.join(timeout=600)
         if not t_close.is_alive():
-            _unlink_quiet(data_path, index_path, bloom_path)
+            _unlink_quiet(data_path, index_path)
+        if t_bloom is None or not t_bloom.is_alive():
+            _unlink_quiet(bloom_path)
         raise
+    wrote_bloom = bloom_blob is not None
+    assert wrote_bloom == wants_bloom
 
     at.to("close_wait")
     t_close.join(timeout=600)

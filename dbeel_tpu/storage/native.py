@@ -648,6 +648,27 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.c_uint64,
         ]
     lib.dbeel_bloom_add_batch.restype = None
+    # The device pipeline's two-phase bloom (ops/pipeline.py).
+    lib.dbeel_bloom_hash_gather.restype = None
+    lib.dbeel_bloom_hash_gather.argtypes = [
+        ctypes.POINTER(u8p),
+        ctypes.POINTER(ctypes.c_uint32),
+        ctypes.POINTER(ctypes.c_uint64),
+        ctypes.POINTER(ctypes.c_uint32),
+        ctypes.c_uint64,
+        ctypes.c_uint64,
+        ctypes.c_uint32,
+        ctypes.c_uint32,
+        ctypes.POINTER(ctypes.c_uint32),
+    ]
+    lib.dbeel_bloom_set_hashes.restype = None
+    lib.dbeel_bloom_set_hashes.argtypes = [
+        u8p,
+        ctypes.c_uint64,
+        ctypes.c_uint32,
+        ctypes.POINTER(ctypes.c_uint32),
+        ctypes.c_uint64,
+    ]
     lib.dbeel_merge.restype = ctypes.c_int64
     lib.dbeel_merge.argtypes = [
         ctypes.POINTER(ctypes.c_char_p),

@@ -137,6 +137,62 @@ void dbeel_bloom_add_batch(uint8_t* bits, uint64_t num_bits,
   }
 }
 
+// dbeel_bloom_add_batch in two phases, for a caller that meets its
+// keys before it knows how many there will be (the device pipeline:
+// num_bits follows from the final entry count, the hashes do not).
+// Phase 1: the two hashes of entry i's key, read where the gather
+// writer reads it (run_ptrs[src_run[i]] + src_off[i] is the record,
+// its key starts key_off bytes in), stored as pairs[2i], pairs[2i+1].
+void dbeel_bloom_hash_gather(const uint8_t* const* run_ptrs,
+                             const uint32_t* src_run,
+                             const uint64_t* src_off,
+                             const uint32_t* key_size, uint64_t n,
+                             uint64_t key_off, uint32_t seed1,
+                             uint32_t seed2, uint32_t* pairs) {
+  for (uint64_t i = 0; i < n; i++) {
+    const uint8_t* key = run_ptrs[src_run[i]] + src_off[i] + key_off;
+    pairs[2 * i] = murmur3_32(key, key_size[i], seed1);
+    pairs[2 * i + 1] = murmur3_32(key, key_size[i], seed2) | 1u;
+  }
+}
+
+// Phase 2: set bit (h1 + j*h2) mod num_bits for j < num_hashes, the
+// same bits as above.  Stepping b <- b + (h2 mod m), minus m once it
+// passes m, walks the same residues with two divisions a key, 32-bit
+// ones (both hashes are below 2^32, so a wider m leaves them as they
+// are).  A block's bytes are prefetched before any of them is
+// written: the bitmap (12 MB for 10M keys) is touched at random.
+void dbeel_bloom_set_hashes(uint8_t* bits, uint64_t num_bits,
+                            uint32_t num_hashes, const uint32_t* pairs,
+                            uint64_t n) {
+  if (num_bits == 0 || num_hashes == 0) return;
+  constexpr uint64_t kBlock = 16;
+  const bool narrow = num_bits <= 0xFFFFFFFFull;
+  const uint32_t m32 = (uint32_t)num_bits;
+  std::vector<uint64_t> at(kBlock * num_hashes);
+  for (uint64_t lo = 0; lo < n; lo += kBlock) {
+    const uint64_t hi = lo + kBlock < n ? lo + kBlock : n;
+    uint64_t* out = at.data();
+    for (uint64_t i = lo; i < hi; i++) {
+      uint64_t b = pairs[2 * i];
+      uint64_t step = pairs[2 * i + 1];
+      if (narrow) {
+        b = (uint32_t)b % m32;
+        step = (uint32_t)step % m32;
+      }
+      for (uint32_t j = 0; j < num_hashes; j++) {
+        __builtin_prefetch(bits + (b >> 3), 1);
+        *out++ = b;
+        b += step;
+        if (b >= num_bits) b -= num_bits;
+      }
+    }
+    for (const uint64_t* p = at.data(); p != out; p++) {
+      bits[*p >> 3] |= (uint8_t)(1u << (*p & 7));
+    }
+  }
+}
+
 // k-way merge. Returns the number of output entries; fills out_data
 // (records) and out_index (16B entries), sets *out_data_size.
 // The caller sizes out_data/out_index at the sum of the inputs.

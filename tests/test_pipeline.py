@@ -348,14 +348,20 @@ def _write_random_runs(d, seed, nruns=4, npr=600):
     return [r * 2 for r in range(nruns)]
 
 
-def _merge(name, d, idxs, oi):
+def _merge_with_bar(name, d, idxs, oi, bloom_min_size):
     srcs = [SSTable(d, i, None) for i in idxs]
     try:
-        get_strategy(name).merge(srcs, d, oi, None, False, 1)
+        res = get_strategy(name).merge(
+            srcs, d, oi, None, False, bloom_min_size
+        )
     finally:
         for s in srcs:
             s.close()
-    return _sha_triplet(d, oi)
+    return _sha_triplet(d, oi), res.entry_count, res.data_size, res.wrote_bloom
+
+
+def _merge(name, d, idxs, oi):
+    return _merge_with_bar(name, d, idxs, oi, 1)[0]
 
 
 def test_launch_slots_bound_every_merge_of_the_process(
@@ -477,7 +483,7 @@ CALLER_STAGES = (
 # The other threads' stages, which overlap those.
 THREAD_STAGES = (
     "read_run", "operand", "slot_wait", "h2d_dispatch", "d2h",
-    "gather_write", "fsync",
+    "gather_write", "fsync", "bloom_hash", "bloom_set",
 )
 
 
@@ -501,6 +507,18 @@ def _stage_deltas(before, after):
     }
 
 
+def _one_merge_closed(before, wall_before):
+    """Since ``before`` one pipeline merge ran, and however it ended
+    its calling thread's stages sum to its wall.  Returns the deltas."""
+    after, wall_after = _pipeline_stages()
+    got = _stage_deltas(before, after)
+    wall = wall_after - wall_before
+    caller = sum(got.get(n, (0.0, 0))[0] for n in CALLER_STAGES)
+    assert got["merge"][1] == 1
+    assert wall > 0 and abs(caller - wall) <= 0.02 * wall, got
+    return got
+
+
 def test_caller_stages_partition_the_pipelines_wall(tmp_dir, monkeypatch):
     """After one pipeline merge every stage of the pipeline has been
     counted, and the calling thread's stages sum to the merge's wall:
@@ -521,6 +539,10 @@ def test_caller_stages_partition_the_pipelines_wall(tmp_dir, monkeypatch):
     assert got["read_run"][1] == len(idxs)
     assert got["d2h"][1] == got["h2d_dispatch"][1]
     assert got["gather_write"][1] == got["decode"][1]
+    # The bloom thread hashed every partition the writer wrote, and
+    # set the bits once.
+    assert got["bloom_hash"][1] == got["gather_write"][1]
+    assert got["bloom_set"][1] == 1
     assert "throttle" not in got  # none attached
     caller = sum(got[name][0] for name in CALLER_STAGES)
     assert wall > 0 and abs(caller - wall) <= 0.02 * wall, (caller, wall)
@@ -538,14 +560,6 @@ def test_a_failed_merge_leaves_no_span_open(tmp_dir, monkeypatch):
     def refuse(dev, counts, pack_bits):
         raise RuntimeError("RESOURCE_EXHAUSTED: injected")
 
-    def closes(before, wall_before):
-        after, wall_after = _pipeline_stages()
-        got = _stage_deltas(before, after)
-        wall = wall_after - wall_before
-        caller = sum(got.get(n, (0.0, 0))[0] for n in CALLER_STAGES)
-        assert got["merge"][1] == 1
-        assert wall > 0 and abs(caller - wall) <= 0.02 * wall, got
-
     before = _pipeline_stages()
     with monkeypatch.context() as m:
         for name in (
@@ -555,12 +569,214 @@ def test_a_failed_merge_leaves_no_span_open(tmp_dir, monkeypatch):
             m.setattr(bitonic, name, refuse)
         with pytest.raises(RuntimeError, match="injected"):
             _merge("device", tmp_dir, idxs, 103)
-    closes(*before)
+    _one_merge_closed(*before)
     before = _pipeline_stages()
     assert _merge("device", tmp_dir, idxs, 105) == _merge(
         "heap", tmp_dir, idxs, 101
     )
-    closes(*before)
+    _one_merge_closed(*before)
+
+
+# ---- the two-phase bloom build (bloom thread) -------------------------
+
+
+def _bloom_thread_alive():
+    import threading
+
+    return any(
+        t.name == "dbeel-pipeline-bloom" for t in threading.enumerate()
+    )
+
+
+@pytest.mark.parametrize(
+    "num_bits,num_hashes",
+    [
+        (64, 7),  # the smallest filter: every step wraps
+        (10007, 7),  # a prime: steps of h2 mod m, some of them 0
+        (95850584, 7),  # what 10M keys get
+        ((1 << 32) + 15, 7),  # wider than a hash: nothing is reduced
+        (4099, 1),
+        (1 << 20, 13),
+    ],
+)
+def test_two_phase_bloom_sets_the_bits_add_batch_sets(num_bits, num_hashes):
+    """``dbeel_bloom_hash_gather`` + ``dbeel_bloom_set_hashes`` (the
+    pipeline's bloom thread) against ``dbeel_bloom_add_batch`` (the
+    native merge, the single-shot path) and ``BloomFilter.add_batch``
+    (numpy): the same bitmap, bit for bit, for keys of 1-40 bytes
+    scattered over four run buffers and met in any order."""
+    import ctypes
+
+    import numpy as np
+
+    from dbeel_tpu.storage import native
+    from dbeel_tpu.storage.bloom import _SEED1, _SEED2, BloomFilter
+    from dbeel_tpu.storage.entry import ENTRY_HEADER_SIZE
+
+    lib = native.require()
+    rng = random.Random(num_bits)
+    u8p = ctypes.POINTER(ctypes.c_uint8)
+    u32p = ctypes.POINTER(ctypes.c_uint32)
+    u64p = ctypes.POINTER(ctypes.c_uint64)
+
+    # Records as the runs hold them: header, key, value.
+    runs, where = [], []
+    for r in range(4):
+        buf = bytearray()
+        for _ in range(rng.randint(60, 90)):
+            key = rng.randbytes(rng.randint(1, 40))
+            where.append((r, len(buf), key))
+            buf += bytes(ENTRY_HEADER_SIZE) + key
+            buf += rng.randbytes(rng.randint(0, 30))
+        runs.append(np.frombuffer(bytes(buf), dtype=np.uint8))
+    rng.shuffle(where)
+    n = len(where)
+    src_run = np.array([w[0] for w in where], dtype=np.uint32)
+    src_off = np.array([w[1] for w in where], dtype=np.uint64)
+    key_size = np.array([len(w[2]) for w in where], dtype=np.uint32)
+
+    def bitmap():
+        # Whole u64 words, so three sparse 512 MB bitmaps compare by
+        # their nonzero words and their untouched pages stay untouched.
+        return np.zeros(-(-num_bits // 64) * 8, dtype=np.uint8)
+
+    def words(bits):
+        w = bits.view(np.uint64)
+        at = np.flatnonzero(w)
+        return at.tolist(), w[at].tolist()
+
+    two_phase = bitmap()
+    run_ptrs = (u8p * len(runs))(*[r.ctypes.data_as(u8p) for r in runs])
+    pairs = np.empty(2 * n, dtype=np.uint32)
+    # In two calls, as the bloom thread makes one a partition.
+    for lo, hi in ((0, n // 3), (n // 3, n)):
+        lib.dbeel_bloom_hash_gather(
+            run_ptrs,
+            src_run[lo:hi].ctypes.data_as(u32p),
+            src_off[lo:hi].ctypes.data_as(u64p),
+            key_size[lo:hi].ctypes.data_as(u32p),
+            hi - lo,
+            ENTRY_HEADER_SIZE,
+            _SEED1,
+            _SEED2,
+            pairs[2 * lo :].ctypes.data_as(u32p),
+        )
+    lib.dbeel_bloom_set_hashes(
+        two_phase.ctypes.data_as(u8p), num_bits, num_hashes,
+        pairs.ctypes.data_as(u32p), n,
+    )
+
+    one_call = bitmap()
+    data = np.concatenate(runs)
+    base = np.cumsum([0] + [r.size for r in runs[:-1]]).astype(np.uint64)
+    key_at = base[src_run] + src_off + np.uint64(ENTRY_HEADER_SIZE)
+    lib.dbeel_bloom_add_batch(
+        one_call.ctypes.data_as(u8p),
+        ctypes.c_uint64(num_bits),
+        ctypes.c_uint32(num_hashes),
+        data.ctypes.data_as(u8p),
+        key_at.ctypes.data_as(u64p),
+        key_size.ctypes.data_as(u32p),
+        ctypes.c_uint64(n),
+        ctypes.c_uint32(_SEED1),
+        ctypes.c_uint32(_SEED2),
+    )
+
+    numpy_filter = BloomFilter(num_bits, num_hashes)
+    assert numpy_filter.num_bits == num_bits
+    numpy_filter.bits = bitmap()
+    numpy_filter.add_batch([w[2] for w in where])
+
+    got = words(two_phase)
+    assert got[0], "no bit set"
+    assert got == words(one_call)
+    assert got == words(numpy_filter.bits)
+
+
+@pytest.mark.parametrize(
+    "bar,hashed,wrote",
+    [
+        ("under_the_output", True, True),
+        ("between_output_and_input", True, False),
+        ("over_the_input", False, False),
+    ],
+)
+def test_bloom_is_written_iff_the_output_passes_the_bar(
+    tmp_dir, monkeypatch, bar, hashed, wrote
+):
+    """Four runs of the same keys merge to a quarter of their bytes.
+    The bloom thread hashes speculatively wherever the INPUT passes
+    ``bloom_min_size``; the filter is written only where the OUTPUT
+    does, as every other strategy decides it."""
+    monkeypatch.setattr(DeviceMergeStrategy, "PIPELINE_MIN_BYTES", 0)
+    rng = random.Random(70)
+    keys = sorted({rng.randbytes(rng.randint(8, 16)) for _ in range(500)})
+    idxs = []
+    for r in range(4):
+        write_sstable_fixture(
+            tmp_dir, r * 2, [(k, b"v" * 20, 900 + r) for k in keys]
+        )
+        idxs.append(r * 2)
+    out_bytes = sum(16 + len(k) + 20 for k in keys)
+    bloom_min_size = {
+        "under_the_output": out_bytes,
+        "between_output_and_input": out_bytes + 1,
+        "over_the_input": 4 * out_bytes + 1,
+    }[bar]
+    heap = _merge_with_bar("heap", tmp_dir, idxs, 101, bloom_min_size)
+    before = _pipeline_stages()
+    device = _merge_with_bar("device", tmp_dir, idxs, 103, bloom_min_size)
+    got = _one_merge_closed(*before)
+    assert device == heap
+    assert device[1:] == (len(keys), out_bytes, wrote)
+    assert os.path.exists(
+        f"{tmp_dir}/{file_name(103, 'compact_bloom')}"
+    ) == wrote
+    written = got["gather_write"][1]
+    assert written >= 1
+    assert got.get("bloom_hash", (0.0, 0))[1] == (written if hashed else 0)
+    assert got.get("bloom_set", (0.0, 0))[1] == (1 if wrote else 0)
+    assert not _bloom_thread_alive()
+
+
+def test_a_failed_bloom_write_fails_the_merge_and_leaves_no_file(
+    tmp_dir, monkeypatch
+):
+    """The bloom thread's set phase meets a full disk: the error is
+    the merge's (re-raised on the calling thread), no file of the
+    triplet stays behind looking complete, no bloom thread lives on,
+    and the next merge is whole."""
+    import errno
+
+    from dbeel_tpu.ops import pipeline as pipeline_mod
+
+    monkeypatch.setattr(DeviceMergeStrategy, "PIPELINE_MIN_BYTES", 0)
+    idxs = _write_random_runs(tmp_dir, 62)
+
+    def full_disk(dir_path, output_index, bloom):
+        # As _write_bloom fails: the file is there, partly written.
+        path = f"{dir_path}/{file_name(output_index, 'compact_bloom')}"
+        with open(path, "wb") as f:
+            f.write(b"half")
+        raise OSError(errno.ENOSPC, "injected: no space left on device")
+
+    before = _pipeline_stages()
+    with monkeypatch.context() as m:
+        m.setattr(pipeline_mod, "_write_bloom", full_disk)
+        with pytest.raises(OSError, match="injected") as failed:
+            _merge("device", tmp_dir, idxs, 103)
+    assert failed.value.errno == errno.ENOSPC
+    got = _one_merge_closed(*before)
+    assert got["bloom_set"][1] == 1  # it got as far as the set phase
+    for ext in ("compact_data", "compact_index", "compact_bloom"):
+        assert not os.path.exists(f"{tmp_dir}/{file_name(103, ext)}"), ext
+    assert not _bloom_thread_alive()
+    before = _pipeline_stages()
+    assert _merge("device", tmp_dir, idxs, 105) == _merge(
+        "heap", tmp_dir, idxs, 101
+    )
+    _one_merge_closed(*before)
+    assert not _bloom_thread_alive()
 
 
 def test_two_merges_at_once_both_leave_spans_in_one_profile(
