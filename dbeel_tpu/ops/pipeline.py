@@ -381,8 +381,10 @@ def pipeline_merge(
     outer span ``merge``; the other threads' (``read_run``,
     ``operand``, ``slot_wait``, ``h2d_dispatch``, ``d2h``,
     ``gather_write``, ``fsync``, ``bloom_hash``, ``bloom_set``) and the
-    nested ``stage_prefixes`` overlap them and say what the caller was
-    waiting on.
+    nested ``stage_prefixes`` (in ``read_stage``) and ``tie_fixup`` (in
+    ``decode``) overlap them and say what the caller was waiting on.
+    The merge's shape (launches, partitions, rows launched and real,
+    runs, tie entries) is counted under ``get_stats.compaction.shape``.
 
     The bloom filter is built beside the stream, not after it: a bloom
     thread hashes each partition's keys while the writer gather-writes
@@ -392,6 +394,7 @@ def pipeline_merge(
     and the close's fdatasync.  The caller's ``bloom`` stage starts the
     close and joins that thread; ``close_wait`` is what then remains
     of the close's flush."""
+    shape: dict = {}
     with Stages("pipeline", "read_stage") as at:
         result = _pipeline_merge_impl(
             sources,
@@ -403,12 +406,13 @@ def pipeline_merge(
             throttle,
             tombstone_drop_before,
             at=at,
+            shape=shape,
         )
     # Counted here, once, for every caller of the pipeline.
     if result is None:
         compaction_stats.note_pipeline_decline()
     else:
-        compaction_stats.note_path("pipeline")
+        compaction_stats.note_pipeline(shape)
     return result
 
 
@@ -545,7 +549,10 @@ def _pipeline_merge_impl(
     tombstone_drop_before: "int | None" = None,
     *,
     at: Stages,
+    shape: dict,
 ) -> Optional[MergeResult]:
+    """``shape``: filled, where the merge produces an output, with its
+    ``compaction.PIPELINE_SHAPE`` counts."""
     from ..storage import native as native_mod
 
     lib = native_mod.require()
@@ -962,7 +969,7 @@ def _pipeline_merge_impl(
         t_sync = threading.Thread(target=syncer, daemon=True)
         t_sync.start()
 
-    queued = queued_bytes = 0
+    queued = queued_bytes = tie_entries = 0
     try:
         expected = 0
         while True:
@@ -1084,36 +1091,38 @@ def _pipeline_merge_impl(
                 else:
                     flags = pf[1:] == pf[:-1]
             keep = np.ones(n_p, dtype=bool)
-            positions, block_id = columnar.tie_positions_and_blocks(
-                flags
-            )
-            if positions.size:
-                sel_t = gidx[positions]
-                ks_t = ks_cat[sel_t]
-                ent_w = columnar.tie_block_widths(block_id, ks_t)
-                for w in np.unique(ent_w):
-                    bm = ent_w == w
-                    kwords, inv_ts, inv_src = _gather_tie_arrays(
-                        runs,
-                        run_base,
-                        off_cat,
-                        ks_cat,
-                        sel_t[bm],
-                        int(w),
-                    )
-                    order, dup = columnar.tie_block_sort(
-                        block_id[bm], kwords, ks_t[bm], inv_ts, inv_src
-                    )
-                    gidx[positions[bm]] = sel_t[bm][order]
-                    # The reorder moved entries across runs: refresh
-                    # the run-id column at exactly those positions.
-                    rids32[positions[bm]] = (
-                        np.searchsorted(
-                            run_base, gidx[positions[bm]], side="right"
+            with span("tie_fixup", part=p):
+                positions, block_id = columnar.tie_positions_and_blocks(
+                    flags
+                )
+                tie_entries += int(positions.size)
+                if positions.size:
+                    sel_t = gidx[positions]
+                    ks_t = ks_cat[sel_t]
+                    ent_w = columnar.tie_block_widths(block_id, ks_t)
+                    for w in np.unique(ent_w):
+                        bm = ent_w == w
+                        kwords, inv_ts, inv_src = _gather_tie_arrays(
+                            runs,
+                            run_base,
+                            off_cat,
+                            ks_cat,
+                            sel_t[bm],
+                            int(w),
                         )
-                        - 1
-                    ).astype(np.uint32)
-                    keep[positions[bm]] = ~dup
+                        order, dup = columnar.tie_block_sort(
+                            block_id[bm], kwords, ks_t[bm], inv_ts, inv_src
+                        )
+                        gidx[positions[bm]] = sel_t[bm][order]
+                        # The reorder moved entries across runs: refresh
+                        # the run-id column at exactly those positions.
+                        rids32[positions[bm]] = (
+                            np.searchsorted(
+                                run_base, gidx[positions[bm]], side="right"
+                            )
+                            - 1
+                        ).astype(np.uint32)
+                        keep[positions[bm]] = ~dup
 
             if not keep_tombstones:
                 drop = tomb_cat[gidx]
@@ -1350,4 +1359,15 @@ def _pipeline_merge_impl(
             ext=checksums.COMPACT_SUMS_FILE_EXT,
         )
 
+    # The upload thread is joined: ``launches`` has counted them all,
+    # and every launch has the one compiled shape.
+    n_launches = next(launches)
+    shape.update(
+        launches=n_launches,
+        partitions=n_parts,
+        rows_launched=n_launches * launch_j * k2 * p2,
+        rows_real=int(run_base[-1]),
+        runs_in=len(runs),
+        tie_entries=tie_entries,
+    )
     return MergeResult(int(entries), int(data_size.value), wrote_bloom)

@@ -71,6 +71,20 @@ MERGE_PATHS = (
     "heap",
 )
 
+# What a pipeline merge's shape was (ops/pipeline.py), summed over the
+# merges it produced: kernel launches and keyspace partitions, the rows
+# the launches held (batch x padded runs x padded rows a run, of every
+# launch) against the entries that were really there, the runs merged,
+# and the entries the host tie fix-up had to re-order.
+PIPELINE_SHAPE = (
+    "launches",
+    "partitions",
+    "rows_launched",
+    "rows_real",
+    "runs_in",
+    "tie_entries",
+)
+
 
 class CompactionStats:
     """Process-wide single-pass compaction/flush accounting
@@ -110,6 +124,7 @@ class CompactionStats:
         # Big merges the pipeline declined on their data (prefix
         # skew) and another device path then produced.
         self.pipeline_declines = 0
+        self.shape = {name: 0 for name in PIPELINE_SHAPE}
         # Merges between start and end right now (compile included),
         # and merges that raised.
         self.merges_running = 0
@@ -134,6 +149,14 @@ class CompactionStats:
         """One merge output produced by ``path`` (a MERGE_PATHS name)."""
         with self._lock:
             self.paths[path] += 1
+
+    def note_pipeline(self, shape: dict) -> None:
+        """One merge output produced by the pipeline, and what its
+        shape was (PIPELINE_SHAPE name -> count)."""
+        with self._lock:
+            self.paths["pipeline"] += 1
+            for name, count in shape.items():
+                self.shape[name] += int(count)
 
     def note_merge_running(self, delta: int) -> None:
         with self._lock:
@@ -218,6 +241,7 @@ class CompactionStats:
                 "index_maintenance_amplification": idx_amp,
                 "paths": dict(self.paths),
                 "pipeline_declines": self.pipeline_declines,
+                "shape": dict(self.shape),
                 "merges_running": self.merges_running,
                 "merges_failed": self.merges_failed,
                 "stages": {

@@ -867,6 +867,30 @@ def test_stats_schema_real_index_counters_exported():
     assert findings == [], findings
 
 
+def test_stats_schema_covers_the_pipeline_shape_block(tmp_path):
+    # ISSUE 28: the real compaction.py's ``shape`` counters (launches,
+    # partitions, rows, runs, tie entries of a pipeline merge) are
+    # increment-checked: clean as they are, and a tree whose
+    # get_stats.compaction drops the block fires.
+    with open(
+        os.path.join(REPO_ROOT, "dbeel_tpu/storage/compaction.py")
+    ) as f:
+        real = f.read()
+    export = '"shape": dict(self.shape),'
+    assert real.count(export) == 1
+    for source, fires in ((real, False), (real.replace(export, ""), True)):
+        root = _stats_tree(
+            tmp_path / str(fires), "class Unused:\n    pass\n"
+        )
+        os.makedirs(os.path.join(root, "dbeel_tpu/storage"))
+        with open(
+            os.path.join(root, "dbeel_tpu/storage/compaction.py"), "w"
+        ) as f:
+            f.write(source)
+        findings = stats_schema.check(Repo(root))
+        assert any("self.shape" in f.message for f in findings) == fires
+
+
 def test_stats_schema_escape_comment(tmp_path):
     root = _stats_tree(
         tmp_path,

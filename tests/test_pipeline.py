@@ -126,6 +126,7 @@ def _golden_vs_heap(tmp_dir, idxs, keep_tomb=False, expect_pipeline=True):
     assert results["heap"] == results["device"]
     if expect_pipeline:
         assert ran and ran[-1], "pipeline fell back to single-shot"
+    return results["device"]
 
 
 def _keys_from_u64(vals):
@@ -241,6 +242,76 @@ def test_pipeline_many_runs_wide_packing(tmp_dir, monkeypatch):
             [(k, v, ts) for k, (v, ts) in sorted(entries.items())],
         )
     _golden_vs_heap(tmp_dir, [r * 2 for r in range(64)])
+
+
+def _plain_model():
+    """The benchmark's numpy model of a merge (harness/varlen_runs.py),
+    which the wide cell's ``correct`` holds every merge on the chip
+    to."""
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmark.harness import varlen_runs
+
+    return varlen_runs.model
+
+
+@pytest.mark.parametrize(
+    "repeats,keep_tomb",
+    [(False, False), (True, False), (True, True)],
+    ids=["distinct-keys", "repeated-keys-tombstones-dropped",
+         "repeated-keys-tombstones-kept"],
+)
+def test_wide_64_variable_length_runs_vs_heap_and_model(
+    tmp_dir, monkeypatch, repeats, keep_tomb
+):
+    """BASELINE configs[3]'s shape at test size THROUGH THE PIPELINE
+    (test_device_merge's wide test takes the single-shot path at its
+    size): 64 overlapping runs of 16 B keys with values of 8-159 B, run
+    r's timestamps above run r-1's.  The benchmark's cell draws uniform
+    keys, which never repeat; with ``repeats`` every key lives in about
+    four runs and 15 % of the entries are tombstones, so newest-wins and
+    the tombstone drop are shown.  Triplet byte-identical to the heap
+    oracle's, and the numpy model's entry count and data-file length
+    equal to both."""
+    import numpy as np
+
+    monkeypatch.setattr(DeviceMergeStrategy, "PIPELINE_MIN_BYTES", 0)
+    rng = random.Random(2800 + repeats)
+    nruns, npr = 64, 120
+    pool = [rng.randbytes(16) for _ in range(nruns * npr // 4)]
+    rows = []  # (key, ts, full size, tombstone) of every entry written
+    for r in range(nruns):
+        keys = (
+            rng.sample(pool, npr)
+            if repeats
+            else [rng.randbytes(16) for _ in range(npr)]
+        )
+        entries = []
+        for k in sorted(keys):
+            tomb = repeats and rng.random() < 0.15
+            v = b"" if tomb else rng.randbytes(rng.randint(8, 159))
+            entries.append((k, v, 1000 * r + rng.randrange(1000)))
+        write_sstable_fixture(tmp_dir, r * 2, entries)
+        rows += [(k, ts, 32 + len(v), v == b"") for k, v, ts in entries]
+    _sha, count, data_size, wrote_bloom = _golden_vs_heap(
+        tmp_dir, [r * 2 for r in range(nruns)], keep_tomb=keep_tomb
+    )
+    assert wrote_bloom
+    keys = np.frombuffer(b"".join(k for k, *_ in rows), np.uint8)
+    model = _plain_model()(
+        keys.reshape(len(rows), 16),
+        np.array([ts for _k, ts, _f, _t in rows], dtype=np.int64),
+        np.array([f for _k, _ts, f, _t in rows], dtype=np.uint32),
+        None if keep_tomb else np.array([t for *_, t in rows]),
+    )
+    assert model == (count, data_size)
+    if repeats:
+        assert count < len(rows) // 2  # most entries were shadowed
+    else:
+        assert count == len(rows)
 
 
 def test_pipeline_mesh_byte_identical(tmp_dir, monkeypatch):
@@ -485,6 +556,9 @@ THREAD_STAGES = (
     "read_run", "operand", "slot_wait", "h2d_dispatch", "d2h",
     "gather_write", "fsync", "bloom_hash", "bloom_set",
 )
+# On the calling thread INSIDE one of its stages (``read_stage``,
+# ``decode``): counted beside the caller's sum, never in it.
+NESTED_STAGES = ("stage_prefixes", "tie_fixup")
 
 
 def _pipeline_stages():
@@ -532,7 +606,7 @@ def test_caller_stages_partition_the_pipelines_wall(tmp_dir, monkeypatch):
     after, wall_after = _pipeline_stages()
     got = _stage_deltas(before, after)
     wall = wall_after - wall_before
-    for name in CALLER_STAGES + THREAD_STAGES:
+    for name in CALLER_STAGES + THREAD_STAGES + NESTED_STAGES:
         assert got[name][1] >= 1, (name, got)
         assert got[name][0] >= 0.0
     assert got["merge"][1] == 1 and got["merge"][0] == pytest.approx(wall)
@@ -575,6 +649,67 @@ def test_a_failed_merge_leaves_no_span_open(tmp_dir, monkeypatch):
         "heap", tmp_dir, idxs, 101
     )
     _one_merge_closed(*before)
+
+
+def test_shape_counters_rise_by_one_merges_shape(tmp_dir, monkeypatch):
+    """``get_stats.compaction.shape`` across one pipeline merge of a
+    known shape: every launch and its rows, the partitions, the runs
+    and entries that went in, the entries the host tie fix-up took;
+    and ``tie_fixup`` is nested in ``decode``: the caller's stages
+    still sum to the merge's wall without it."""
+    from dbeel_tpu.ops import bitonic
+    from dbeel_tpu.storage.compaction import PIPELINE_SHAPE, compaction_stats
+
+    monkeypatch.setattr(DeviceMergeStrategy, "PIPELINE_MIN_BYTES", 0)
+    nruns = 5
+    idxs = _write_random_runs(tmp_dir, 62, nruns=nruns, npr=700)
+    # Planted ties: ten keys under one 8-byte prefix in each of two
+    # runs, so the device order leaves at least those twenty tied.
+    planted = [b"TIEDTIED" + bytes([i]) for i in range(10)]
+    for r in (1, 3):
+        write_sstable_fixture(
+            tmp_dir, 2 * nruns + 2 * r,
+            [(k, b"v%d" % r, 950 + r) for k in planted],
+        )
+    idxs += [2 * nruns + 2, 2 * nruns + 6]
+    srcs = [SSTable(tmp_dir, i, None) for i in idxs]
+    entries_in = sum(s.entry_count for s in srcs)
+    for s in srcs:
+        s.close()
+    launched = []  # operand shape of every launch
+    for name in (
+        "merge_runs_prefix32_packed_batch_kernel",
+        "merge_runs_prefix64_packed_batch_kernel",
+    ):
+        real = getattr(bitonic, name)
+
+        def spy(vals, counts, pack_bits, real=real):
+            launched.append(vals.shape)
+            return real(vals, counts, pack_bits)
+
+        monkeypatch.setattr(bitonic, name, spy)
+
+    before_shape = compaction_stats.stats()["shape"]
+    assert set(before_shape) == set(PIPELINE_SHAPE)
+    before = _pipeline_stages()
+    assert _merge("device", tmp_dir, idxs, 103) == _merge(
+        "heap", tmp_dir, idxs, 101
+    )
+    got = _one_merge_closed(*before)
+    after_shape = compaction_stats.stats()["shape"]
+    rose = {k: after_shape[k] - before_shape[k] for k in PIPELINE_SHAPE}
+    assert launched and rose["launches"] == len(launched)
+    assert rose["launches"] == got["h2d_dispatch"][1] == got["d2h"][1]
+    assert rose["rows_launched"] == sum(
+        j * k * p for j, k, p, *_words in launched
+    )
+    assert rose["rows_launched"] >= rose["rows_real"] == entries_in
+    assert rose["runs_in"] == len(idxs)
+    assert rose["partitions"] == got["decode"][1] >= 1
+    assert 2 * len(planted) <= rose["tie_entries"] <= entries_in
+    # One nested span per partition that held entries, inside decode.
+    assert got["tie_fixup"][1] == got["gather_write"][1]
+    assert 0.0 <= got["tie_fixup"][0] <= got["decode"][0]
 
 
 # ---- the two-phase bloom build (bloom thread) -------------------------
@@ -843,12 +978,14 @@ def test_two_merges_at_once_both_leave_spans_in_one_profile(
                 ).add((plane.name, li))
     assert len(by_merge) == 2, by_merge
     for events in by_merge.values():
-        assert set(events) >= set(CALLER_STAGES + THREAD_STAGES + ("merge",))
+        assert set(events) >= set(
+            CALLER_STAGES + THREAD_STAGES + NESTED_STAGES + ("merge",)
+        )
         # One merge's caller stages share its thread's line; the
         # upload, download and writer threads have lines of their own.
         caller_line = events["merge"]
         assert len(caller_line) == 1
-        for name in CALLER_STAGES:
+        for name in CALLER_STAGES + NESTED_STAGES:
             assert events[name] == caller_line, name
         for name in ("operand", "d2h", "gather_write"):
             assert not events[name] & caller_line, name
