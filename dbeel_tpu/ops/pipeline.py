@@ -38,6 +38,24 @@ keyspace ranges cut at sampled 8-byte key prefixes, so equal prefixes
 (hence equal keys, hence every dedup decision) never cross a partition
 boundary.  Output bytes are identical to every other strategy (golden
 tests enforce it).
+
+Memory (PR 29).  Every array of a merge that can reach 128 KiB is
+leased from one process-wide block pool (ops/block_pool.py) through the
+merge's ``Leases``, almost all of them up front, on the calling thread
+and in one order: the index and prefix columns of all runs side by
+side (each run's are slices, filled as it is read), a data buffer a
+run, the tombstone column, three operand stacks, a ring of
+per-partition sets, the bloom's hash pairs and bits.  A partition set
+returns to its ring when both the writer and the bloom thread have
+consumed its raw pointers; an operand stack when its launch's output
+has been read back (JAX may read the host array until the transfer
+completes); everything goes back to the pool when the merge's threads
+are joined — or is dropped: all of it where one of them is wedged, a
+failed merge's stacks (a launch may never have been read back).  What comes back is dirty: each stage fills what it
+reads, and only logical lengths reach C.  The pool keeps what recent
+merges used (a block idle for two merges is released; the free total
+stays within what they leased at once), so the second and every later
+merge of a process runs on pages that are already mapped.
 """
 
 from __future__ import annotations
@@ -66,14 +84,21 @@ from ..storage.entry import (
     COMPACT_DATA_FILE_EXT,
     COMPACT_INDEX_FILE_EXT,
     ENTRY_HEADER_SIZE,
+    INDEX_ENTRY_SIZE,
     file_name,
 )
+from .block_pool import ALIGN as _ALIGN
+from .block_pool import BlockPool, Leases
 from .spans import Stages
 
 log = logging.getLogger(__name__)
 
+# Every array of a merge that can reach 128 KiB is leased from this one
+# pool of the process and goes back when the merge's threads are joined
+# (ops/block_pool.py; the module docstring's last paragraph).
+_POOL = BlockPool(note=compaction_stats.note_pool)
+
 SENTINEL = np.uint32(0xFFFFFFFF)
-_ALIGN = 4096
 # Per-(run, partition) kernel rows: pow2-padded; partitions are split
 # until every slice fits.
 _MAX_P2 = 1 << 17
@@ -90,6 +115,8 @@ _MAX_KP = 1 << 20
 # process, and a bound per merge would let two shards' big merges put
 # four such programs on the one chip.
 _LAUNCH_SLOTS = threading.BoundedSemaphore(2)
+# Entries a step of the tombstone column: 64 KiB temporaries.
+_TOMB_STEP = 1 << 14
 # Per-partition row target used to pick the partition count.
 _PAD_WASTE_LIMIT = 0.12
 # A shifted-u32 partition whose within-run duplicate excess (collisions
@@ -139,29 +166,23 @@ def _pow2(n: int) -> int:
     return p
 
 
-def _aligned_empty(size: int) -> np.ndarray:
-    """uint8 buffer whose base address and capacity are 4KiB-aligned
-    (O_DIRECT contract of dbeel_read_file)."""
-    cap = (size + _ALIGN - 1) & ~(_ALIGN - 1)
-    raw = np.empty(cap + _ALIGN, dtype=np.uint8)
-    off = (-raw.ctypes.data) % _ALIGN
-    return raw[off : off + cap]
-
-
 @dataclass
 class _Run:
-    data: np.ndarray  # uint8 (aligned), logical [:size]
+    data: np.ndarray  # uint8 (4 KiB-aligned, leased), logical [:size]
     size: int
     offsets: np.ndarray  # u64 within-run record offsets
     key_size: np.ndarray  # u32
     full_size: np.ndarray  # u32
-    prefix64: np.ndarray = field(default=None)  # (n,) >u8 padded prefix
+    prefix64: np.ndarray = field(default=None)  # (n,) u64 padded prefix
 
 
-def _read_run(lib, source) -> _Run:
-    offs, ks, fs = source.read_index_columns()
+def _read_run(lib, source, buf, cols, scratch) -> _Run:
+    """``buf``: the run's data buffer, 4 KiB-aligned in base and length
+    (the O_DIRECT contract of dbeel_read_file); ``cols``: where its
+    index columns go (its slices of ``off_cat``, ``ks_cat``,
+    ``fs_cat``); ``scratch``: what the index file is read into."""
+    offs, ks, fs = source.read_index_columns(out=cols, scratch=scratch)
     size = source.data_size
-    buf = _aligned_empty(size)
     if size:
         got = lib.dbeel_read_file(
             source.data_path.encode(),
@@ -172,33 +193,36 @@ def _read_run(lib, source) -> _Run:
             raise OSError(
                 f"short read {got} != {size} for {source.data_path}"
             )
-    return _Run(buf, size, offs.astype(np.uint64), ks, fs)
+    return _Run(buf, size, offs, ks, fs)
 
 
-def _stage_prefixes(run: _Run, lib=None) -> None:
-    """Fill run.prefix64: the zero-padded 8-byte big-endian key prefix
-    per entry as one >u8 value (splitters, searchsorted, and the
-    per-partition rebase that feeds the device operand).  Prefers the
-    C stager — the numpy paths held the GIL ~90ms per 1.25M-key run,
-    measured as back-to-back serving stalls at compaction start."""
+def _stage_prefixes(run: _Run, out: np.ndarray, lib=None) -> None:
+    """Fill run.prefix64 = ``out`` (the run's slice of the leased
+    ``pf_cat``): the zero-padded 8-byte big-endian key prefix per entry
+    as one native u64 value (splitters, searchsorted, the
+    per-partition rebase that feeds the device operand, the native
+    decoder).  Prefers the C stager — the numpy paths held the GIL
+    ~90ms per 1.25M-key run, measured as back-to-back serving stalls at
+    compaction start."""
     n = run.offsets.size
+    run.prefix64 = out
     if n == 0:
-        run.prefix64 = np.zeros(0, dtype=">u8")
         return
     if lib is not None and hasattr(lib, "dbeel_stage_prefixes"):
-        pref = np.empty(n * 8, dtype=np.uint8)
-        offs = np.ascontiguousarray(run.offsets, dtype=np.uint64)
-        ks = np.ascontiguousarray(run.key_size, dtype=np.uint32)
         lib.dbeel_stage_prefixes(
             run.data.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
             ctypes.c_uint64(run.size),
-            offs.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
-            ks.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
+            run.offsets.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
+            run.key_size.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
             ctypes.c_uint64(n),
             ctypes.c_uint64(ENTRY_HEADER_SIZE),
-            pref.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+            out.view(np.uint8).ctypes.data_as(
+                ctypes.POINTER(ctypes.c_uint8)
+            ),
         )
-        run.prefix64 = pref.view(">u8").reshape(n)
+        # The stager writes key bytes, big-endian: one swap in place
+        # here, beside the reads, makes every later use native.
+        out.view(">u8").byteswap(inplace=True)
         return
     rec = int(run.full_size[0]) if run.full_size.size else 0
     uniform = (
@@ -224,7 +248,7 @@ def _stage_prefixes(run: _Run, lib=None) -> None:
             valid, run.data[pos.astype(np.int64)], 0
         ).astype(np.uint8)
         pref = np.ascontiguousarray(pref)
-    run.prefix64 = pref.view(">u8").reshape(n)
+    out[:] = pref.view(">u8").reshape(n)
 
 
 def max_partition_rows(n_runs: int) -> int:
@@ -246,7 +270,7 @@ def _choose_partitions(runs: List[_Run], launch_batch: int = None):
     max_run = max((r.prefix64.size for r in runs), default=0)
     total_rows = sum(r.prefix64.size for r in runs)
     if max_run == 0:
-        return np.zeros(0, dtype=">u8"), None, 8
+        return np.zeros(0, dtype=np.uint64), None, 8
     # Prefer enough partitions to fill at least TWO launch batches:
     # the pipeline's whole point is overlapping read/upload/kernel/
     # download/write, and with every partition in one batch the stages
@@ -296,7 +320,7 @@ def _choose_partitions(runs: List[_Run], launch_batch: int = None):
         for k in range(1, parts)
     ]
     # strictly increasing splitters (duplicates collapse partitions)
-    splitters = np.array(sorted(set(cut)), dtype=">u8")
+    splitters = np.array(sorted(set(cut)), dtype=np.uint64)
 
     def bounds_for(splits):
         return [
@@ -337,7 +361,7 @@ def _choose_partitions(runs: List[_Run], launch_batch: int = None):
         # halves.
         mid = uniq[(uniq.size - 1) // 2]
         splitters = np.array(
-            sorted(set(splitters.tolist()) | {int(mid)}), dtype=">u8"
+            sorted(set(splitters.tolist()) | {int(mid)}), dtype=np.uint64
         )
         bounds = bounds_for(splitters)
     else:
@@ -347,6 +371,32 @@ def _choose_partitions(runs: List[_Run], launch_batch: int = None):
 
 class _PipelineError(Exception):
     pass
+
+
+class _PartSet:
+    """One partition's arrays on their way from the decode to the
+    writer and the bloom thread, leased for ``rows`` entries; each
+    partition uses their heads.  ``holders``: the threads that have
+    yet to consume the partition's raw pointers."""
+
+    __slots__ = (
+        "gidx", "rids32", "tieb", "keep", "mask", "sel", "src_run",
+        "src_off", "ks_sel", "fs_sel", "holders",
+    )
+
+    def __init__(self, mem: Leases, rows: int) -> None:
+        self.gidx = mem.array(rows, np.int64)  # decoded global indices
+        self.rids32 = mem.array(rows, np.uint32)  # and their runs
+        self.tieb = mem.array(rows, np.uint8)  # device-key tie flags
+        self.keep = mem.array(rows, np.bool_)
+        # Scratch: the tie blocks' members, then the tombstones.
+        self.mask = mem.array(rows, np.bool_)
+        self.sel = mem.array(rows, np.int64)  # gidx[keep]
+        self.src_run = mem.array(rows, np.uint32)  # rids32[keep]
+        self.src_off = mem.array(rows, np.uint64)
+        self.ks_sel = mem.array(rows, np.uint32)
+        self.fs_sel = mem.array(rows, np.uint32)
+        self.holders = 0
 
 
 def pipeline_merge(
@@ -395,19 +445,30 @@ def pipeline_merge(
     close and joins that thread; ``close_wait`` is what then remains
     of the close's flush."""
     shape: dict = {}
-    with Stages("pipeline", "read_stage") as at:
-        result = _pipeline_merge_impl(
-            sources,
-            dir_path,
-            output_index,
-            keep_tombstones,
-            bloom_min_size,
-            mesh,
-            throttle,
-            tombstone_drop_before,
-            at=at,
-            shape=shape,
-        )
+    mem = _POOL.leases()
+    threads: List[threading.Thread] = []
+    try:
+        with Stages("pipeline", "read_stage") as at:
+            result = _pipeline_merge_impl(
+                sources,
+                dir_path,
+                output_index,
+                keep_tombstones,
+                bloom_min_size,
+                mesh,
+                throttle,
+                tombstone_drop_before,
+                at=at,
+                shape=shape,
+                mem=mem,
+                threads=threads,
+            )
+    finally:
+        # Every way out of the merge has joined its threads; one that
+        # outlived its join is wedged and may still read or write the
+        # blocks (the paths that leak the native handle): those are
+        # dropped, never handed to the next merge.
+        mem.close(drop=any(t.is_alive() for t in threads))
     # Counted here, once, for every caller of the pipeline.
     if result is None:
         compaction_stats.note_pipeline_decline()
@@ -416,21 +477,25 @@ def pipeline_merge(
     return result
 
 
-def _partition_operand(runs, bounds, p, k2, p2):
-    """Stage partition ``p``: choose the u32 (rebased+shifted) or exact
-    2-word operand, build the sentinel-padded host array.
+def _plan_operand(pf_cat, run_base, bounds, p, k2, tmp):
+    """Plan partition ``p``'s operand: each run's slice of the
+    native-endian prefixes, and the choice between the u32
+    (rebased+shifted) and the exact 2-word form.  ``tmp``: a u64
+    scratch of at least the kernel rows.
 
-    Returns (host, counts, los, mode32, minpf, shift)."""
+    Returns (slices, counts, los, mode32, minpf, shift); ``slices`` is
+    None where the partition is empty."""
     counts = np.zeros(k2, dtype=np.uint32)
-    los = np.zeros(len(runs), dtype=np.int64)
+    los = np.zeros(len(bounds), dtype=np.int64)
     slices = []
     minpf = None
     maxpf = None
-    for ri, (r, b) in enumerate(zip(runs, bounds)):
+    for ri, b in enumerate(bounds):
         lo, hi = int(b[p]), int(b[p + 1])
         los[ri] = lo
         counts[ri] = hi - lo
-        sl = r.prefix64[lo:hi]
+        base = int(run_base[ri])
+        sl = pf_cat[base + lo : base + hi]
         slices.append(sl)
         if hi > lo:
             first, last = int(sl[0]), int(sl[-1])
@@ -442,10 +507,6 @@ def _partition_operand(runs, bounds, p, k2, p2):
     span = maxpf - minpf
     shift = max(0, span.bit_length() - 32)
     mode32 = True
-    shifted = [
-        (sl.astype(np.uint64) - np.uint64(minpf)) >> np.uint64(shift)
-        for sl in slices
-    ]
     if shift:
         # Within-run duplicate excess introduced by the shift (beyond
         # genuine 8-byte-prefix ties): if the shift collapses dense
@@ -453,30 +514,43 @@ def _partition_operand(runs, bounds, p, k2, p2):
         # keep the exact operand there instead.
         d32 = 0
         d64 = 0
-        for sl, v in zip(slices, shifted):
+        for sl in slices:
             if sl.size < 2:
                 continue
-            d32 += int((v[1:] == v[:-1]).sum())
-            d64 += int((sl[1:] == sl[:-1]).sum())
+            v = _shifted(sl, minpf, shift, tmp)
+            d32 += int(np.count_nonzero(v[1:] == v[:-1]))
+            d64 += int(np.count_nonzero(sl[1:] == sl[:-1]))
         if d32 - d64 > _SHIFT_DUP_LIMIT * n_p:
             mode32 = False
-    if mode32:
-        host = np.full((k2, p2), SENTINEL, dtype=np.uint32)
-        for ri, v in enumerate(shifted):
-            if v.size:
-                host[ri, : v.size] = v.astype(np.uint32)
-    else:
-        host = np.full((k2, p2, 2), SENTINEL, dtype=np.uint32)
-        for ri, sl in enumerate(slices):
-            if sl.size:
-                v = sl.astype(np.uint64)
-                host[ri, : sl.size, 0] = (v >> np.uint64(32)).astype(
-                    np.uint32
-                )
-                host[ri, : sl.size, 1] = (
-                    v & np.uint64(0xFFFFFFFF)
-                ).astype(np.uint32)
-    return host, counts, los, mode32, minpf, shift
+    return slices, counts, los, mode32, minpf, shift
+
+
+def _shifted(sl, minpf, shift, tmp):
+    """(sl - minpf) >> shift in ``tmp``'s head."""
+    v = tmp[: sl.size]
+    np.subtract(sl, np.uint64(minpf), out=v)
+    np.right_shift(v, np.uint64(shift), out=v)
+    return v
+
+
+def _fill_operand(dest, slices, mode32, minpf, shift, tmp):
+    """Write a planned partition's sentinel-padded operand into
+    ``dest``, one slot of a launch's stack — (k2, p2) u32, or
+    (k2, p2, 2) for the exact form.  ``dest`` is dirty: every word of
+    it is written."""
+    for ri, sl in enumerate(slices):
+        n = sl.size
+        if n:
+            if mode32:
+                dest[ri, :n] = _shifted(sl, minpf, shift, tmp)
+            else:
+                v = tmp[:n]
+                np.right_shift(sl, np.uint64(32), out=v)
+                dest[ri, :n, 0] = v
+                np.bitwise_and(sl, np.uint64(0xFFFFFFFF), out=v)
+                dest[ri, :n, 1] = v
+        dest[ri, n:] = SENTINEL
+    dest[len(slices) :] = SENTINEL
 
 
 def _gather_tie_arrays(runs, run_base, off_cat, ks_cat, sel, lpad):
@@ -550,9 +624,14 @@ def _pipeline_merge_impl(
     *,
     at: Stages,
     shape: dict,
+    mem: Leases,
+    threads: List[threading.Thread],
 ) -> Optional[MergeResult]:
     """``shape``: filled, where the merge produces an output, with its
-    ``compaction.PIPELINE_SHAPE`` counts."""
+    ``compaction.PIPELINE_SHAPE`` counts.  ``mem``: what every large
+    array is leased from; the caller closes it.  ``threads``: every
+    thread started here that works in leased memory is appended, for
+    the caller to see whether one is still alive."""
     from ..storage import native as native_mod
 
     lib = native_mod.require()
@@ -579,9 +658,45 @@ def _pipeline_merge_impl(
         1, int(os.environ.get("DBEEL_PIPE_READERS", "2") or 2)
     )
 
+    # Leased up front, on this thread and in one order, so that a
+    # merge of the same inputs leases the same blocks: the index
+    # columns and key prefixes of all runs side by side (each run's
+    # are slices, filled as it is read), one data buffer a run, one
+    # index-file scratch a reader.
+    counts_all = np.array(
+        [s.entry_count for s in sources], dtype=np.int64
+    )
+    run_base = np.zeros(len(sources) + 1, dtype=np.int64)
+    np.cumsum(counts_all, out=run_base[1:])
+    total_rows = int(run_base[-1])
+    off_cat = mem.array(total_rows, np.uint64)
+    ks_cat = mem.array(total_rows, np.uint32)
+    fs_cat = mem.array(total_rows, np.uint32)
+    pf_cat = mem.array(total_rows, np.uint64)
+    bufs = [
+        mem.array((s.data_size + _ALIGN - 1) & ~(_ALIGN - 1))
+        for s in sources
+    ]
+    scratch_q: "queue.Queue" = queue.Queue()
+    for _ in range(min(n_readers, len(sources))):
+        scratch_q.put(
+            mem.array(int(counts_all.max()) * INDEX_ENTRY_SIZE)
+        )
+
     def read_run(i, source):
         with span("read_run", run=i):
-            return _read_run(lib, source)
+            lo, hi = int(run_base[i]), int(run_base[i + 1])
+            scratch = scratch_q.get()
+            try:
+                return _read_run(
+                    lib,
+                    source,
+                    bufs[i],
+                    (off_cat[lo:hi], ks_cat[lo:hi], fs_cat[lo:hi]),
+                    scratch,
+                )
+            finally:
+                scratch_q.put(scratch)
 
     with ThreadPoolExecutor(max_workers=n_readers) as io:
         futs = [io.submit(read_run, i, s) for i, s in enumerate(sources)]
@@ -589,7 +704,9 @@ def _pipeline_merge_impl(
         for i, f in enumerate(futs):
             r = f.result()
             with span("stage_prefixes", run=i):
-                _stage_prefixes(r, lib)
+                _stage_prefixes(
+                    r, pf_cat[run_base[i] : run_base[i + 1]], lib
+                )
             runs.append(r)
     at.to("plan")
     # Mesh mode: widen the launch batch to a device multiple and shard
@@ -619,36 +736,21 @@ def _pipeline_merge_impl(
     k2 = _pow2(max(1, len(runs)))
     pack_bits = rid_pack_bits(k2)
 
-    counts_all = np.array(
-        [r.offsets.size for r in runs], dtype=np.int64
+    tomb_cat = None
+    if not keep_tombstones:
+        tomb_cat = mem.array(total_rows, np.bool_)
+        # In steps whose temporaries stay small enough for the heap.
+        hdr = np.uint32(ENTRY_HEADER_SIZE)
+        for lo in range(0, total_rows, _TOMB_STEP):
+            hi = lo + _TOMB_STEP
+            np.equal(
+                fs_cat[lo:hi], ks_cat[lo:hi] + hdr, out=tomb_cat[lo:hi]
+            )
+    # Most entries of one partition over all runs: what a partition's
+    # set of arrays (below) is sized for.
+    max_np = (
+        int(sum(np.diff(b) for b in bounds).max()) if n_parts else 0
     )
-    run_base = np.zeros(len(runs) + 1, dtype=np.int64)
-    np.cumsum(counts_all, out=run_base[1:])
-
-    off_cat = (
-        np.concatenate([r.offsets for r in runs])
-        if runs
-        else np.zeros(0, np.uint64)
-    )
-    ks_cat = (
-        np.concatenate([r.key_size for r in runs])
-        if runs
-        else np.zeros(0, np.uint32)
-    )
-    fs_cat = (
-        np.concatenate([r.full_size for r in runs])
-        if runs
-        else np.zeros(0, np.uint32)
-    )
-    # Native-endian u64 prefixes: one bulk byteswap here replaces the
-    # per-partition BE->native astype in the consume loop AND feeds
-    # the native decoder directly.
-    pf_cat = (
-        np.concatenate([r.prefix64 for r in runs]).astype(np.uint64)
-        if runs
-        else np.zeros(0, np.uint64)
-    )
-    tomb_cat = fs_cat == ks_cat + np.uint32(ENTRY_HEADER_SIZE)
     have_decode = hasattr(lib, "dbeel_pipe_decode")
 
     data_path = f"{dir_path}/{file_name(output_index, COMPACT_DATA_FILE_EXT)}"
@@ -703,25 +805,57 @@ def _pipeline_merge_impl(
         return True
 
     launches = itertools.count()
+    # Operand stacks: flat u32 buffers of the one-word launch's size
+    # (the two-word form re-leases twice that), leased here and cycled
+    # through ``stack_free``.  JAX may read a stack handed to
+    # device_put until the transfer completes, so a stack is refilled
+    # only after its launch's output has been read back: the downloader
+    # puts it back beside the launch's permit.  Three: two launches in
+    # flight (_LAUNCH_SLOTS) and the next being filled.
+    stack_words = launch_j * k2 * p2
+    stacks = [
+        mem.array(stack_words, np.uint32)
+        for _ in range(min(3, -(-n_parts // launch_j)))
+    ]
+    stack_free: "queue.Queue" = queue.Queue()
+    for buf in stacks:
+        stack_free.put(buf)
+    # The upload thread's scratch for rebased prefixes.
+    shift_tmp = mem.array(p2 if n_parts else 0, np.uint64)
 
-    def _launch_batch(metas, hosts, mode32):
+    def _take_stack(mode32):
+        """A free stack as (buffer, array in the launch's shape); None
+        where the merge stopped."""
+        while True:
+            try:
+                buf = stack_free.get(timeout=0.25)
+                break
+            except queue.Empty:
+                if stop.is_set():
+                    return None
+        words = stack_words * (1 if mode32 else 2)
+        if buf.size < words:
+            slot = next(i for i, b in enumerate(stacks) if b is buf)
+            mem.give(buf)
+            buf = stacks[slot] = mem.array(words, np.uint32)
+        tail = () if mode32 else (2,)
+        return buf, buf[:words].reshape((launch_j, k2, p2) + tail)
+
+    def _launch_batch(metas, taken, mode32):
         """One vmapped launch over up to ``launch_j`` same-mode
         partitions, empty-slot padded to a single compiled shape; the
-        batch axis shards over the mesh when one is supplied."""
+        batch axis shards over the mesh when one is supplied.
+        ``taken``: the stack from _take_stack, its first len(metas)
+        slots filled."""
         j = launch_j
+        buf, stack = taken
         # One launch's spans share ``launch`` (its ordinal in the
         # merge) and ``part`` (its first partition).
         ids = {"launch": next(launches), "part": metas[0][0]}
         with span("operand", **ids):
-            if mode32:
-                stack = np.full((j, k2, p2), SENTINEL, dtype=np.uint32)
-            else:
-                stack = np.full(
-                    (j, k2, p2, 2), SENTINEL, dtype=np.uint32
-                )
+            stack[len(metas) :] = SENTINEL
             counts = np.zeros((j, k2), dtype=np.uint32)
-            for slot, (meta, host) in enumerate(zip(metas, hosts)):
-                stack[slot] = host
+            for slot, meta in enumerate(metas):
                 counts[slot] = meta[1]
         with span("slot_wait", **ids):
             while not _LAUNCH_SLOTS.acquire(timeout=0.25):
@@ -744,19 +878,19 @@ def _pipeline_merge_impl(
                 out = merge_runs_prefix64_packed_batch_kernel(
                     dev, cnt, pack_bits
                 )
-        kernel_q.put((metas, out, ids))
+        kernel_q.put((metas, out, ids, buf))
 
     def upload():
         try:
             metas: list = []  # (p, counts, los, mode32, minpf, shift)
-            hosts: list = []
+            taken = None  # the stack the pending batch is filled into
             batch_mode = True
 
             def flush():
-                nonlocal metas, hosts
+                nonlocal metas, taken
                 if metas:
-                    _launch_batch(metas, hosts, batch_mode)
-                    metas, hosts = [], []
+                    _launch_batch(metas, taken, batch_mode)
+                    metas, taken = [], None
 
             for p in range(n_parts):
                 # Timed acquire + stop checks: if the downloader dies
@@ -769,23 +903,38 @@ def _pipeline_merge_impl(
                 if stop.is_set():
                     return
                 with span("operand", part=p):
-                    host, counts, los, mode32, minpf, shift = (
-                        _partition_operand(runs, bounds, p, k2, p2)
+                    slices, counts, los, mode32, minpf, shift = (
+                        _plan_operand(
+                            pf_cat, run_base, bounds, p, k2, shift_tmp
+                        )
                     )
-                if host is None:
+                if slices is None:
                     # Keep strict partition order: launch whatever is
                     # pending first, THEN the empty marker (the
                     # downloader releases this partition's permit).
                     flush()
                     kernel_q.put(
-                        ([(p, counts, los, True, 0, 0)], None, None)
+                        ([(p, counts, los, True, 0, 0)], None, None, None)
                     )
                     continue
                 if metas and mode32 != batch_mode:
                     flush()
                 batch_mode = mode32
+                if taken is None:
+                    with span("slot_wait", part=p):
+                        taken = _take_stack(mode32)
+                    if taken is None:
+                        return
+                with span("operand", part=p):
+                    _fill_operand(
+                        taken[1][len(metas)],
+                        slices,
+                        mode32,
+                        minpf,
+                        shift,
+                        shift_tmp,
+                    )
                 metas.append((p, counts, los, mode32, minpf, shift))
-                hosts.append(host)
                 if len(metas) == launch_j:
                     flush()
             flush()
@@ -812,12 +961,13 @@ def _pipeline_merge_impl(
                     stop.set()
                     order_q.put(item)
                     return
-                metas, out, ids = item
+                metas, out, ids, buf = item
                 if out is not None:
                     # The kernel's completion + the d2h of its
                     # bit-packed run-ids.
                     with span("d2h", **ids):
                         words = np.asarray(out)
+                    stack_free.put(buf)
                     _release_slot()
                     for slot, meta in enumerate(metas):
                         in_flight.release()
@@ -831,6 +981,7 @@ def _pipeline_merge_impl(
 
     t_up = threading.Thread(target=upload, daemon=True)
     t_down = threading.Thread(target=download, daemon=True)
+    threads += [t_up, t_down]
     t_up.start()
     t_down.start()
 
@@ -843,6 +994,24 @@ def _pipeline_merge_impl(
     write_q: "queue.Queue" = queue.Queue(maxsize=4)
     writer_state = {"wrote": 0, "bytes": 0, "error": None}
     have_sync = hasattr(lib, "dbeel_writer_sync")
+    will_bloom = total_input >= bloom_min_size
+    # A partition's arrays, from its decode to the writer and the bloom
+    # thread, are one of these sets, each sized for the largest
+    # partition and dirty from its last.  As many as can be alive: the
+    # one in the caller's hands, write_q's, the writer's.  A set is
+    # free again when BOTH the writer and the bloom thread have
+    # consumed its raw pointers.
+    sets_free: "queue.Queue" = queue.Queue()
+    for _ in range(min(n_parts, write_q.maxsize + 2)):
+        sets_free.put(_PartSet(mem, max_np))
+    sets_lock = threading.Lock()
+
+    def _set_done(pset):
+        with sets_lock:
+            pset.holders -= 1
+            idle = pset.holders == 0
+        if idle:
+            sets_free.put(pset)
 
     def writer():
         try:
@@ -855,9 +1024,10 @@ def _pipeline_merge_impl(
                     continue
                 if job is None:
                     return
-                sel_sz, args, nbytes, _arrays, p = job
+                sel_sz, args, nbytes, pset, p = job
                 with span("gather_write", part=p):
                     rc = lib.dbeel_writer_put(handle, run_ptrs, *args)
+                _set_done(pset)
                 if rc != 0:
                     writer_state["error"] = _PipelineError(
                         "native gather-write failed"
@@ -897,13 +1067,18 @@ def _pipeline_merge_impl(
     bloom_q: "queue.Queue" = queue.Queue()
     bloom_state = {"blob": None, "error": None}
 
+    if will_bloom:
+        # Two hashes a key, and the bits of the largest filter the
+        # output can need (it never has more entries than the input).
+        pairs = mem.array(2 * total_rows, np.uint32)
+        bits_buf = mem.array((BloomFilter.size_for(total_rows)[0] + 7) // 8)
+
     def bloomer(runs):
         # ``runs``: the raw pointers in run_ptrs are only as alive as
         # these buffers, whatever becomes of the caller's frame.
         try:
             u32p = ctypes.POINTER(ctypes.c_uint32)
             u64p = ctypes.POINTER(ctypes.c_uint64)
-            pairs = np.empty(2 * int(run_base[-1]), dtype=np.uint32)
             hashed = 0
             while True:
                 # Timed get + stop check, as the writer's.
@@ -915,7 +1090,7 @@ def _pipeline_merge_impl(
                     continue
                 if not isinstance(item, tuple):
                     break
-                p, src_run, src_off, ks_sel = item
+                p, src_run, src_off, ks_sel, pset = item
                 with span("bloom_hash", part=p):
                     lib.dbeel_bloom_hash_gather(
                         run_ptrs,
@@ -929,13 +1104,17 @@ def _pipeline_merge_impl(
                         pairs[2 * hashed :].ctypes.data_as(u32p),
                     )
                 hashed += src_run.size
+                _set_done(pset)
             # ``item`` is the output's entry count, or 0 where the
             # output ended under bloom_min_size: the hashes are dropped.
             if not item:
                 return
             assert item == hashed
             with span("bloom_set"):
-                bloom = BloomFilter.with_capacity(hashed)
+                num_bits, num_hashes = BloomFilter.size_for(hashed)
+                bits = bits_buf[: (num_bits + 7) // 8]
+                bits.fill(0)
+                bloom = BloomFilter(num_bits, num_hashes, bits=bits)
                 lib.dbeel_bloom_set_hashes(
                     bloom.bits.ctypes.data_as(
                         ctypes.POINTER(ctypes.c_uint8)
@@ -952,24 +1131,28 @@ def _pipeline_merge_impl(
             bloom_state["error"] = e
 
     t_write = threading.Thread(target=writer, daemon=True)
+    threads.append(t_write)
     t_write.start()
     t_bloom = None
-    if total_input >= bloom_min_size:
+    if will_bloom:
         t_bloom = threading.Thread(
             target=bloomer,
             args=(runs,),
             name="dbeel-pipeline-bloom",
             daemon=True,
         )
+        threads.append(t_bloom)
         t_bloom.start()
     t_sync = None
     if _SYNC_STRIDE <= 0:
         have_sync = False  # disabled: one flush at close only
     if have_sync:
         t_sync = threading.Thread(target=syncer, daemon=True)
+        threads.append(t_sync)
         t_sync.start()
 
     queued = queued_bytes = tie_entries = 0
+    failed = False
     try:
         expected = 0
         while True:
@@ -992,12 +1175,25 @@ def _pipeline_merge_impl(
             if isinstance(item, BaseException):
                 raise item
             (p, counts, los, mode32, minpf, shift), packed = item
+            n_p = int(counts.sum())
+            pset = None
+            if n_p and sets_free.empty():
+                # Every set is queued or being written: the writer's
+                # queue is full, one step early.
+                at.to("wait_writer", part=p)
+            while n_p and pset is None:
+                try:
+                    pset = sets_free.get(timeout=0.25)
+                except queue.Empty:
+                    if stop.is_set() or writer_state["error"]:
+                        raise writer_state["error"] or _PipelineError(
+                            "writer stopped"
+                        )
             at.to("decode", part=p)
             if writer_state["error"] is not None:
                 raise writer_state["error"]
             assert p == expected
             expected += 1
-            n_p = int(counts.sum())
             if n_p == 0:
                 continue
             if have_decode:
@@ -1006,9 +1202,9 @@ def _pipeline_merge_impl(
                 # numpy unpack/bincount/argsort/cumcount chain — on a
                 # 1-core host this decode was ~40% of the pipeline's
                 # host CPU.
-                gidx = np.empty(n_p, dtype=np.int64)
-                rids32 = np.empty(n_p, dtype=np.uint32)
-                tieb = np.empty(n_p, dtype=np.uint8)
+                gidx = pset.gidx[:n_p]
+                rids32 = pset.rids32[:n_p]
+                tieb = pset.tieb[:n_p]
                 packed_c = np.ascontiguousarray(packed)
                 cnts_c = np.ascontiguousarray(
                     counts[: len(runs)], dtype=np.uint32
@@ -1090,10 +1286,11 @@ def _pipeline_merge_impl(
                     flags = dv[1:] == dv[:-1]
                 else:
                     flags = pf[1:] == pf[:-1]
-            keep = np.ones(n_p, dtype=bool)
+            keep = pset.keep[:n_p]
+            keep.fill(True)
             with span("tie_fixup", part=p):
                 positions, block_id = columnar.tie_positions_and_blocks(
-                    flags
+                    flags, pset.mask
                 )
                 tie_entries += int(positions.size)
                 if positions.size:
@@ -1125,12 +1322,14 @@ def _pipeline_merge_impl(
                         keep[positions[bm]] = ~dup
 
             if not keep_tombstones:
-                drop = tomb_cat[gidx]
+                # (mode="clip": numpy buffers ``out`` under "raise".)
+                drop = np.take(
+                    tomb_cat, gidx, out=pset.mask[:n_p], mode="clip"
+                )
                 if tombstone_drop_before and drop.any():
                     # gc_grace: tombstones younger than the cutoff
                     # survive the drop.  Timestamps are gathered only
                     # for the drop candidates.
-                    drop = drop.copy()
                     cand = np.flatnonzero(drop)
                     cand_ts = _gather_timestamps(
                         runs, run_base, off_cat, gidx[cand]
@@ -1141,40 +1340,40 @@ def _pipeline_merge_impl(
                             >= np.uint64(tombstone_drop_before)
                         ]
                     ] = False
-                keep &= ~drop
-            if not keep.all():
-                sel = gidx[keep]
-                src_run = np.ascontiguousarray(rids32[keep])
+                np.logical_not(drop, out=drop)
+                keep &= drop
+            m = int(np.count_nonzero(keep))
+            if m == 0:
+                sets_free.put(pset)
+                continue
+            if m != n_p:
+                sel = np.compress(keep, gidx, out=pset.sel[:m])
+                src_run = np.compress(keep, rids32, out=pset.src_run[:m])
             else:
                 sel = gidx
                 src_run = np.ascontiguousarray(rids32)
-            if sel.size == 0:
-                continue
-            src_off = np.ascontiguousarray(off_cat[sel])
-            ks_sel = np.ascontiguousarray(ks_cat[sel])
-            fs_sel = np.ascontiguousarray(fs_cat[sel])
+            src_off = np.take(
+                off_cat, sel, out=pset.src_off[:m], mode="clip"
+            )
+            ks_sel = np.take(ks_cat, sel, out=pset.ks_sel[:m], mode="clip")
+            fs_sel = np.take(fs_cat, sel, out=pset.fs_sel[:m], mode="clip")
             args = (
                 src_run.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
                 src_off.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
                 ks_sel.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
                 fs_sel.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-                ctypes.c_uint64(sel.size),
+                ctypes.c_uint64(m),
             )
             nbytes = int(fs_sel.sum())
-            # The queue item carries the numpy arrays so they stay
-            # alive exactly until the writer thread has consumed the
-            # raw pointers (the bounded queue caps live jobs).
-            job = (
-                int(sel.size),
-                args,
-                nbytes,
-                (src_run, src_off, ks_sel, fs_sel),
-                p,
-            )
-            queued += int(sel.size)
+            # The set stays out of ``sets_free`` exactly until the
+            # writer and the bloom thread have consumed the raw
+            # pointers (the number of sets caps the live jobs).
+            pset.holders = 2 if t_bloom is not None else 1
+            job = (m, args, nbytes, pset, p)
+            queued += m
             queued_bytes += nbytes
             if t_bloom is not None:
-                bloom_q.put((p, src_run, src_off, ks_sel))
+                bloom_q.put((p, src_run, src_off, ks_sel, pset))
             at.to("wait_writer", part=p)
             while True:
                 try:
@@ -1202,6 +1401,7 @@ def _pipeline_merge_impl(
         if writer_state["error"] is not None:
             raise writer_state["error"]
     except BaseException:
+        failed = True
         stop.set()
         t_write.join(timeout=60)
         # Joined before ``runs`` can go: it hashes through run_ptrs.
@@ -1231,6 +1431,11 @@ def _pipeline_merge_impl(
         t_down.join(timeout=60)
         while _release_slot():
             pass
+        if failed:
+            # A launch that was never read back may still be reading
+            # its stack: a failed merge's stacks are not returned.
+            for buf in stacks:
+                mem.forget(buf)
 
     sync_done.set()
     if t_sync is not None:
@@ -1299,6 +1504,7 @@ def _pipeline_merge_impl(
                 )
 
     t_close = threading.Thread(target=_close, daemon=True)
+    threads.append(t_close)
     t_close.start()
 
     entries = writer_state["wrote"]

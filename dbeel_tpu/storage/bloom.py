@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import math
 import struct
-from typing import Iterable, Optional
+from typing import Iterable, Optional, Tuple
 
 import numpy as np
 
@@ -28,19 +28,36 @@ _HEADER = struct.Struct("<QII")  # num_bits, num_hashes, reserved
 
 
 class BloomFilter:
-    def __init__(self, num_bits: int, num_hashes: int) -> None:
+    def __init__(
+        self, num_bits: int, num_hashes: int, bits: Optional[np.ndarray] = None
+    ) -> None:
+        """``bits``: the caller's own zeroed uint8 array of
+        ``(num_bits + 7) // 8`` bytes, in place of a fresh one."""
         self.num_bits = max(64, int(num_bits))
         self.num_hashes = max(1, int(num_hashes))
-        self.bits = np.zeros((self.num_bits + 7) // 8, dtype=np.uint8)
+        nbytes = (self.num_bits + 7) // 8
+        if bits is None:
+            bits = np.zeros(nbytes, dtype=np.uint8)
+        elif bits.dtype != np.uint8 or bits.shape != (nbytes,):
+            raise ValueError(
+                f"bloom bits must be {nbytes} uint8, got "
+                f"{bits.dtype} {bits.shape}"
+            )
+        self.bits = bits
+
+    @staticmethod
+    def size_for(n_items: int, fp_rate: float = 0.01) -> Tuple[int, int]:
+        """(num_bits, num_hashes) of a filter for ``n_items`` keys."""
+        n = max(1, n_items)
+        m = int(-n * math.log(fp_rate) / (math.log(2) ** 2)) + 1
+        k = max(1, round(m / n * math.log(2)))
+        return max(64, m), k
 
     @classmethod
     def with_capacity(
         cls, n_items: int, fp_rate: float = 0.01
     ) -> "BloomFilter":
-        n = max(1, n_items)
-        m = int(-n * math.log(fp_rate) / (math.log(2) ** 2)) + 1
-        k = max(1, round(m / n * math.log(2)))
-        return cls(m, k)
+        return cls(*cls.size_for(n_items, fp_rate))
 
     def _indices(self, key: bytes) -> np.ndarray:
         h1 = murmur3_32(key, _SEED1)

@@ -216,15 +216,21 @@ def _flags_to_runs(flags: np.ndarray) -> List[Tuple[int, int]]:
     return runs
 
 
-def tie_positions_and_blocks(flags: np.ndarray):
+def tie_positions_and_blocks(flags: np.ndarray, scratch=None):
     """Adjacent-pair tie flags (n-1,) → (positions, block_id): the
     sorted positions participating in any tie block, and a 0-based
     block index per position.  Blocks are maximal chains of flagged
     pairs; a False flag between two flagged pairs separates blocks even
-    when the positions are contiguous."""
+    when the positions are contiguous.  ``scratch``: a bool buffer of at
+    least n entries to mark the block members in, in place of a fresh
+    one."""
     if flags.size == 0 or not flags.any():
         return np.zeros(0, np.int64), np.zeros(0, np.int64)
-    in_block = np.zeros(flags.size + 1, dtype=bool)
+    if scratch is None:
+        in_block = np.zeros(flags.size + 1, dtype=bool)
+    else:
+        in_block = scratch[: flags.size + 1]
+        in_block.fill(False)
     in_block[:-1] |= flags
     in_block[1:] |= flags
     positions = np.flatnonzero(in_block)

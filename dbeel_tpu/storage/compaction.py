@@ -85,6 +85,13 @@ PIPELINE_SHAPE = (
     "tie_entries",
 )
 
+# The pipeline's block pool (ops/block_pool.py).  Counters: leases,
+# those a free block served, the capacity leased and the part of it
+# that had to be newly allocated.  Gauges: bytes of free blocks the
+# pool keeps, bytes out with merges (0 between merges).
+POOL_COUNTERS = ("leases", "hits", "bytes_leased", "bytes_fresh")
+POOL_GAUGES = ("retained_bytes", "leased_bytes")
+
 
 class CompactionStats:
     """Process-wide single-pass compaction/flush accounting
@@ -125,6 +132,7 @@ class CompactionStats:
         # skew) and another device path then produced.
         self.pipeline_declines = 0
         self.shape = {name: 0 for name in PIPELINE_SHAPE}
+        self.pool = {name: 0 for name in POOL_COUNTERS + POOL_GAUGES}
         # Merges between start and end right now (compile included),
         # and merges that raised.
         self.merges_running = 0
@@ -157,6 +165,17 @@ class CompactionStats:
             self.paths["pipeline"] += 1
             for name, count in shape.items():
                 self.shape[name] += int(count)
+
+    def note_pool(
+        self, retained_bytes: int, leased_bytes: int, **adds: int
+    ) -> None:
+        """The block pool leased, took back or released blocks:
+        POOL_COUNTERS name -> increment, and where the gauges stand."""
+        with self._lock:
+            for name, count in adds.items():
+                self.pool[name] += count
+            self.pool["retained_bytes"] = retained_bytes
+            self.pool["leased_bytes"] = leased_bytes
 
     def note_merge_running(self, delta: int) -> None:
         with self._lock:
@@ -242,6 +261,7 @@ class CompactionStats:
                 "paths": dict(self.paths),
                 "pipeline_declines": self.pipeline_declines,
                 "shape": dict(self.shape),
+                "pool": dict(self.pool),
                 "merges_running": self.merges_running,
                 "merges_failed": self.merges_failed,
                 "stages": {

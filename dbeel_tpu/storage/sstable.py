@@ -540,11 +540,24 @@ class SSTable:
 
     # -- bulk columnar access (device compaction path) ------------------
 
-    def read_index_columns(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    def read_index_columns(
+        self, out=None, scratch=None
+    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Whole index file as (offsets u64, key_sizes u32, full_sizes u32)
-        column arrays in one read — the host→device staging format."""
+        column arrays in one read — the host→device staging format.
+
+        ``out``: three destination arrays of ``entry_count`` each, which
+        are filled and returned in place of fresh ones.  ``scratch``: a
+        contiguous uint8 buffer of at least the index file's size that
+        the file is read into, in place of a fresh ``bytes``.  (The
+        device pipeline passes both from its block pool.)"""
+        nbytes = self.entry_count * INDEX_ENTRY_SIZE
         with open(self.index_path, "rb") as f:
-            raw = f.read(self.entry_count * INDEX_ENTRY_SIZE)
+            if scratch is None:
+                raw = f.read(nbytes)
+            else:
+                raw = memoryview(scratch)[:nbytes]
+                raw = raw[: f.readinto(raw)]
         self._verify_whole(raw, "index")
         rec = np.frombuffer(
             raw,
@@ -552,11 +565,15 @@ class SSTable:
                 [("offset", "<u8"), ("key_size", "<u4"), ("full_size", "<u4")]
             ),
         )
-        return (
-            rec["offset"].copy(),
-            rec["key_size"].copy(),
-            rec["full_size"].copy(),
-        )
+        if out is None:
+            return (
+                rec["offset"].copy(),
+                rec["key_size"].copy(),
+                rec["full_size"].copy(),
+            )
+        for dst, name in zip(out, ("offset", "key_size", "full_size")):
+            np.copyto(dst, rec[name])
+        return tuple(out)
 
     def read_data_bytes(self) -> bytes:
         """Whole data file in one bulk read (bypasses the page cache on
