@@ -1,61 +1,91 @@
-"""Partitioned, fully-overlapped device compaction pipeline.
+"""The device compaction pipeline: one merge as a few one-way boxes.
 
-Round 1's device path ran read → stage → h2d → kernel → d2h → gather →
-write strictly in sequence, so ~96% of a 10M-key major compaction was
-host time with the device idle (VERDICT round 1).  Round 2 replaced the
-serial host pipeline with a keyspace-partitioned software pipeline in
-which every stage runs concurrently on its own partition:
+::
 
-  upload thread    O_DIRECT bulk reads (native C++), 8-byte-prefix
-                   staging, per-partition device_put + kernel dispatch
-  download thread  per-partition packed run-id d2h off the async device
-                   queue
-  caller thread    permutation rebuild → vectorized tie fixup → dedup →
-                   tombstone filter → native C++ gather + O_DIRECT
-                   streaming write
+  sources -> inputs -> plan -> launcher -> downloader -> decode -> output -> MergeResult
+             reader    caller  upload      download      caller    writer, bloom
+             pool              thread      thread                  and close threads
 
-Round 3 cut the transfer volume (what that buys on a chip-local host is
-not measured on the current chip):
+What each box is, which thread runs it, and what crosses each arrow:
 
-  * Uplink (half): each partition's 8-byte prefixes are rebased to the
-    partition minimum and right-shifted until the span fits 32 bits —
-    an order-preserving u32 approximation, ONE word per entry instead
-    of two.  Collisions under the shift become tie blocks fixed up on
-    the host exactly like genuinely equal prefixes; partitions where
-    the shift would collapse dense clusters (cheap host check) keep the
-    exact 2-word operand.
-  * Downlink (8x for K<=16): within a partition each run's survivors
-    appear in increasing position order, so the kernel returns only the
-    bit-packed run-id sequence (~4 bits/entry) and the host rebuilds
-    positions with per-run counters.
+* **inputs** (``_read_inputs``; the calling thread and a pool of
+  ``_READERS`` reader threads).  Every run's index columns and data are
+  read (O_DIRECT, C, GIL released) while the caller stages the 8-byte
+  key prefixes of the runs already in.  Out: ``_Inputs`` — the runs,
+  their four columns side by side (``off_cat``, ``ks_cat``, ``fs_cat``,
+  ``pf_cat``; run ``i`` is ``[run_base[i], run_base[i + 1])``) and the
+  run pointers C gathers from.
+* **plan** (``_make_plan``; the calling thread).  Keyspace partitions
+  cut at sampled prefixes so that every run's slice of a partition
+  fits the kernel's rows — equal prefixes, hence equal keys, hence
+  every dedup decision never cross a cut — the launch width and, on a
+  mesh, the shardings of the launch-batch axis (partitions are
+  disjoint sorted ranges: pure data parallelism, no exchange), the
+  tombstone column, the output's paths.  Out: ``_Plan``, immutable, or
+  None where one equal-prefix group is larger than the kernel's rows
+  (the caller of the pipeline then takes the single-shot path).
+* **launcher** (``_Launches._upload``; the upload thread).  Per
+  partition: each run's prefixes rebased to the partition's minimum
+  and right-shifted until the span fits 32 bits — an order-preserving
+  ONE-word operand; where the shift would collapse dense clusters
+  (``_SHIFT_DUP_LIMIT``) the exact two-word operand instead.  Up to
+  ``launch_j`` same-mode partitions fill one operand stack and go to
+  the device as one vmapped launch of one compiled shape.  Out, in
+  partition order: (partitions, the launch's device result).
+* **downloader** (``_Launches._download``; the download thread).
+  Reads a launch's result back: within a partition each run's
+  survivors appear in position order, so the kernel returns only the
+  bit-packed run-id sequence.  Out, in partition order: (partition,
+  its packed words) on ``_Launches.results``, then None.
+* **decode** (``_decode``; the calling thread).  One C pass rebuilds
+  the permutation and flags entries equal under the DEVICE key; those
+  tie blocks (shift collisions, shared 8-byte prefixes, long keys) are
+  re-ordered by one lexsort over padded key words — (full key asc,
+  newest ts, newest src), the reference's merge order
+  (/root/reference/src/storage_engine/lsm_tree.rs:1038-1066) — and
+  deduplicated; tombstones are dropped where the merge drops them.
+  Out: a ``_Job`` — run, offset, key size and full size per surviving
+  entry, in the partition set it was given.
+* **output** (``_Output``; writer, bloom and close threads).  The
+  writer gather-writes each job through the native handle (O_DIRECT
+  stream, page CRCs accumulated inline); the bloom thread builds the
+  filter beside it (``_Output``'s docstring); the close thread runs
+  the final fdatasync + truncate; the caller writes the ``.sums``
+  sidecar from the CRCs the close hands back.  Out: entries, bytes,
+  bloom or not.
 
-Tie blocks (equal u32 approximations, shared 8-byte prefixes, long
-keys) are re-ordered by one vectorized lexsort over padded key words —
-(full key asc, newest ts, newest src), the reference merge order
-(/root/reference/src/storage_engine/lsm_tree.rs:1038-1066) — so
-tie-heavy keyspaces no longer abort the pipeline run.  Partitions are
-keyspace ranges cut at sampled 8-byte key prefixes, so equal prefixes
-(hence equal keys, hence every dedup decision) never cross a partition
-boundary.  Output bytes are identical to every other strategy (golden
-tests enforce it).
+``_pipeline_merge_impl`` wires the boxes and runs the consumer loop
+(wait for the device, take a partition set, decode, queue).  Output
+bytes are identical to every other strategy's (golden tests).
 
-Memory (PR 29).  Every array of a merge that can reach 128 KiB is
-leased from one process-wide block pool (ops/block_pool.py) through the
-merge's ``Leases``, almost all of them up front, on the calling thread
-and in one order: the index and prefix columns of all runs side by
-side (each run's are slices, filled as it is read), a data buffer a
-run, the tombstone column, three operand stacks, a ring of
-per-partition sets, the bloom's hash pairs and bits.  A partition set
-returns to its ring when both the writer and the bloom thread have
-consumed its raw pointers; an operand stack when its launch's output
-has been read back (JAX may read the host array until the transfer
-completes); everything goes back to the pool when the merge's threads
-are joined — or is dropped: all of it where one of them is wedged, a
-failed merge's stacks (a launch may never have been read back).  What comes back is dirty: each stage fills what it
-reads, and only logical lengths reach C.  The pool keeps what recent
-merges used (a block idle for two merges is released; the free total
-stays within what they leased at once), so the second and every later
-merge of a process runs on pages that are already mapped.
+Failure.  One ``_Stop`` a merge: the first error of any thread sets
+it, every wait of every thread honours it within ``_POLL_S``, and the
+caller re-raises that first error.  What is then undone is written
+once each: ``_Output.abort`` (join; never free the handle or unlink
+under a live pwrite / fdatasync — a wedged writer or close leaks the
+handle and its files; otherwise the whole triplet goes, so a failed
+bloom takes data and index with it) and ``_Launches.close`` (join,
+return the process's launch permits, forget the stacks of launches
+never read back).
+
+Memory.  Every array of a merge that can reach 128 KiB is leased from
+one process-wide block pool (ops/block_pool.py) through the merge's
+``Leases``, on the calling thread, before the box's threads start and
+in one order — the four columns, a data buffer a run, an index scratch a
+reader (inputs); the tombstone column (plan); the operand stacks and
+the rebase scratch (launcher); the ring of partition sets, the bloom's
+hash pairs and bits (output) — so that a merge of the same inputs
+takes the same blocks (only a two-word launch re-leases its stack, on
+the upload thread).  A partition set returns to its ring when both
+the writer and the bloom thread have consumed its raw pointers; an
+operand stack when its launch's result has been read back (JAX may
+read the host array until the transfer completes).  ``pipeline_merge``
+owns the ``Leases``: every block goes back when the merge's threads
+are joined — or is dropped, all of them where a thread is wedged.
+What comes back is dirty: each stage fills what it reads, and only
+logical lengths reach C.  The native writer handle belongs to
+``_Output`` from open to the start of the close thread, then to that
+thread; process-wide are only the pool and ``_LAUNCH_SLOTS``.
 """
 
 from __future__ import annotations
@@ -67,8 +97,9 @@ import os
 import queue
 import threading
 import zlib
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
-from typing import List, Optional, Sequence
+from typing import List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -115,6 +146,17 @@ _MAX_KP = 1 << 20
 # process, and a bound per merge would let two shards' big merges put
 # four such programs on the one chip.
 _LAUNCH_SLOTS = threading.BoundedSemaphore(2)
+# Operand stacks a merge: two launches in flight (_LAUNCH_SLOTS) and
+# the next being filled.
+_STACKS = 3
+# Reader threads of the inputs: queue depth 2 on the virtio disk
+# overlaps one run's tail with the next run's head, and the calling
+# thread stages one run's prefixes meanwhile.
+_READERS = 2
+# Jobs queued ahead of the writer.  A partition's arrays are one set of
+# a ring of this many + 2: write_q's, the one in the writer's hands,
+# the one in the caller's.
+_WRITE_AHEAD = 4
 # Entries a step of the tombstone column: 64 KiB temporaries.
 _TOMB_STEP = 1 << 14
 # Per-partition row target used to pick the partition count.
@@ -130,28 +172,23 @@ _LAUNCH_BATCH = 4
 # above this many total input rows — below it the extra per-launch
 # dispatch outweighs the overlap.
 _MULTIBATCH_MIN_ROWS = 1 << 19
-# Background fdatasync stride: flush the output's device write cache
-# every this many written bytes concurrently with the write stream.
-# DISABLED by default (0): on this virtio disk a concurrent fdatasync
-# SERIALIZES against in-flight O_DIRECT pwrites and stalls the gather
-# writer ~0.5s per flush (measured: bg-sync-on 6.0s vs off 4.85s on
-# the 10M merge), while the single close-time flush costs <1s.  Set
-# DBEEL_SYNC_STRIDE to a byte count on devices whose close-time cache
-# flush is the bigger tail.
-try:
-    _SYNC_STRIDE = int(os.environ.get("DBEEL_SYNC_STRIDE", 0))
-except ValueError:
-    logging.getLogger(__name__).warning(
-        "DBEEL_SYNC_STRIDE=%r is not an integer byte count; "
-        "background sync stays disabled",
-        os.environ.get("DBEEL_SYNC_STRIDE"),
-    )
-    _SYNC_STRIDE = 0
+# How often a waiting thread looks at its merge's stop flag: a failed
+# peer may never feed the queue or return the permit it waits for.
+_POLL_S = 0.25
+# How long a join waits for a thread that has been told to stop, and
+# for one that is finishing real work (the writer's queue, the bloom's
+# set phase, the close's fdatasync).  A thread alive after its join is
+# wedged (``_Output.abort``).
+_JOIN_STOPPED_S = 60
+_JOIN_WORK_S = 600
+
+_u8p = ctypes.POINTER(ctypes.c_uint8)
+_u32p = ctypes.POINTER(ctypes.c_uint32)
+_u64p = ctypes.POINTER(ctypes.c_uint64)
+_i64p = ctypes.POINTER(ctypes.c_int64)
 
 
 def _unlink_quiet(*paths: str) -> None:
-    import os
-
     for p in paths:
         try:
             os.unlink(p)
@@ -186,7 +223,7 @@ def _read_run(lib, source, buf, cols, scratch) -> _Run:
     if size:
         got = lib.dbeel_read_file(
             source.data_path.encode(),
-            buf.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+            buf.ctypes.data_as(_u8p),
             ctypes.c_uint64(size),
         )
         if got != size:
@@ -196,59 +233,108 @@ def _read_run(lib, source, buf, cols, scratch) -> _Run:
     return _Run(buf, size, offs, ks, fs)
 
 
-def _stage_prefixes(run: _Run, out: np.ndarray, lib=None) -> None:
+def _stage_prefixes(lib, run: _Run, out: np.ndarray) -> None:
     """Fill run.prefix64 = ``out`` (the run's slice of the leased
     ``pf_cat``): the zero-padded 8-byte big-endian key prefix per entry
     as one native u64 value (splitters, searchsorted, the
     per-partition rebase that feeds the device operand, the native
-    decoder).  Prefers the C stager — the numpy paths held the GIL
-    ~90ms per 1.25M-key run, measured as back-to-back serving stalls at
-    compaction start."""
+    decoder).  In C, GIL released: a serving shard's loop keeps running
+    while a merge stages."""
     n = run.offsets.size
     run.prefix64 = out
     if n == 0:
         return
-    if lib is not None and hasattr(lib, "dbeel_stage_prefixes"):
-        lib.dbeel_stage_prefixes(
-            run.data.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-            ctypes.c_uint64(run.size),
-            run.offsets.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
-            run.key_size.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-            ctypes.c_uint64(n),
-            ctypes.c_uint64(ENTRY_HEADER_SIZE),
-            out.view(np.uint8).ctypes.data_as(
-                ctypes.POINTER(ctypes.c_uint8)
-            ),
-        )
-        # The stager writes key bytes, big-endian: one swap in place
-        # here, beside the reads, makes every later use native.
-        out.view(">u8").byteswap(inplace=True)
-        return
-    rec = int(run.full_size[0]) if run.full_size.size else 0
-    uniform = (
-        rec > 0
-        and run.size == n * rec
-        and (run.full_size == rec).all()
-        and (
-            run.offsets == np.arange(n, dtype=np.uint64) * np.uint64(rec)
-        ).all()
-        and (run.key_size >= 8).all()
+    lib.dbeel_stage_prefixes(
+        run.data.ctypes.data_as(_u8p),
+        ctypes.c_uint64(run.size),
+        run.offsets.ctypes.data_as(_u64p),
+        run.key_size.ctypes.data_as(_u32p),
+        ctypes.c_uint64(n),
+        ctypes.c_uint64(ENTRY_HEADER_SIZE),
+        out.view(np.uint8).ctypes.data_as(_u8p),
     )
-    if uniform:
-        mat = run.data[: n * rec].reshape(n, rec)
-        pref = np.ascontiguousarray(
-            mat[:, ENTRY_HEADER_SIZE : ENTRY_HEADER_SIZE + 8]
+    # The stager writes key bytes, big-endian: one swap in place
+    # here, beside the reads, makes every later use native.
+    out.view(">u8").byteswap(inplace=True)
+
+
+@dataclass(frozen=True)
+class _Inputs:
+    """What the inputs box hands on.  Run ``i``'s entries are
+    ``[run_base[i], run_base[i + 1])`` of the four columns."""
+
+    runs: List[_Run]
+    run_base: np.ndarray  # (n_runs + 1,) int64
+    off_cat: np.ndarray  # u64 within-run record offsets
+    ks_cat: np.ndarray  # u32 key sizes
+    fs_cat: np.ndarray  # u32 record sizes
+    pf_cat: np.ndarray  # u64 native-endian 8-byte key prefixes
+    run_ptrs: ctypes.Array  # each run's data, for C to gather from
+    total_rows: int
+    total_bytes: int
+
+
+def _read_inputs(lib, sources: Sequence, mem: Leases, span) -> _Inputs:
+    """Spans ``read_run`` (a reader), ``stage_prefixes`` (the caller)."""
+    # Leased up front, on this thread and in one order, so that a
+    # merge of the same inputs leases the same blocks: the index
+    # columns and key prefixes of all runs side by side (each run's
+    # are slices, filled as it is read), one data buffer a run, one
+    # index-file scratch a reader.
+    counts_all = np.array(
+        [s.entry_count for s in sources], dtype=np.int64
+    )
+    run_base = np.zeros(len(sources) + 1, dtype=np.int64)
+    np.cumsum(counts_all, out=run_base[1:])
+    total_rows = int(run_base[-1])
+    off_cat = mem.array(total_rows, np.uint64)
+    ks_cat = mem.array(total_rows, np.uint32)
+    fs_cat = mem.array(total_rows, np.uint32)
+    pf_cat = mem.array(total_rows, np.uint64)
+    bufs = [
+        mem.array((s.data_size + _ALIGN - 1) & ~(_ALIGN - 1))
+        for s in sources
+    ]
+    scratch_q: "queue.Queue" = queue.Queue()
+    for _ in range(min(_READERS, len(sources))):
+        scratch_q.put(
+            mem.array(int(counts_all.max()) * INDEX_ENTRY_SIZE)
         )
-    else:
-        lanes = np.arange(8, dtype=np.uint64)
-        pos = (run.offsets + np.uint64(ENTRY_HEADER_SIZE))[:, None] + lanes
-        valid = lanes < run.key_size.astype(np.uint64)[:, None]
-        pos = np.minimum(pos, np.uint64(max(0, run.size - 1)))
-        pref = np.where(
-            valid, run.data[pos.astype(np.int64)], 0
-        ).astype(np.uint8)
-        pref = np.ascontiguousarray(pref)
-    out[:] = pref.view(">u8").reshape(n)
+
+    def read(i, source):
+        with span("read_run", run=i):
+            lo, hi = int(run_base[i]), int(run_base[i + 1])
+            scratch = scratch_q.get()
+            try:
+                return _read_run(
+                    lib,
+                    source,
+                    bufs[i],
+                    (off_cat[lo:hi], ks_cat[lo:hi], fs_cat[lo:hi]),
+                    scratch,
+                )
+            finally:
+                scratch_q.put(scratch)
+
+    runs = []
+    with ThreadPoolExecutor(
+        max_workers=_READERS, thread_name_prefix="dbeel-pipeline-read"
+    ) as io:
+        futs = [io.submit(read, i, s) for i, s in enumerate(sources)]
+        for i, f in enumerate(futs):
+            r = f.result()
+            with span("stage_prefixes", run=i):
+                _stage_prefixes(
+                    lib, r, pf_cat[run_base[i] : run_base[i + 1]]
+                )
+            runs.append(r)
+    run_ptrs = (_u8p * max(1, len(runs)))(
+        *[r.data.ctypes.data_as(_u8p) for r in runs]
+    )
+    return _Inputs(
+        runs, run_base, off_cat, ks_cat, fs_cat, pf_cat, run_ptrs,
+        total_rows, int(sum(r.size for r in runs)),
+    )
 
 
 def max_partition_rows(n_runs: int) -> int:
@@ -369,8 +455,159 @@ def _choose_partitions(runs: List[_Run], launch_batch: int = None):
     return splitters, bounds, p2
 
 
+@dataclass(frozen=True)
+class _Plan:
+    """What the plan box decides, for every later box to read."""
+
+    launch_j: int  # partitions a launch: its batch axis
+    # Mesh mode only (else None): the batch axis of the one-word and
+    # two-word operands and of their counts, sharded over the mesh.
+    shard32: object
+    shard64: object
+    shard_counts: object
+    bounds: Optional[list]  # per run, n_parts + 1 cut positions
+    n_parts: int
+    p2: int  # kernel rows per (run, partition)
+    k2: int  # kernel runs: pow2 of the run count
+    pack_bits: int  # bits a run-id in the kernel's result
+    tomb_cat: Optional[np.ndarray]  # None where tombstones are kept
+    max_np: int  # most entries of one partition over all runs
+    dir_path: str
+    output_index: int
+
+    def path(self, ext: str) -> str:
+        """A file of the output."""
+        return f"{self.dir_path}/{file_name(self.output_index, ext)}"
+
+
+def _make_plan(
+    inputs: _Inputs,
+    mesh,
+    keep_tombstones: bool,
+    dir_path: str,
+    output_index: int,
+    mem: Leases,
+) -> Optional[_Plan]:
+    from .bitonic import rid_pack_bits
+
+    # Mesh mode: widen the launch batch to a device multiple and shard
+    # the batch axis — each device merges its own keyspace partitions.
+    # Computed BEFORE partitioning: the multi-batch preference must
+    # target the EFFECTIVE launch width, or a wide mesh swallows every
+    # partition into one launch and re-serializes the stages.
+    launch_j = _LAUNCH_BATCH
+    shard32 = shard64 = shard_counts = None
+    if mesh is not None and mesh.devices.size > 1:
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        n_dev = int(mesh.devices.size)
+        launch_j = n_dev * max(1, _LAUNCH_BATCH // n_dev)
+        axis = mesh.axis_names[0]
+        shard32 = NamedSharding(mesh, PartitionSpec(axis, None, None))
+        shard64 = NamedSharding(
+            mesh, PartitionSpec(axis, None, None, None)
+        )
+        shard_counts = NamedSharding(mesh, PartitionSpec(axis, None))
+
+    chosen = _choose_partitions(inputs.runs, launch_j)
+    if chosen is None:
+        return None
+    _splitters, bounds, p2 = chosen
+    n_parts = (bounds[0].size - 1) if bounds is not None else 0
+    k2 = _pow2(max(1, len(inputs.runs)))
+
+    tomb_cat = None
+    if not keep_tombstones:
+        tomb_cat = mem.array(inputs.total_rows, np.bool_)
+        # In steps whose temporaries stay small enough for the heap.
+        hdr = np.uint32(ENTRY_HEADER_SIZE)
+        for lo in range(0, inputs.total_rows, _TOMB_STEP):
+            hi = lo + _TOMB_STEP
+            np.equal(
+                inputs.fs_cat[lo:hi],
+                inputs.ks_cat[lo:hi] + hdr,
+                out=tomb_cat[lo:hi],
+            )
+    return _Plan(
+        launch_j=launch_j,
+        shard32=shard32,
+        shard64=shard64,
+        shard_counts=shard_counts,
+        bounds=bounds,
+        n_parts=n_parts,
+        p2=p2,
+        k2=k2,
+        pack_bits=rid_pack_bits(k2),
+        tomb_cat=tomb_cat,
+        max_np=(
+            int(sum(np.diff(b) for b in bounds).max()) if n_parts else 0
+        ),
+        dir_path=dir_path,
+        output_index=output_index,
+    )
+
+
 class _PipelineError(Exception):
     pass
+
+
+class _Stopped(_PipelineError):
+    """Raised in a wait of a merge that has failed elsewhere."""
+
+
+class _Stop:
+    """One merge's stop flag, the first error that set it, and the
+    only ways its threads start and wait.  A thread that fails calls
+    ``fail``; every other thread of the merge leaves its next wait
+    with ``_Stopped`` and ends quietly; the calling thread re-raises
+    ``error``.  ``threads``: the caller's list, for it to see whether
+    one outlived the merge."""
+
+    def __init__(self, threads: List[threading.Thread]) -> None:
+        self.error: Optional[BaseException] = None
+        self._set = threading.Event()
+        self._lock = threading.Lock()
+        self._threads = threads
+
+    def fail(self, error: BaseException) -> None:
+        with self._lock:
+            if self.error is None:
+                self.error = error
+        self._set.set()
+
+    def check(self) -> None:
+        if self._set.is_set():
+            raise _Stopped("pipeline stopped")
+
+    def wait(self, attempt, *args):
+        """``attempt(*args, timeout=)`` — a queue's ``get`` or ``put``,
+        a semaphore's ``acquire`` — until it succeeds: a peer that
+        failed may never feed the queue or return the permit, so no
+        thread of a merge parks without looking at its stop flag."""
+        while True:
+            self.check()
+            try:
+                got = attempt(*args, timeout=_POLL_S)
+            except (queue.Empty, queue.Full):
+                continue
+            if got is not False:
+                return got
+
+    def spawn(self, name: str, target, *args) -> threading.Thread:
+        def run():
+            try:
+                target(*args)
+            except _Stopped:
+                pass
+            except BaseException as e:  # the caller re-raises it
+                self.fail(e)
+
+        t = threading.Thread(
+            target=run, name=f"dbeel-pipeline-{name}", daemon=True
+        )
+        self._threads.append(t)
+        t.start()
+        return t
 
 
 class _PartSet:
@@ -434,16 +671,7 @@ def pipeline_merge(
     nested ``stage_prefixes`` (in ``read_stage``) and ``tie_fixup`` (in
     ``decode``) overlap them and say what the caller was waiting on.
     The merge's shape (launches, partitions, rows launched and real,
-    runs, tie entries) is counted under ``get_stats.compaction.shape``.
-
-    The bloom filter is built beside the stream, not after it: a bloom
-    thread hashes each partition's keys while the writer gather-writes
-    them (``bloom_hash``), and sets the bits and writes and fsyncs the
-    bloom file (``bloom_set``) from the moment the last partition is
-    queued and the entry count known — under the writer's final join
-    and the close's fdatasync.  The caller's ``bloom`` stage starts the
-    close and joins that thread; ``close_wait`` is what then remains
-    of the close's flush."""
+    runs, tie entries) is counted under ``get_stats.compaction.shape``."""
     shape: dict = {}
     mem = _POOL.leases()
     threads: List[threading.Thread] = []
@@ -553,6 +781,206 @@ def _fill_operand(dest, slices, mode32, minpf, shift, tmp):
     dest[len(slices) :] = SENTINEL
 
 
+class _Part(NamedTuple):
+    """One partition as it travels from the launcher to the decode:
+    ``_plan_operand``'s choices."""
+
+    p: int
+    counts: np.ndarray  # (k2,) u32 entries per run
+    los: np.ndarray  # per run, where its slice starts in the run
+    mode32: bool  # the one-word operand
+    minpf: int
+    shift: int
+
+
+class _Launches:
+    """The launcher and the downloader of one merge: partitions in,
+    ``results`` out — (``_Part``, its packed run-ids or None where it
+    is empty) in partition order, then None.
+
+    Owns the operand stacks — flat u32 buffers of the one-word
+    launch's size (the two-word form re-leases twice that), cycled
+    through ``_stack_free``: JAX may read a stack handed to device_put
+    until the transfer completes, so a stack is refilled only after its
+    launch's result has been read back — the per-partition permits
+    ``_in_flight``, and the ``_LAUNCH_SLOTS`` permits this merge
+    holds, one token each in ``_held``.  ``close`` gives back whatever
+    an abort left."""
+
+    def __init__(
+        self, plan: _Plan, inputs: _Inputs, mem: Leases, span, stop: _Stop
+    ) -> None:
+        import jax
+
+        # Looked up at each merge: whoever wraps ops.bitonic's names
+        # (the benchmark's LaunchSpy, the tests) sees every launch.
+        from . import bitonic
+
+        self._device_put = jax.device_put
+        self._kernels = {
+            True: bitonic.merge_runs_prefix32_packed_batch_kernel,
+            False: bitonic.merge_runs_prefix64_packed_batch_kernel,
+        }
+        self._plan, self._inputs = plan, inputs
+        self._mem, self._span, self._stop = mem, span, stop
+        self.results: "queue.Queue" = queue.Queue()
+        self._kernel_q: "queue.Queue" = queue.Queue()
+        # Sized for two full launches: the upload thread holds up to
+        # launch_j permits while it assembles one, so fewer would
+        # deadlock the assembly itself.
+        self._in_flight = threading.Semaphore(2 * plan.launch_j)
+        self._held: list = []
+        # Launches so far; every one has the one compiled shape.
+        self.launched = 0
+        # True once every launch has been read back.
+        self._complete = False
+        self._threads: List[threading.Thread] = []
+        self._stack_words = plan.launch_j * plan.k2 * plan.p2
+        self._stacks = [
+            mem.array(self._stack_words, np.uint32)
+            for _ in range(min(_STACKS, -(-plan.n_parts // plan.launch_j)))
+        ]
+        self._stack_free: "queue.Queue" = queue.Queue()
+        for buf in self._stacks:
+            self._stack_free.put(buf)
+        # The upload thread's scratch for rebased prefixes.
+        self._tmp = mem.array(plan.p2 if plan.n_parts else 0, np.uint64)
+
+    def start(self) -> None:
+        self._threads = [
+            self._stop.spawn("upload", self._upload),
+            self._stop.spawn("download", self._download),
+        ]
+
+    def _upload(self) -> None:
+        plan, inputs, span = self._plan, self._inputs, self._span
+        parts: List[_Part] = []  # the pending launch
+        taken = None  # the stack it is filled into
+        for p in range(plan.n_parts):
+            with span("slot_wait", part=p):
+                self._stop.wait(self._in_flight.acquire)
+            with span("operand", part=p):
+                slices, *choice = _plan_operand(
+                    inputs.pf_cat, inputs.run_base, plan.bounds, p,
+                    plan.k2, self._tmp,
+                )
+            part = _Part(p, *choice)
+            if slices is None:
+                # Keep strict partition order: launch whatever is
+                # pending first, THEN the empty marker (the downloader
+                # releases this partition's permit).
+                parts, taken = self._launch(parts, taken)
+                self._kernel_q.put(([part], None, None, None))
+                continue
+            if parts and part.mode32 != parts[0].mode32:
+                parts, taken = self._launch(parts, taken)
+            if taken is None:
+                with span("slot_wait", part=p):
+                    taken = self._take_stack(part.mode32)
+            with span("operand", part=p):
+                _fill_operand(
+                    taken[1][len(parts)], slices, part.mode32,
+                    part.minpf, part.shift, self._tmp,
+                )
+            parts.append(part)
+            if len(parts) == plan.launch_j:
+                parts, taken = self._launch(parts, taken)
+        self._launch(parts, taken)
+        self._kernel_q.put(None)
+
+    def _take_stack(self, mode32: bool):
+        """A free stack as (buffer, array in the launch's shape)."""
+        plan = self._plan
+        buf = self._stop.wait(self._stack_free.get)
+        words = self._stack_words * (1 if mode32 else 2)
+        if buf.size < words:
+            slot = next(i for i, b in enumerate(self._stacks) if b is buf)
+            self._mem.give(buf)
+            buf = self._stacks[slot] = self._mem.array(words, np.uint32)
+        tail = () if mode32 else (2,)
+        return buf, buf[:words].reshape(
+            (plan.launch_j, plan.k2, plan.p2) + tail
+        )
+
+    def _launch(self, parts: List[_Part], taken):
+        """One vmapped launch over up to ``launch_j`` same-mode
+        partitions, empty-slot padded to a single compiled shape; the
+        batch axis shards over the mesh when one is supplied.
+        ``taken``: the stack from _take_stack, its first len(parts)
+        slots filled.  Returns the next pending launch: none."""
+        if not parts:
+            return [], None
+        plan, span = self._plan, self._span
+        mode32 = parts[0].mode32
+        buf, stack = taken
+        # One launch's spans share ``launch`` (its ordinal in the
+        # merge) and ``part`` (its first partition).
+        ids = {"launch": self.launched, "part": parts[0].p}
+        self.launched += 1
+        with span("operand", **ids):
+            stack[len(parts) :] = SENTINEL
+            counts = np.zeros((plan.launch_j, plan.k2), dtype=np.uint32)
+            for slot, part in enumerate(parts):
+                counts[slot] = part.counts
+        with span("slot_wait", **ids):
+            self._stop.wait(_LAUNCH_SLOTS.acquire)
+        self._held.append(None)
+        with span("h2d_dispatch", **ids):
+            sharding = plan.shard32 if mode32 else plan.shard64
+            if sharding is not None:
+                dev = self._device_put(stack, sharding)
+                cnt = self._device_put(counts, plan.shard_counts)
+            else:
+                dev = self._device_put(stack)
+                cnt = counts
+            out = self._kernels[mode32](dev, cnt, plan.pack_bits)
+        self._kernel_q.put((parts, out, ids, buf))
+        return [], None
+
+    def _download(self) -> None:
+        while True:
+            item = self._stop.wait(self._kernel_q.get)
+            if item is None:
+                self._complete = True
+                self.results.put(None)
+                return
+            parts, out, ids, buf = item
+            if out is None:
+                self._in_flight.release()  # re-balance the empty slot
+                self.results.put((parts[0], None))
+                continue
+            # The kernel's completion + the d2h of its bit-packed
+            # run-ids.
+            with self._span("d2h", **ids):
+                words = np.asarray(out)
+            self._stack_free.put(buf)
+            self._release_slot()
+            for slot, part in enumerate(parts):
+                self._in_flight.release()
+                self.results.put((part, words[slot]))
+
+    def _release_slot(self) -> bool:
+        try:
+            self._held.pop()
+        except IndexError:
+            return False
+        _LAUNCH_SLOTS.release()
+        return True
+
+    def close(self) -> None:
+        """When the consumer has left its loop, either way: join both
+        threads, return the launch permits an abort left taken, and —
+        a launch that was never read back may still be reading its
+        stack — forget, not return, an incomplete merge's stacks."""
+        for t in self._threads:
+            t.join(timeout=_JOIN_STOPPED_S)
+        while self._release_slot():
+            pass
+        if not self._complete:
+            for buf in self._stacks:
+                self._mem.forget(buf)
+
+
 def _gather_tie_arrays(runs, run_base, off_cat, ks_cat, sel, lpad):
     """Per-run vectorized gather of (padded key words, ~ts, ~src) for
     the tie-block entries ``sel`` (global indices), key matrix padded
@@ -612,6 +1040,432 @@ def _gather_timestamps(runs, run_base, off_cat, sel):
     return ts
 
 
+class _Job(NamedTuple):
+    """What the decode hands the output: partition ``p``'s ``m``
+    surviving entries in output order — run, offset in the run, key
+    size and record size of each — as heads of ``pset``'s arrays."""
+
+    p: int
+    pset: _PartSet
+    m: int
+    nbytes: int
+    src_run: np.ndarray  # u32
+    src_off: np.ndarray  # u64
+    ks_sel: np.ndarray  # u32
+    fs_sel: np.ndarray  # u32
+
+
+def _decode(
+    lib,
+    inputs: _Inputs,
+    plan: _Plan,
+    part: _Part,
+    packed: np.ndarray,
+    pset: _PartSet,
+    span,
+    tombstone_drop_before: "int | None",
+):
+    """One partition from the downloader's packed run-ids to the
+    writer's job, in ``pset``.  Returns (job, entries the host tie
+    fix-up took); the job is None where no entry survives.  Span
+    ``tie_fixup``, nested in the caller's ``decode``."""
+    runs, run_base = inputs.runs, inputs.run_base
+    off_cat, ks_cat = inputs.off_cat, inputs.ks_cat
+    n_p = int(part.counts.sum())
+    # One C pass: unpack rids, per-run counters -> permutation (the
+    # comparator is a total order and runs are pre-sorted, so each
+    # run's entries appear in increasing position order), device-key
+    # tie flags.
+    gidx = pset.gidx[:n_p]
+    rids32 = pset.rids32[:n_p]
+    tieb = pset.tieb[:n_p]
+    rc = lib.dbeel_pipe_decode(
+        np.ascontiguousarray(packed).ctypes.data_as(_u32p),
+        n_p,
+        plan.pack_bits,
+        len(runs),
+        np.ascontiguousarray(
+            part.counts[: len(runs)], dtype=np.uint32
+        ).ctypes.data_as(_u32p),
+        np.ascontiguousarray(part.los, dtype=np.int64).ctypes.data_as(
+            _i64p
+        ),
+        run_base.ctypes.data_as(_i64p),
+        inputs.pf_cat.ctypes.data_as(_u64p),
+        part.minpf,
+        part.shift,
+        1 if part.mode32 else 0,
+        gidx.ctypes.data_as(_i64p),
+        rids32.ctypes.data_as(_u32p),
+        tieb.ctypes.data_as(_u8p),
+    )
+    if rc != 0:
+        raise _PipelineError("packed run-id decode mismatch")
+
+    # Tie blocks: adjacent entries equal under the DEVICE sort key
+    # (shifted u32 or exact 8B prefix) are re-ordered by (full key,
+    # newest ts, newest src) — one vectorized lexsort — and duplicate
+    # keys are marked for dedup.
+    keep = pset.keep[:n_p]
+    keep.fill(True)
+    with span("tie_fixup", part=part.p):
+        positions, block_id = columnar.tie_positions_and_blocks(
+            tieb[1:].view(np.bool_), pset.mask
+        )
+        if positions.size:
+            sel_t = gidx[positions]
+            ks_t = ks_cat[sel_t]
+            ent_w = columnar.tie_block_widths(block_id, ks_t)
+            for w in np.unique(ent_w):
+                bm = ent_w == w
+                kwords, inv_ts, inv_src = _gather_tie_arrays(
+                    runs, run_base, off_cat, ks_cat, sel_t[bm], int(w)
+                )
+                order, dup = columnar.tie_block_sort(
+                    block_id[bm], kwords, ks_t[bm], inv_ts, inv_src
+                )
+                gidx[positions[bm]] = sel_t[bm][order]
+                # The reorder moved entries across runs: refresh
+                # the run-id column at exactly those positions.
+                rids32[positions[bm]] = (
+                    np.searchsorted(
+                        run_base, gidx[positions[bm]], side="right"
+                    )
+                    - 1
+                ).astype(np.uint32)
+                keep[positions[bm]] = ~dup
+
+    if plan.tomb_cat is not None:
+        # (mode="clip": numpy buffers ``out`` under "raise".)
+        drop = np.take(
+            plan.tomb_cat, gidx, out=pset.mask[:n_p], mode="clip"
+        )
+        if tombstone_drop_before and drop.any():
+            # gc_grace: tombstones younger than the cutoff survive
+            # the drop.  Timestamps are gathered only for the drop
+            # candidates.
+            cand = np.flatnonzero(drop)
+            cand_ts = _gather_timestamps(
+                runs, run_base, off_cat, gidx[cand]
+            )
+            drop[cand[cand_ts >= np.uint64(tombstone_drop_before)]] = False
+        np.logical_not(drop, out=drop)
+        keep &= drop
+    ties = int(positions.size)
+    m = int(np.count_nonzero(keep))
+    if m == 0:
+        return None, ties
+    if m != n_p:
+        sel = np.compress(keep, gidx, out=pset.sel[:m])
+        src_run = np.compress(keep, rids32, out=pset.src_run[:m])
+    else:
+        sel = gidx
+        src_run = rids32
+    src_off = np.take(off_cat, sel, out=pset.src_off[:m], mode="clip")
+    ks_sel = np.take(ks_cat, sel, out=pset.ks_sel[:m], mode="clip")
+    fs_sel = np.take(inputs.fs_cat, sel, out=pset.fs_sel[:m], mode="clip")
+    return (
+        _Job(
+            part.p, pset, m, int(fs_sel.sum()), src_run, src_off,
+            ks_sel, fs_sel,
+        ),
+        ties,
+    )
+
+
+class _Output:
+    """The output of one merge: the native gather-writer's handle, the
+    writer, bloom and close threads, the ring of partition sets.
+
+    The bloom filter is built beside the stream, not after it.  Its
+    size follows from the FINAL entry count, a key's two hashes do
+    not: the bloom thread hashes each job's keys as it is queued for
+    the writer (the job's own arrays, the same run pointers), and once
+    ``finish`` has posted the count it sets the bits from the stored
+    pairs and writes and fsyncs the bloom file — under the writer's
+    last partitions and the close's fdatasync.  Hashing is
+    speculative: only a merge whose INPUT passes ``bloom_min_size``
+    can end with an output that does."""
+
+    def __init__(
+        self,
+        lib,
+        plan: _Plan,
+        inputs: _Inputs,
+        mem: Leases,
+        span,
+        stop: _Stop,
+        bloom_min_size: int,
+    ) -> None:
+        self._lib = lib
+        # ``inputs``: the raw pointers in run_ptrs are only as alive
+        # as the runs' buffers, whatever becomes of the caller's frame.
+        self._plan, self._inputs = plan, inputs
+        self._span, self._stop = span, stop
+        self._bloom_min_size = bloom_min_size
+        self._write_q: "queue.Queue" = queue.Queue(maxsize=_WRITE_AHEAD)
+        # Unbounded: write_q paces the caller, and a partition hashes
+        # several times faster than it gather-writes.
+        self._bloom_q: "queue.Queue" = queue.Queue()
+        # Each set is sized for the largest partition and dirty from
+        # its last; a set is free again when BOTH the writer and the
+        # bloom thread have consumed its raw pointers.
+        self._sets_free: "queue.Queue" = queue.Queue()
+        for _ in range(min(plan.n_parts, _WRITE_AHEAD + 2)):
+            self._sets_free.put(_PartSet(mem, plan.max_np))
+        self._sets_lock = threading.Lock()
+        self._will_bloom = inputs.total_bytes >= bloom_min_size
+        if self._will_bloom:
+            # Two hashes a key, and the bits of the largest filter the
+            # output can need (it never has more entries than the
+            # input).
+            self._pairs = mem.array(2 * inputs.total_rows, np.uint32)
+            self._bits = mem.array(
+                (BloomFilter.size_for(inputs.total_rows)[0] + 7) // 8
+            )
+        self._writer = self._bloomer = self._closer = None
+        self.queued = self.queued_bytes = 0  # by the caller
+        self._wrote = self._wrote_bytes = 0  # by the writer
+        self._bloom_blob: Optional[bytes] = None
+        self._data_size = ctypes.c_uint64(0)
+        self._closed_entries = -1
+        self._crcs = None
+        # Single-pass sidecar: the gather writer's inline page-CRC
+        # accumulators are armed, so the .sums sidecar is written from
+        # the bytes AS they streamed through — no triplet re-read.
+        self._data_path = plan.path(COMPACT_DATA_FILE_EXT)
+        self._index_path = plan.path(COMPACT_INDEX_FILE_EXT)
+        # 0 where the output cannot be opened (a disk fault).
+        self.handle = lib.dbeel_writer_open2(
+            self._data_path.encode(), self._index_path.encode(), 1
+        )
+
+    def start(self) -> None:
+        # Gather-writes run off the decode thread so partition p+1's
+        # decode overlaps partition p's disk write (GIL released).
+        self._writer = self._stop.spawn("writer", self._write)
+        if self._will_bloom:
+            self._bloomer = self._stop.spawn("bloom", self._bloom)
+
+    # ---- the ring of partition sets ---------------------------------
+
+    def sets_busy(self) -> bool:
+        """Every set is queued or being written: the writer's queue is
+        full, one step early."""
+        return self._sets_free.empty()
+
+    def take_set(self) -> _PartSet:
+        return self._stop.wait(self._sets_free.get)
+
+    def give_set(self, pset: _PartSet) -> None:
+        """A set no job went out in."""
+        self._sets_free.put(pset)
+
+    def _set_done(self, pset: _PartSet) -> None:
+        with self._sets_lock:
+            pset.holders -= 1
+            idle = pset.holders == 0
+        if idle:
+            self._sets_free.put(pset)
+
+    # ---- the stream -------------------------------------------------
+
+    def put(self, job: _Job) -> None:
+        """Queue ``job`` for the bloom thread and the writer; waits
+        where the writer is ``_WRITE_AHEAD`` jobs behind."""
+        job.pset.holders = 2 if self._bloomer is not None else 1
+        self.queued += job.m
+        self.queued_bytes += job.nbytes
+        if self._bloomer is not None:
+            self._bloom_q.put(job)
+        self._stop.wait(self._write_q.put, job)
+
+    def _write(self) -> None:
+        while True:
+            job = self._stop.wait(self._write_q.get)
+            if job is None:
+                return
+            with self._span("gather_write", part=job.p):
+                rc = self._lib.dbeel_writer_put(
+                    self.handle,
+                    self._inputs.run_ptrs,
+                    job.src_run.ctypes.data_as(_u32p),
+                    job.src_off.ctypes.data_as(_u64p),
+                    job.ks_sel.ctypes.data_as(_u32p),
+                    job.fs_sel.ctypes.data_as(_u32p),
+                    ctypes.c_uint64(job.m),
+                )
+            self._set_done(job.pset)
+            if rc != 0:
+                raise _PipelineError("native gather-write failed")
+            self._wrote += job.m
+            self._wrote_bytes += job.nbytes
+
+    def _bloom(self) -> None:
+        hashed = 0
+        while True:
+            item = self._stop.wait(self._bloom_q.get)
+            if not isinstance(item, _Job):
+                break
+            with self._span("bloom_hash", part=item.p):
+                self._lib.dbeel_bloom_hash_gather(
+                    self._inputs.run_ptrs,
+                    item.src_run.ctypes.data_as(_u32p),
+                    item.src_off.ctypes.data_as(_u64p),
+                    item.ks_sel.ctypes.data_as(_u32p),
+                    item.m,
+                    ENTRY_HEADER_SIZE,
+                    _SEED1,
+                    _SEED2,
+                    self._pairs[2 * hashed :].ctypes.data_as(_u32p),
+                )
+            hashed += item.m
+            self._set_done(item.pset)
+        # ``item`` is the output's entry count, or 0 where the output
+        # ended under bloom_min_size: the hashes are dropped.
+        if not item:
+            return
+        assert item == hashed
+        with self._span("bloom_set"):
+            num_bits, num_hashes = BloomFilter.size_for(hashed)
+            bits = self._bits[: (num_bits + 7) // 8]
+            bits.fill(0)
+            bloom = BloomFilter(num_bits, num_hashes, bits=bits)
+            self._lib.dbeel_bloom_set_hashes(
+                bloom.bits.ctypes.data_as(_u8p),
+                bloom.num_bits,
+                bloom.num_hashes,
+                self._pairs.ctypes.data_as(_u32p),
+                hashed,
+            )
+            self._bloom_blob = _write_bloom(
+                self._plan.dir_path, self._plan.output_index, bloom
+            )
+
+    def _close(self) -> None:
+        # CRC handoff caps: the merged output can never exceed the sum
+        # of its inputs (dedup/tombstone-drop only shrink it).
+        dcap = self._inputs.total_bytes // 4096 + 2
+        icap = self._inputs.total_rows * 16 // 4096 + 2
+        dcrc = (ctypes.c_uint32 * dcap)()
+        icrc = (ctypes.c_uint32 * icap)()
+        nd = ctypes.c_uint64(0)
+        ni = ctypes.c_uint64(0)
+        # Inside the call: the final fdatasync + truncate.
+        with self._span("fsync"):
+            rc = self._lib.dbeel_writer_close2(
+                self.handle,
+                ctypes.byref(self._data_size),
+                dcrc,
+                dcap,
+                icrc,
+                icap,
+                ctypes.byref(nd),
+                ctypes.byref(ni),
+            )
+        if rc == -2:
+            # Triplet closed fine; only the CRC handoff was refused —
+            # the LSM's counted post-hoc sidecar covers it.  Entries
+            # are known from the writer's own accounting.
+            rc = self._wrote
+        elif rc >= 0:
+            self._crcs = (list(dcrc[: nd.value]), list(icrc[: ni.value]))
+        self._closed_entries = rc
+
+    def finish(self, at: Stages):
+        """Every job is queued.  Returns (entries, data bytes, whether
+        a bloom was written) of the complete output; the caller's
+        stages ``wait_writer``, ``bloom``, ``close_wait``, ``sidecar``."""
+        # The output's entry count and size are known while the writer
+        # still has its queue to write: the bloom's set phase starts
+        # here.
+        wants_bloom = (
+            self.queued > 0 and self.queued_bytes >= self._bloom_min_size
+        )
+        if self._bloomer is not None:
+            self._bloom_q.put(self.queued if wants_bloom else 0)
+        at.to("wait_writer")
+        self._stop.wait(self._write_q.put, None)
+        self._join(self._writer, "writer thread")
+        assert (self._wrote, self._wrote_bytes) == (
+            self.queued, self.queued_bytes,
+        )
+        # The close runs on its own thread: nothing below depends on
+        # its completing.  The bloom thread has had the count since the
+        # last partition was queued, so ``bloom`` is a join — what the
+        # set phase and the bloom file's fsync have left over runs
+        # beside the close's device write-cache flush — and
+        # ``close_wait`` is whatever of that flush remains.
+        at.to("bloom")
+        self._closer = self._stop.spawn("close", self._close)
+        if self._bloomer is not None:
+            self._join(self._bloomer, "bloom thread")
+        assert (self._bloom_blob is not None) == wants_bloom
+        at.to("close_wait")
+        self._join(self._closer, "writer close")
+        if self._closed_entries < 0:
+            raise _PipelineError("native writer close failed")
+        assert self._closed_entries == self._wrote
+        data_size = int(self._data_size.value)
+        assert data_size == self._wrote_bytes
+        at.to("sidecar")
+        if self._crcs is not None:
+            # The per-page CRCs streamed out of the gather writer; the
+            # bloom blob is still in RAM.  Written under the same
+            # journaled rename as the triplet.
+            from ..storage import checksums
+
+            blob = self._bloom_blob
+            checksums.write_crcs(
+                self._plan.dir_path,
+                self._plan.output_index,
+                *self._crcs,
+                data_size,
+                zlib.crc32(blob) if blob is not None else 0,
+                blob is not None,
+                ext=checksums.COMPACT_SUMS_FILE_EXT,
+            )
+        return self._wrote, data_size, wants_bloom
+
+    def _join(self, thread: threading.Thread, what: str) -> None:
+        """Wait for ``thread`` to finish its work; its error, or any
+        other thread's, is the merge's."""
+        thread.join(timeout=_JOIN_WORK_S)
+        self._stop.check()
+        if thread.is_alive():
+            raise _PipelineError(f"{what} wedged")
+
+    def abort(self) -> None:
+        """The merge has failed (its stop flag is set): undo the
+        output.  The one place that says how.  Its contract is the
+        whole triplet — a failed bloom build (ENOSPC, MemoryError)
+        must not leave data and index behind looking complete — and a
+        file is never unlinked, nor the handle freed, under a live
+        pwrite / fdatasync / truncate: a writer or close thread that
+        outlives its join is wedged, and the handle and the partial
+        files are leaked to it (``pipeline_merge`` then drops the
+        merge's blocks).  The bloom thread is joined before the runs'
+        buffers can go: it hashes through run_ptrs."""
+        self._writer.join(timeout=_JOIN_STOPPED_S)
+        if self._bloomer is not None:
+            self._bloomer.join(timeout=_JOIN_STOPPED_S)
+        if self._closer is not None:
+            self._closer.join(timeout=_JOIN_WORK_S)
+        if self._bloomer is None or not self._bloomer.is_alive():
+            _unlink_quiet(self._plan.path(COMPACT_BLOOM_FILE_EXT))
+        for t in (self._writer, self._closer):
+            if t is not None and t.is_alive():
+                log.error(
+                    "pipeline %s wedged; leaking native writer handle "
+                    "for %s", t.name, self._data_path,
+                )
+                return
+        if self._closer is None:
+            # The handle is still ours; a close frees it itself.
+            self._lib.dbeel_writer_abort(self.handle)
+        _unlink_quiet(self._data_path, self._index_path)
+
+
 def _pipeline_merge_impl(
     sources: Sequence,
     dir_path: str,
@@ -627,7 +1481,9 @@ def _pipeline_merge_impl(
     mem: Leases,
     threads: List[threading.Thread],
 ) -> Optional[MergeResult]:
-    """``shape``: filled, where the merge produces an output, with its
+    """The coordinator: wires the boxes of the module docstring and
+    runs the consumer loop between the downloader and the output.
+    ``shape``: filled, where the merge produces an output, with its
     ``compaction.PIPELINE_SHAPE`` counts.  ``mem``: what every large
     array is leased from; the caller closes it.  ``threads``: every
     thread started here that works in leased memory is appended, for
@@ -635,945 +1491,66 @@ def _pipeline_merge_impl(
     from ..storage import native as native_mod
 
     lib = native_mod.require()
-    import jax
-
-    from .bitonic import (
-        merge_runs_prefix32_packed_batch_kernel,
-        merge_runs_prefix64_packed_batch_kernel,
-        rid_pack_bits,
-        unpack_rids,
-    )
-
     span = at.span  # this merge's stages on its other threads
-
-    # ---- host staging (index columns + O_DIRECT data reads) ---------
-    # IO threads read ahead (O_DIRECT, GIL released inside the C
-    # call) while this thread stages completed runs' prefixes.  Two
-    # readers by default: queue depth 2 on the virtio disk overlaps
-    # one run's tail with the next run's head (DBEEL_PIPE_READERS
-    # overrides; 1 restores the round-3 serial-read prologue).
-    from concurrent.futures import ThreadPoolExecutor
-
-    n_readers = max(
-        1, int(os.environ.get("DBEEL_PIPE_READERS", "2") or 2)
-    )
-
-    # Leased up front, on this thread and in one order, so that a
-    # merge of the same inputs leases the same blocks: the index
-    # columns and key prefixes of all runs side by side (each run's
-    # are slices, filled as it is read), one data buffer a run, one
-    # index-file scratch a reader.
-    counts_all = np.array(
-        [s.entry_count for s in sources], dtype=np.int64
-    )
-    run_base = np.zeros(len(sources) + 1, dtype=np.int64)
-    np.cumsum(counts_all, out=run_base[1:])
-    total_rows = int(run_base[-1])
-    off_cat = mem.array(total_rows, np.uint64)
-    ks_cat = mem.array(total_rows, np.uint32)
-    fs_cat = mem.array(total_rows, np.uint32)
-    pf_cat = mem.array(total_rows, np.uint64)
-    bufs = [
-        mem.array((s.data_size + _ALIGN - 1) & ~(_ALIGN - 1))
-        for s in sources
-    ]
-    scratch_q: "queue.Queue" = queue.Queue()
-    for _ in range(min(n_readers, len(sources))):
-        scratch_q.put(
-            mem.array(int(counts_all.max()) * INDEX_ENTRY_SIZE)
-        )
-
-    def read_run(i, source):
-        with span("read_run", run=i):
-            lo, hi = int(run_base[i]), int(run_base[i + 1])
-            scratch = scratch_q.get()
-            try:
-                return _read_run(
-                    lib,
-                    source,
-                    bufs[i],
-                    (off_cat[lo:hi], ks_cat[lo:hi], fs_cat[lo:hi]),
-                    scratch,
-                )
-            finally:
-                scratch_q.put(scratch)
-
-    with ThreadPoolExecutor(max_workers=n_readers) as io:
-        futs = [io.submit(read_run, i, s) for i, s in enumerate(sources)]
-        runs = []
-        for i, f in enumerate(futs):
-            r = f.result()
-            with span("stage_prefixes", run=i):
-                _stage_prefixes(
-                    r, pf_cat[run_base[i] : run_base[i + 1]], lib
-                )
-            runs.append(r)
+    inputs = _read_inputs(lib, sources, mem, span)
     at.to("plan")
-    # Mesh mode: widen the launch batch to a device multiple and shard
-    # the batch axis — each device merges its own keyspace partitions.
-    # Computed BEFORE partitioning: the multi-batch preference must
-    # target the EFFECTIVE launch width, or a wide mesh swallows every
-    # partition into one launch and re-serializes the stages.
-    launch_j = _LAUNCH_BATCH
-    shard32 = shard64 = shard_counts = None
-    if mesh is not None and mesh.devices.size > 1:
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        n_dev = int(mesh.devices.size)
-        launch_j = n_dev * max(1, _LAUNCH_BATCH // n_dev)
-        axis = mesh.axis_names[0]
-        shard32 = NamedSharding(mesh, PartitionSpec(axis, None, None))
-        shard64 = NamedSharding(
-            mesh, PartitionSpec(axis, None, None, None)
-        )
-        shard_counts = NamedSharding(mesh, PartitionSpec(axis, None))
-
-    chosen = _choose_partitions(runs, launch_j)
-    if chosen is None:
-        return None
-    _splitters, bounds, p2 = chosen
-    n_parts = (bounds[0].size - 1) if bounds is not None else 0
-    k2 = _pow2(max(1, len(runs)))
-    pack_bits = rid_pack_bits(k2)
-
-    tomb_cat = None
-    if not keep_tombstones:
-        tomb_cat = mem.array(total_rows, np.bool_)
-        # In steps whose temporaries stay small enough for the heap.
-        hdr = np.uint32(ENTRY_HEADER_SIZE)
-        for lo in range(0, total_rows, _TOMB_STEP):
-            hi = lo + _TOMB_STEP
-            np.equal(
-                fs_cat[lo:hi], ks_cat[lo:hi] + hdr, out=tomb_cat[lo:hi]
-            )
-    # Most entries of one partition over all runs: what a partition's
-    # set of arrays (below) is sized for.
-    max_np = (
-        int(sum(np.diff(b) for b in bounds).max()) if n_parts else 0
+    plan = _make_plan(
+        inputs, mesh, keep_tombstones, dir_path, output_index, mem
     )
-    have_decode = hasattr(lib, "dbeel_pipe_decode")
-
-    data_path = f"{dir_path}/{file_name(output_index, COMPACT_DATA_FILE_EXT)}"
-    index_path = f"{dir_path}/{file_name(output_index, COMPACT_INDEX_FILE_EXT)}"
-    bloom_path = f"{dir_path}/{file_name(output_index, COMPACT_BLOOM_FILE_EXT)}"
-    # Single-pass sidecar (ISSUE 15): arm the gather writer's inline
-    # page-CRC accumulators so the .sums sidecar is written from the
-    # bytes AS they streamed through — no post-hoc triplet re-read.
-    writer_crcs = hasattr(lib, "dbeel_writer_open2")
-    if writer_crcs:
-        handle = lib.dbeel_writer_open2(
-            data_path.encode(), index_path.encode(), 1
-        )
-    else:
-        handle = lib.dbeel_writer_open(
-            data_path.encode(), index_path.encode()
-        )
-    if not handle:
-        # The output cannot be opened (a disk fault): the single-shot
-        # writer meets the same disk and raises its errno.
+    if plan is None:
         return None
-
-    total_input = int(sum(r.size for r in runs))
-
-    run_ptrs = (ctypes.POINTER(ctypes.c_uint8) * max(1, len(runs)))(
-        *[
-            r.data.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8))
-            for r in runs
-        ]
-    )
-
-    # ---- pipeline threads -------------------------------------------
-    # Per-partition permits, sized for two full launch batches in
-    # flight (the upload thread holds up to launch_j permits while
-    # assembling a batch, so the pool must exceed one batch or
-    # assembly itself would deadlock).
-    in_flight = threading.Semaphore(2 * launch_j)
-    kernel_q: "queue.Queue" = queue.Queue()
-    order_q: "queue.Queue" = queue.Queue()
-    stop = threading.Event()
-    # One token per _LAUNCH_SLOTS permit this merge holds: the
-    # downloader returns a permit when its launch has been read back,
-    # and whatever an abort leaves is returned after the joins below.
-    held_slots: list = []
-
-    def _release_slot() -> bool:
-        try:
-            held_slots.pop()
-        except IndexError:
-            return False
-        _LAUNCH_SLOTS.release()
-        return True
-
-    launches = itertools.count()
-    # Operand stacks: flat u32 buffers of the one-word launch's size
-    # (the two-word form re-leases twice that), leased here and cycled
-    # through ``stack_free``.  JAX may read a stack handed to
-    # device_put until the transfer completes, so a stack is refilled
-    # only after its launch's output has been read back: the downloader
-    # puts it back beside the launch's permit.  Three: two launches in
-    # flight (_LAUNCH_SLOTS) and the next being filled.
-    stack_words = launch_j * k2 * p2
-    stacks = [
-        mem.array(stack_words, np.uint32)
-        for _ in range(min(3, -(-n_parts // launch_j)))
-    ]
-    stack_free: "queue.Queue" = queue.Queue()
-    for buf in stacks:
-        stack_free.put(buf)
-    # The upload thread's scratch for rebased prefixes.
-    shift_tmp = mem.array(p2 if n_parts else 0, np.uint64)
-
-    def _take_stack(mode32):
-        """A free stack as (buffer, array in the launch's shape); None
-        where the merge stopped."""
-        while True:
-            try:
-                buf = stack_free.get(timeout=0.25)
-                break
-            except queue.Empty:
-                if stop.is_set():
-                    return None
-        words = stack_words * (1 if mode32 else 2)
-        if buf.size < words:
-            slot = next(i for i, b in enumerate(stacks) if b is buf)
-            mem.give(buf)
-            buf = stacks[slot] = mem.array(words, np.uint32)
-        tail = () if mode32 else (2,)
-        return buf, buf[:words].reshape((launch_j, k2, p2) + tail)
-
-    def _launch_batch(metas, taken, mode32):
-        """One vmapped launch over up to ``launch_j`` same-mode
-        partitions, empty-slot padded to a single compiled shape; the
-        batch axis shards over the mesh when one is supplied.
-        ``taken``: the stack from _take_stack, its first len(metas)
-        slots filled."""
-        j = launch_j
-        buf, stack = taken
-        # One launch's spans share ``launch`` (its ordinal in the
-        # merge) and ``part`` (its first partition).
-        ids = {"launch": next(launches), "part": metas[0][0]}
-        with span("operand", **ids):
-            stack[len(metas) :] = SENTINEL
-            counts = np.zeros((j, k2), dtype=np.uint32)
-            for slot, meta in enumerate(metas):
-                counts[slot] = meta[1]
-        with span("slot_wait", **ids):
-            while not _LAUNCH_SLOTS.acquire(timeout=0.25):
-                if stop.is_set():
-                    return
-        held_slots.append(None)
-        with span("h2d_dispatch", **ids):
-            sharding = shard32 if mode32 else shard64
-            if sharding is not None:
-                dev = jax.device_put(stack, sharding)
-                cnt = jax.device_put(counts, shard_counts)
-            else:
-                dev = jax.device_put(stack)
-                cnt = counts
-            if mode32:
-                out = merge_runs_prefix32_packed_batch_kernel(
-                    dev, cnt, pack_bits
-                )
-            else:
-                out = merge_runs_prefix64_packed_batch_kernel(
-                    dev, cnt, pack_bits
-                )
-        kernel_q.put((metas, out, ids, buf))
-
-    def upload():
-        try:
-            metas: list = []  # (p, counts, los, mode32, minpf, shift)
-            taken = None  # the stack the pending batch is filled into
-            batch_mode = True
-
-            def flush():
-                nonlocal metas, taken
-                if metas:
-                    _launch_batch(metas, taken, batch_mode)
-                    metas, taken = [], None
-
-            for p in range(n_parts):
-                # Timed acquire + stop checks: if the downloader dies
-                # it can never release permits, and this thread must
-                # not park forever pinning the run buffers.
-                with span("slot_wait", part=p):
-                    while not in_flight.acquire(timeout=0.25):
-                        if stop.is_set():
-                            return
-                if stop.is_set():
-                    return
-                with span("operand", part=p):
-                    slices, counts, los, mode32, minpf, shift = (
-                        _plan_operand(
-                            pf_cat, run_base, bounds, p, k2, shift_tmp
-                        )
-                    )
-                if slices is None:
-                    # Keep strict partition order: launch whatever is
-                    # pending first, THEN the empty marker (the
-                    # downloader releases this partition's permit).
-                    flush()
-                    kernel_q.put(
-                        ([(p, counts, los, True, 0, 0)], None, None, None)
-                    )
-                    continue
-                if metas and mode32 != batch_mode:
-                    flush()
-                batch_mode = mode32
-                if taken is None:
-                    with span("slot_wait", part=p):
-                        taken = _take_stack(mode32)
-                    if taken is None:
-                        return
-                with span("operand", part=p):
-                    _fill_operand(
-                        taken[1][len(metas)],
-                        slices,
-                        mode32,
-                        minpf,
-                        shift,
-                        shift_tmp,
-                    )
-                metas.append((p, counts, los, mode32, minpf, shift))
-                if len(metas) == launch_j:
-                    flush()
-            flush()
-            kernel_q.put(None)
-        except BaseException as e:  # propagate to writer
-            kernel_q.put(e)
-
-    def download():
-        try:
-            while True:
-                # Timed get + stop check: on a consumer-side abort no
-                # sentinel may ever arrive, and this thread must not
-                # park forever (it would leak and stall the joins).
-                try:
-                    item = kernel_q.get(timeout=0.25)
-                except queue.Empty:
-                    if stop.is_set():
-                        return
-                    continue
-                if item is None:
-                    order_q.put(None)
-                    return
-                if isinstance(item, BaseException):
-                    stop.set()
-                    order_q.put(item)
-                    return
-                metas, out, ids, buf = item
-                if out is not None:
-                    # The kernel's completion + the d2h of its
-                    # bit-packed run-ids.
-                    with span("d2h", **ids):
-                        words = np.asarray(out)
-                    stack_free.put(buf)
-                    _release_slot()
-                    for slot, meta in enumerate(metas):
-                        in_flight.release()
-                        order_q.put((meta, words[slot]))
-                else:
-                    in_flight.release()  # re-balance the empty slot
-                    order_q.put((metas[0], None))
-        except BaseException as e:
-            stop.set()
-            order_q.put(e)
-
-    t_up = threading.Thread(target=upload, daemon=True)
-    t_down = threading.Thread(target=download, daemon=True)
-    threads += [t_up, t_down]
-    t_up.start()
-    t_down.start()
-
-    # Writer thread: native gather-writes run off the decode thread so
-    # partition p+1's permutation rebuild overlaps partition p's disk
-    # write (the ctypes call releases the GIL).  A sync thread
-    # periodically fdatasyncs the data file CONCURRENTLY with the
-    # writes, so the device write-cache flush pipelines behind the
-    # stream instead of landing as one multi-second close_sync tail.
-    write_q: "queue.Queue" = queue.Queue(maxsize=4)
-    writer_state = {"wrote": 0, "bytes": 0, "error": None}
-    have_sync = hasattr(lib, "dbeel_writer_sync")
-    will_bloom = total_input >= bloom_min_size
-    # A partition's arrays, from its decode to the writer and the bloom
-    # thread, are one of these sets, each sized for the largest
-    # partition and dirty from its last.  As many as can be alive: the
-    # one in the caller's hands, write_q's, the writer's.  A set is
-    # free again when BOTH the writer and the bloom thread have
-    # consumed its raw pointers.
-    sets_free: "queue.Queue" = queue.Queue()
-    for _ in range(min(n_parts, write_q.maxsize + 2)):
-        sets_free.put(_PartSet(mem, max_np))
-    sets_lock = threading.Lock()
-
-    def _set_done(pset):
-        with sets_lock:
-            pset.holders -= 1
-            idle = pset.holders == 0
-        if idle:
-            sets_free.put(pset)
-
-    def writer():
-        try:
-            while True:
-                try:
-                    job = write_q.get(timeout=0.25)
-                except queue.Empty:
-                    if stop.is_set():
-                        return
-                    continue
-                if job is None:
-                    return
-                sel_sz, args, nbytes, pset, p = job
-                with span("gather_write", part=p):
-                    rc = lib.dbeel_writer_put(handle, run_ptrs, *args)
-                _set_done(pset)
-                if rc != 0:
-                    writer_state["error"] = _PipelineError(
-                        "native gather-write failed"
-                    )
-                    stop.set()
-                    return
-                writer_state["wrote"] += sel_sz
-                writer_state["bytes"] += nbytes
-        except BaseException as e:
-            writer_state["error"] = e
-            stop.set()
-
-    sync_done = threading.Event()
-
-    def syncer():
-        # Flush ~every _SYNC_STRIDE of new bytes; safe concurrently
-        # with dbeel_writer_put (see dbeel_writer_sync).
-        last = 0
-        while not sync_done.wait(0.2):
-            b = writer_state["bytes"]
-            if b - last >= _SYNC_STRIDE:
-                with span("fsync"):
-                    lib.dbeel_writer_sync(handle)
-                last = b
-
-    # Bloom thread.  The filter's size follows from the FINAL entry
-    # count, a key's two hashes do not: each partition's keys are
-    # hashed here as it is queued for the writer (the writer job's own
-    # arrays, the same run pointers), and once the caller has posted
-    # the count the bits are set from the stored pairs and the bloom
-    # file is written and fsynced — under the writer's last partitions
-    # and the close's fdatasync, not after them.  Hashing is
-    # speculative: only a merge whose INPUT passes bloom_min_size can
-    # end with an output that does.  The queue is unbounded: write_q
-    # paces the caller, and a partition hashes several times faster
-    # than it gather-writes.
-    bloom_q: "queue.Queue" = queue.Queue()
-    bloom_state = {"blob": None, "error": None}
-
-    if will_bloom:
-        # Two hashes a key, and the bits of the largest filter the
-        # output can need (it never has more entries than the input).
-        pairs = mem.array(2 * total_rows, np.uint32)
-        bits_buf = mem.array((BloomFilter.size_for(total_rows)[0] + 7) // 8)
-
-    def bloomer(runs):
-        # ``runs``: the raw pointers in run_ptrs are only as alive as
-        # these buffers, whatever becomes of the caller's frame.
-        try:
-            u32p = ctypes.POINTER(ctypes.c_uint32)
-            u64p = ctypes.POINTER(ctypes.c_uint64)
-            hashed = 0
-            while True:
-                # Timed get + stop check, as the writer's.
-                if stop.is_set():
-                    return
-                try:
-                    item = bloom_q.get(timeout=0.25)
-                except queue.Empty:
-                    continue
-                if not isinstance(item, tuple):
-                    break
-                p, src_run, src_off, ks_sel, pset = item
-                with span("bloom_hash", part=p):
-                    lib.dbeel_bloom_hash_gather(
-                        run_ptrs,
-                        src_run.ctypes.data_as(u32p),
-                        src_off.ctypes.data_as(u64p),
-                        ks_sel.ctypes.data_as(u32p),
-                        src_run.size,
-                        ENTRY_HEADER_SIZE,
-                        _SEED1,
-                        _SEED2,
-                        pairs[2 * hashed :].ctypes.data_as(u32p),
-                    )
-                hashed += src_run.size
-                _set_done(pset)
-            # ``item`` is the output's entry count, or 0 where the
-            # output ended under bloom_min_size: the hashes are dropped.
-            if not item:
-                return
-            assert item == hashed
-            with span("bloom_set"):
-                num_bits, num_hashes = BloomFilter.size_for(hashed)
-                bits = bits_buf[: (num_bits + 7) // 8]
-                bits.fill(0)
-                bloom = BloomFilter(num_bits, num_hashes, bits=bits)
-                lib.dbeel_bloom_set_hashes(
-                    bloom.bits.ctypes.data_as(
-                        ctypes.POINTER(ctypes.c_uint8)
-                    ),
-                    bloom.num_bits,
-                    bloom.num_hashes,
-                    pairs.ctypes.data_as(u32p),
-                    hashed,
-                )
-                bloom_state["blob"] = _write_bloom(
-                    dir_path, output_index, bloom
-                )
-        except BaseException as e:  # re-raised by the caller's join
-            bloom_state["error"] = e
-
-    t_write = threading.Thread(target=writer, daemon=True)
-    threads.append(t_write)
-    t_write.start()
-    t_bloom = None
-    if will_bloom:
-        t_bloom = threading.Thread(
-            target=bloomer,
-            args=(runs,),
-            name="dbeel-pipeline-bloom",
-            daemon=True,
-        )
-        threads.append(t_bloom)
-        t_bloom.start()
-    t_sync = None
-    if _SYNC_STRIDE <= 0:
-        have_sync = False  # disabled: one flush at close only
-    if have_sync:
-        t_sync = threading.Thread(target=syncer, daemon=True)
-        threads.append(t_sync)
-        t_sync.start()
-
-    queued = queued_bytes = tie_entries = 0
-    failed = False
+    stop = _Stop(threads)
+    launches = _Launches(plan, inputs, mem, span, stop)
+    output = _Output(lib, plan, inputs, mem, span, stop, bloom_min_size)
+    if not output.handle:
+        # The single-shot writer meets the same disk and raises its
+        # errno.
+        return None
+    launches.start()
+    output.start()
+    tie_entries = 0
     try:
-        expected = 0
         while True:
-            # Timed get: the writer thread can fail and set ``stop``
-            # without ever feeding order_q (it is not part of the
-            # upload->download chain), so an untimed get could park
-            # this thread forever on e.g. a full disk.
             at.to("wait_device")
-            while True:
-                try:
-                    item = order_q.get(timeout=0.25)
-                    break
-                except queue.Empty:
-                    if writer_state["error"] is not None:
-                        raise writer_state["error"]
-                    if stop.is_set():
-                        raise _PipelineError("pipeline stopped")
+            item = stop.wait(launches.results.get)
             if item is None:
                 break
-            if isinstance(item, BaseException):
-                raise item
-            (p, counts, los, mode32, minpf, shift), packed = item
-            n_p = int(counts.sum())
-            pset = None
-            if n_p and sets_free.empty():
-                # Every set is queued or being written: the writer's
-                # queue is full, one step early.
-                at.to("wait_writer", part=p)
-            while n_p and pset is None:
-                try:
-                    pset = sets_free.get(timeout=0.25)
-                except queue.Empty:
-                    if stop.is_set() or writer_state["error"]:
-                        raise writer_state["error"] or _PipelineError(
-                            "writer stopped"
-                        )
-            at.to("decode", part=p)
-            if writer_state["error"] is not None:
-                raise writer_state["error"]
-            assert p == expected
-            expected += 1
-            if n_p == 0:
+            part, packed = item
+            if packed is None:  # an empty partition
+                at.to("decode", part=part.p)
                 continue
-            if have_decode:
-                # One C pass: unpack rids, per-run counters ->
-                # permutation, device-key tie flags.  Replaces the
-                # numpy unpack/bincount/argsort/cumcount chain — on a
-                # 1-core host this decode was ~40% of the pipeline's
-                # host CPU.
-                gidx = pset.gidx[:n_p]
-                rids32 = pset.rids32[:n_p]
-                tieb = pset.tieb[:n_p]
-                packed_c = np.ascontiguousarray(packed)
-                cnts_c = np.ascontiguousarray(
-                    counts[: len(runs)], dtype=np.uint32
-                )
-                los_c = np.ascontiguousarray(los, dtype=np.int64)
-                rc = lib.dbeel_pipe_decode(
-                    packed_c.ctypes.data_as(
-                        ctypes.POINTER(ctypes.c_uint32)
-                    ),
-                    ctypes.c_uint64(n_p),
-                    ctypes.c_uint32(pack_bits),
-                    ctypes.c_uint32(len(runs)),
-                    cnts_c.ctypes.data_as(
-                        ctypes.POINTER(ctypes.c_uint32)
-                    ),
-                    los_c.ctypes.data_as(
-                        ctypes.POINTER(ctypes.c_int64)
-                    ),
-                    run_base.ctypes.data_as(
-                        ctypes.POINTER(ctypes.c_int64)
-                    ),
-                    pf_cat.ctypes.data_as(
-                        ctypes.POINTER(ctypes.c_uint64)
-                    ),
-                    ctypes.c_uint64(minpf),
-                    ctypes.c_uint32(shift),
-                    1 if mode32 else 0,
-                    gidx.ctypes.data_as(
-                        ctypes.POINTER(ctypes.c_int64)
-                    ),
-                    rids32.ctypes.data_as(
-                        ctypes.POINTER(ctypes.c_uint32)
-                    ),
-                    tieb.ctypes.data_as(
-                        ctypes.POINTER(ctypes.c_uint8)
-                    ),
-                )
-                if rc != 0:
-                    raise _PipelineError(
-                        "packed run-id decode mismatch"
-                    )
-                flags = tieb[1:].view(np.bool_)
-            else:
-                rids = unpack_rids(packed, pack_bits, n_p).astype(
-                    np.int64
-                )
-                # Rebuild positions: the comparator is a total order
-                # and runs are pre-sorted, so each run's entries
-                # appear in increasing position order — a per-run
-                # counter inverts it.  One bincount (decode check) +
-                # one stable argsort (grouped cumcount), independent
-                # of the run count.
-                counts_dec = np.bincount(rids, minlength=len(runs))
-                if counts_dec.size > len(runs) or not (
-                    counts_dec == counts[: len(runs)]
-                ).all():
-                    raise _PipelineError(
-                        "packed run-id decode mismatch"
-                    )
-                grouped = np.argsort(rids, kind="stable")
-                group_lo = np.concatenate(
-                    [[0], np.cumsum(counts_dec)[:-1]]
-                )
-                pos = np.empty(n_p, dtype=np.int64)
-                pos[grouped] = np.arange(
-                    n_p, dtype=np.int64
-                ) - np.repeat(group_lo, counts_dec)
-                gidx = run_base[rids] + los[rids] + pos
-                rids32 = rids.astype(np.uint32)
-
-            # Tie blocks: adjacent entries equal under the DEVICE sort
-            # key (shifted u32 or exact 8B prefix) are re-ordered by
-            # (full key, newest ts, newest src) — one vectorized
-            # lexsort — and duplicate keys are marked for dedup.
-            if not have_decode:
-                pf = pf_cat[gidx]
-                if mode32:
-                    dv = (pf - np.uint64(minpf)) >> np.uint64(shift)
-                    flags = dv[1:] == dv[:-1]
-                else:
-                    flags = pf[1:] == pf[:-1]
-            keep = pset.keep[:n_p]
-            keep.fill(True)
-            with span("tie_fixup", part=p):
-                positions, block_id = columnar.tie_positions_and_blocks(
-                    flags, pset.mask
-                )
-                tie_entries += int(positions.size)
-                if positions.size:
-                    sel_t = gidx[positions]
-                    ks_t = ks_cat[sel_t]
-                    ent_w = columnar.tie_block_widths(block_id, ks_t)
-                    for w in np.unique(ent_w):
-                        bm = ent_w == w
-                        kwords, inv_ts, inv_src = _gather_tie_arrays(
-                            runs,
-                            run_base,
-                            off_cat,
-                            ks_cat,
-                            sel_t[bm],
-                            int(w),
-                        )
-                        order, dup = columnar.tie_block_sort(
-                            block_id[bm], kwords, ks_t[bm], inv_ts, inv_src
-                        )
-                        gidx[positions[bm]] = sel_t[bm][order]
-                        # The reorder moved entries across runs: refresh
-                        # the run-id column at exactly those positions.
-                        rids32[positions[bm]] = (
-                            np.searchsorted(
-                                run_base, gidx[positions[bm]], side="right"
-                            )
-                            - 1
-                        ).astype(np.uint32)
-                        keep[positions[bm]] = ~dup
-
-            if not keep_tombstones:
-                # (mode="clip": numpy buffers ``out`` under "raise".)
-                drop = np.take(
-                    tomb_cat, gidx, out=pset.mask[:n_p], mode="clip"
-                )
-                if tombstone_drop_before and drop.any():
-                    # gc_grace: tombstones younger than the cutoff
-                    # survive the drop.  Timestamps are gathered only
-                    # for the drop candidates.
-                    cand = np.flatnonzero(drop)
-                    cand_ts = _gather_timestamps(
-                        runs, run_base, off_cat, gidx[cand]
-                    )
-                    drop[
-                        cand[
-                            cand_ts
-                            >= np.uint64(tombstone_drop_before)
-                        ]
-                    ] = False
-                np.logical_not(drop, out=drop)
-                keep &= drop
-            m = int(np.count_nonzero(keep))
-            if m == 0:
-                sets_free.put(pset)
+            if output.sets_busy():
+                at.to("wait_writer", part=part.p)
+            pset = output.take_set()
+            at.to("decode", part=part.p)
+            job, ties = _decode(
+                lib, inputs, plan, part, packed, pset, span,
+                tombstone_drop_before,
+            )
+            tie_entries += ties
+            if job is None:
+                output.give_set(pset)
                 continue
-            if m != n_p:
-                sel = np.compress(keep, gidx, out=pset.sel[:m])
-                src_run = np.compress(keep, rids32, out=pset.src_run[:m])
-            else:
-                sel = gidx
-                src_run = np.ascontiguousarray(rids32)
-            src_off = np.take(
-                off_cat, sel, out=pset.src_off[:m], mode="clip"
-            )
-            ks_sel = np.take(ks_cat, sel, out=pset.ks_sel[:m], mode="clip")
-            fs_sel = np.take(fs_cat, sel, out=pset.fs_sel[:m], mode="clip")
-            args = (
-                src_run.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-                src_off.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
-                ks_sel.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-                fs_sel.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-                ctypes.c_uint64(m),
-            )
-            nbytes = int(fs_sel.sum())
-            # The set stays out of ``sets_free`` exactly until the
-            # writer and the bloom thread have consumed the raw
-            # pointers (the number of sets caps the live jobs).
-            pset.holders = 2 if t_bloom is not None else 1
-            job = (m, args, nbytes, pset, p)
-            queued += m
-            queued_bytes += nbytes
-            if t_bloom is not None:
-                bloom_q.put((p, src_run, src_off, ks_sel, pset))
-            at.to("wait_writer", part=p)
-            while True:
-                try:
-                    write_q.put(job, timeout=0.25)
-                    break
-                except queue.Full:
-                    if stop.is_set() or writer_state["error"]:
-                        raise writer_state["error"] or _PipelineError(
-                            "writer stopped"
-                        )
+            at.to("wait_writer", part=part.p)
+            output.put(job)
             if throttle is not None:
                 # Latency class: one partition is the consume quantum —
                 # pay back CPU to serving between partitions.
-                at.to("throttle", part=p)
+                at.to("throttle", part=part.p)
                 throttle.tick()
-        # The last partition is queued, so the output's entry count
-        # and size are known while the writer still has its queue to
-        # write: the set phase starts here.
-        wants_bloom = queued > 0 and queued_bytes >= bloom_min_size
-        if t_bloom is not None:
-            bloom_q.put(queued if wants_bloom else 0)
-        at.to("wait_writer")
-        write_q.put(None)
-        t_write.join(timeout=600)
-        if writer_state["error"] is not None:
-            raise writer_state["error"]
-    except BaseException:
-        failed = True
-        stop.set()
-        t_write.join(timeout=60)
-        # Joined before ``runs`` can go: it hashes through run_ptrs.
-        if t_bloom is not None:
-            t_bloom.join(timeout=60)
-            if not t_bloom.is_alive():
-                _unlink_quiet(bloom_path)
-        sync_done.set()
-        if t_sync is not None:
-            t_sync.join(timeout=60)
-        if t_write.is_alive() or (
-            t_sync is not None and t_sync.is_alive()
-        ):
-            # A wedged writer/sync thread may still hold the native
-            # handle: leak it (and the partial files) rather than
-            # free memory under a live pwrite/fdatasync.
-            log.error(
-                "pipeline writer/sync thread wedged; leaking native "
-                "writer handle for %s", data_path
-            )
-        else:
-            lib.dbeel_writer_abort(handle)
-            _unlink_quiet(data_path, index_path)
-        raise
+        entries, data_size, wrote_bloom = output.finish(at)
+    except BaseException as e:
+        stop.fail(e)
+        output.abort()
+        raise stop.error  # the first, whichever thread met it
     finally:
-        t_up.join(timeout=60)
-        t_down.join(timeout=60)
-        while _release_slot():
-            pass
-        if failed:
-            # A launch that was never read back may still be reading
-            # its stack: a failed merge's stacks are not returned.
-            for buf in stacks:
-                mem.forget(buf)
-
-    sync_done.set()
-    if t_sync is not None:
-        t_sync.join(timeout=60)
-    if t_write.is_alive() or (
-        t_sync is not None and t_sync.is_alive()
-    ):
-        log.error(
-            "pipeline writer/sync thread wedged at close; leaking "
-            "native writer handle for %s", data_path
-        )
-        raise _PipelineError("writer thread wedged")
-    # Close (final fdatasync + truncate) runs on its own thread: the
-    # entry and byte counts are known from the writer's own
-    # accounting, so nothing below depends on its completing.  By now
-    # the bloom thread has had the count since the last partition was
-    # queued (the writer's final join ago), so the caller's ``bloom``
-    # stage is a join: what the set phase and the bloom file's fsync
-    # have left over runs beside the close's device write-cache flush
-    # (VERDICT r3 #7: that flush was ~0.5-1s of serial tail), and the
-    # caller then waits in ``close_wait`` for whatever of the flush
-    # remains.
-    at.to("bloom")
-    data_size = ctypes.c_uint64(0)
-    close_ret = {"entries": -1, "crcs": None}
-    # CRC handoff caps: the merged output can never exceed the sum of
-    # its inputs (dedup/tombstone-drop only shrink it).
-    _dcap = int(sum(r.size for r in runs)) // 4096 + 2
-    _icap = int(run_base[-1]) * 16 // 4096 + 2
-
-    def _close():
-        # Inside either call: the final fdatasync + truncate.
-        if writer_crcs:
-            dcrc = (ctypes.c_uint32 * _dcap)()
-            icrc = (ctypes.c_uint32 * _icap)()
-            nd = ctypes.c_uint64(0)
-            ni = ctypes.c_uint64(0)
-            with span("fsync"):
-                rc = lib.dbeel_writer_close2(
-                    handle,
-                    ctypes.byref(data_size),
-                    dcrc,
-                    _dcap,
-                    icrc,
-                    _icap,
-                    ctypes.byref(nd),
-                    ctypes.byref(ni),
-                )
-            if rc == -2:
-                # Triplet closed fine; only the CRC handoff was
-                # refused — the LSM's counted post-hoc sidecar
-                # covers it.  Entries are known from the writer's
-                # own accounting.
-                close_ret["entries"] = writer_state["wrote"]
-            else:
-                close_ret["entries"] = rc
-                if rc >= 0:
-                    close_ret["crcs"] = (
-                        list(dcrc[: nd.value]),
-                        list(icrc[: ni.value]),
-                    )
-        else:
-            with span("fsync"):
-                close_ret["entries"] = lib.dbeel_writer_close(
-                    handle, ctypes.byref(data_size)
-                )
-
-    t_close = threading.Thread(target=_close, daemon=True)
-    threads.append(t_close)
-    t_close.start()
-
-    entries = writer_state["wrote"]
-    assert (entries, writer_state["bytes"]) == (queued, queued_bytes)
-    bloom_blob = None
-    try:
-        if t_bloom is not None:
-            t_bloom.join(timeout=600)
-            if t_bloom.is_alive():
-                raise _PipelineError("bloom thread wedged")
-            if bloom_state["error"] is not None:
-                raise bloom_state["error"]
-            bloom_blob = bloom_state["blob"]
-    except BaseException:
-        # The merge's contract is the whole triplet: a failed bloom
-        # build (ENOSPC, MemoryError) must not leave the data/index
-        # behind looking complete.  Join the async close first — never
-        # unlink under a live fdatasync/truncate.
-        t_close.join(timeout=600)
-        if not t_close.is_alive():
-            _unlink_quiet(data_path, index_path)
-        if t_bloom is None or not t_bloom.is_alive():
-            _unlink_quiet(bloom_path)
-        raise
-    wrote_bloom = bloom_blob is not None
-    assert wrote_bloom == wants_bloom
-
-    at.to("close_wait")
-    t_close.join(timeout=600)
-    if t_close.is_alive():
-        log.error(
-            "pipeline writer close wedged; leaking native writer "
-            "handle for %s", data_path
-        )
-        raise _PipelineError("writer close wedged")
-    if close_ret["entries"] < 0:
-        _unlink_quiet(data_path, index_path, bloom_path)
-        raise _PipelineError("native writer close failed")
-    assert close_ret["entries"] == entries
-    assert int(data_size.value) == writer_state["bytes"]
-
-    at.to("sidecar")
-    if close_ret["crcs"] is not None:
-        # Single-pass sidecar: the per-page CRCs streamed out of the
-        # gather writer; the bloom blob is still in RAM.  Written
-        # under the same journaled rename as the triplet.
-        from ..storage import checksums
-
-        dcrcs, icrcs = close_ret["crcs"]
-        checksums.write_crcs(
-            dir_path,
-            output_index,
-            dcrcs,
-            icrcs,
-            int(data_size.value),
-            zlib.crc32(bloom_blob) if bloom_blob is not None else 0,
-            bloom_blob is not None,
-            ext=checksums.COMPACT_SUMS_FILE_EXT,
-        )
-
-    # The upload thread is joined: ``launches`` has counted them all,
-    # and every launch has the one compiled shape.
-    n_launches = next(launches)
+        launches.close()
     shape.update(
-        launches=n_launches,
-        partitions=n_parts,
-        rows_launched=n_launches * launch_j * k2 * p2,
-        rows_real=int(run_base[-1]),
-        runs_in=len(runs),
+        launches=launches.launched,
+        partitions=plan.n_parts,
+        rows_launched=launches.launched * plan.launch_j * plan.k2 * plan.p2,
+        rows_real=inputs.total_rows,
+        runs_in=len(inputs.runs),
         tie_entries=tie_entries,
     )
-    return MergeResult(int(entries), int(data_size.value), wrote_bloom)
+    return MergeResult(int(entries), int(data_size), wrote_bloom)

@@ -217,19 +217,36 @@ def _load() -> Optional[ctypes.CDLL]:
             TICK_FN,
             ctypes.c_uint64,
         ]
-    if hasattr(lib, "dbeel_stage_prefixes"):
-        lib.dbeel_stage_prefixes.restype = None
-        lib.dbeel_stage_prefixes.argtypes = [
-            u8p,
-            ctypes.c_uint64,
-            ctypes.POINTER(ctypes.c_uint64),
-            ctypes.POINTER(ctypes.c_uint32),
-            ctypes.c_uint64,
-            ctypes.c_uint64,
-            u8p,
-        ]
-    lib.dbeel_writer_open.restype = ctypes.c_void_p
-    lib.dbeel_writer_open.argtypes = [ctypes.c_char_p, ctypes.c_char_p]
+    # The device pipeline's symbols (ops/pipeline.py), here and beside
+    # the bloom's below, are bound without a probe: a library that
+    # lacks one fails the load by its name.
+    lib.dbeel_stage_prefixes.restype = None
+    lib.dbeel_stage_prefixes.argtypes = [
+        u8p,
+        ctypes.c_uint64,
+        ctypes.POINTER(ctypes.c_uint64),
+        ctypes.POINTER(ctypes.c_uint32),
+        ctypes.c_uint64,
+        ctypes.c_uint64,
+        u8p,
+    ]
+    lib.dbeel_pipe_decode.restype = ctypes.c_int
+    lib.dbeel_pipe_decode.argtypes = [
+        ctypes.POINTER(ctypes.c_uint32),
+        ctypes.c_uint64,
+        ctypes.c_uint32,
+        ctypes.c_uint32,
+        ctypes.POINTER(ctypes.c_uint32),
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_uint64),
+        ctypes.c_uint64,
+        ctypes.c_uint32,
+        ctypes.c_int,
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_uint32),
+        u8p,
+    ]
     lib.dbeel_writer_put.restype = ctypes.c_int64
     lib.dbeel_writer_put.argtypes = [
         ctypes.c_void_p,
@@ -240,38 +257,28 @@ def _load() -> Optional[ctypes.CDLL]:
         ctypes.POINTER(ctypes.c_uint32),
         ctypes.c_uint64,
     ]
-    lib.dbeel_writer_close.restype = ctypes.c_int64
-    lib.dbeel_writer_close.argtypes = [
-        ctypes.c_void_p,
-        ctypes.POINTER(ctypes.c_uint64),
-    ]
     lib.dbeel_writer_abort.restype = None
     lib.dbeel_writer_abort.argtypes = [ctypes.c_void_p]
-    if hasattr(lib, "dbeel_writer_sync"):
-        lib.dbeel_writer_sync.restype = None
-        lib.dbeel_writer_sync.argtypes = [ctypes.c_void_p]
-    if hasattr(lib, "dbeel_writer_open2"):
-        # Single-pass sidecar gather writer (ISSUE 15): per-page CRCs
-        # accumulated as bytes are emitted, handed back at close so
-        # the .sums sidecar costs zero re-reads.  Gated together with
-        # close2 — one build ships both.
-        lib.dbeel_writer_open2.restype = ctypes.c_void_p
-        lib.dbeel_writer_open2.argtypes = [
-            ctypes.c_char_p,
-            ctypes.c_char_p,
-            ctypes.c_int32,
-        ]
-        lib.dbeel_writer_close2.restype = ctypes.c_int64
-        lib.dbeel_writer_close2.argtypes = [
-            ctypes.c_void_p,
-            ctypes.POINTER(ctypes.c_uint64),
-            ctypes.POINTER(ctypes.c_uint32),
-            ctypes.c_uint64,
-            ctypes.POINTER(ctypes.c_uint32),
-            ctypes.c_uint64,
-            ctypes.POINTER(ctypes.c_uint64),
-            ctypes.POINTER(ctypes.c_uint64),
-        ]
+    # Single-pass sidecar gather writer (ISSUE 15): per-page CRCs
+    # accumulated as bytes are emitted, handed back at close so
+    # the .sums sidecar costs zero re-reads.
+    lib.dbeel_writer_open2.restype = ctypes.c_void_p
+    lib.dbeel_writer_open2.argtypes = [
+        ctypes.c_char_p,
+        ctypes.c_char_p,
+        ctypes.c_int32,
+    ]
+    lib.dbeel_writer_close2.restype = ctypes.c_int64
+    lib.dbeel_writer_close2.argtypes = [
+        ctypes.c_void_p,
+        ctypes.POINTER(ctypes.c_uint64),
+        ctypes.POINTER(ctypes.c_uint32),
+        ctypes.c_uint64,
+        ctypes.POINTER(ctypes.c_uint32),
+        ctypes.c_uint64,
+        ctypes.POINTER(ctypes.c_uint64),
+        ctypes.POINTER(ctypes.c_uint64),
+    ]
     if hasattr(lib, "dbeel_memtable_flush_write2"):
         # Single-pass native flush: triplet write + inline sidecar
         # CRCs in one GIL-free call (replaces the post-hoc

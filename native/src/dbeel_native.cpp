@@ -788,17 +788,6 @@ int64_t dbeel_writer_close2(void* handle, uint64_t* data_size,
   return entries;
 }
 
-// Flush the data file's written bytes to stable storage WITHOUT
-// closing: safe to call concurrently with dbeel_writer_put from
-// another thread (fdatasync and pwrite on the same fd are
-// independent), letting callers pipeline the device-cache flush
-// behind the write stream instead of paying it all at close_sync.
-// Only touches the kernel-visible file, never the writer's buffers.
-void dbeel_writer_sync(void* handle) {
-  auto* w = static_cast<GatherWriter*>(handle);
-  if (w->data.fd >= 0) ::fdatasync(w->data.fd);
-}
-
 void dbeel_writer_abort(void* handle) {
   auto* w = static_cast<GatherWriter*>(handle);
   w->data.abort_close();

@@ -891,6 +891,26 @@ def test_stats_schema_covers_the_pipeline_shape_block(tmp_path):
         assert any("self.shape" in f.message for f in findings) == fires
 
 
+def test_pipeline_probes_nothing_and_its_library_exports_what_it_calls():
+    # ISSUE 30: the library the device pipeline sees is always this
+    # tree's (native.require() builds it or raises), so the pipeline
+    # neither probes it for a symbol nor reads an environment knob; and
+    # every symbol it calls — the list is taken from its source — is
+    # one the built library exports.
+    import re
+
+    from dbeel_tpu.storage import native
+
+    with open(os.path.join(REPO_ROOT, "dbeel_tpu/ops/pipeline.py")) as f:
+        source = f.read()
+    for banned in ("hasattr(lib", "os.environ", "getenv"):
+        assert banned not in source, banned
+    called = set(re.findall(r"lib\.(dbeel_\w+)", source))
+    assert {"dbeel_pipe_decode", "dbeel_writer_close2"} <= called
+    lib = native.require()
+    assert [name for name in sorted(called) if not hasattr(lib, name)] == []
+
+
 def test_stats_schema_escape_comment(tmp_path):
     root = _stats_tree(
         tmp_path,

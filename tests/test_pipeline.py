@@ -435,6 +435,16 @@ def _merge(name, d, idxs, oi):
     return _merge_with_bar(name, d, idxs, oi, 1)[0]
 
 
+def _both_launch_slots_are_free():
+    """Every permit is back: both can be taken without waiting."""
+    from dbeel_tpu.ops.pipeline import _LAUNCH_SLOTS
+
+    assert _LAUNCH_SLOTS.acquire(timeout=0)
+    assert _LAUNCH_SLOTS.acquire(timeout=0)
+    _LAUNCH_SLOTS.release()
+    _LAUNCH_SLOTS.release()
+
+
 def test_launch_slots_bound_every_merge_of_the_process(
     tmp_dir, monkeypatch
 ):
@@ -510,11 +520,7 @@ def test_launch_slots_bound_every_merge_of_the_process(
         assert got[d] == _merge("heap", d, ix, 101)
     assert seen["launches"] >= 4, seen  # two or more per merge
     assert seen["peak"] <= 2 and seen["unread"] == 0, seen
-    # Every permit is back: both can be taken without waiting.
-    assert pipeline_mod._LAUNCH_SLOTS.acquire(timeout=0)
-    assert pipeline_mod._LAUNCH_SLOTS.acquire(timeout=0)
-    pipeline_mod._LAUNCH_SLOTS.release()
-    pipeline_mod._LAUNCH_SLOTS.release()
+    _both_launch_slots_are_free()
 
 
 def test_launch_slots_come_back_from_a_failed_merge(tmp_dir, monkeypatch):
@@ -522,7 +528,6 @@ def test_launch_slots_come_back_from_a_failed_merge(tmp_dir, monkeypatch):
     fails its merge; its permit must not stay taken, or every later
     big merge of the process would wait for ever."""
     from dbeel_tpu.ops import bitonic
-    from dbeel_tpu.ops import pipeline as pipeline_mod
 
     monkeypatch.setattr(DeviceMergeStrategy, "PIPELINE_MIN_BYTES", 0)
 
@@ -537,10 +542,150 @@ def test_launch_slots_come_back_from_a_failed_merge(tmp_dir, monkeypatch):
     idxs = _write_random_runs(tmp_dir, 50)
     with pytest.raises(RuntimeError, match="injected"):
         _merge("device", tmp_dir, idxs, 103)
-    assert pipeline_mod._LAUNCH_SLOTS.acquire(timeout=0)
-    assert pipeline_mod._LAUNCH_SLOTS.acquire(timeout=0)
-    pipeline_mod._LAUNCH_SLOTS.release()
-    pipeline_mod._LAUNCH_SLOTS.release()
+    _both_launch_slots_are_free()
+
+
+# ---- a failure in each box, and the threads of a merge ---------------
+
+OUTPUT_EXTS = ("compact_data", "compact_index", "compact_bloom", "compact_sums")
+
+
+def _fail_a_reader(m, pipeline_mod, bitonic, lib):
+    real = pipeline_mod._read_run
+
+    def read(lib, source, *rest):
+        if source.data_path.endswith(file_name(2, "data")):
+            raise OSError("injected: read failed")
+        return real(lib, source, *rest)
+
+    m.setattr(pipeline_mod, "_read_run", read)
+
+
+def _fail_the_launcher(m, pipeline_mod, bitonic, lib):
+    def plan(*a):
+        raise RuntimeError("injected: operand")
+
+    m.setattr(pipeline_mod, "_plan_operand", plan)
+
+
+def _fail_the_downloader(m, pipeline_mod, bitonic, lib):
+    class Unreadable:
+        def __array__(self, dtype=None, copy=None):
+            raise RuntimeError("injected: read-back")
+
+    for name in (
+        "merge_runs_prefix32_packed_batch_kernel",
+        "merge_runs_prefix64_packed_batch_kernel",
+    ):
+        m.setattr(bitonic, name, lambda dev, counts, bits: Unreadable())
+
+
+def _fail_the_decode(m, pipeline_mod, bitonic, lib):
+    m.setattr(lib, "dbeel_pipe_decode", lambda *a: -1)
+
+
+def _fail_the_close(m, pipeline_mod, bitonic, lib):
+    real = lib.dbeel_writer_close2
+
+    def close(*a):
+        real(*a)  # the files are closed and the handle freed
+        return -1
+
+    m.setattr(lib, "dbeel_writer_close2", close)
+
+
+@pytest.mark.parametrize(
+    "inject,error,message",
+    [
+        (_fail_a_reader, OSError, "injected: read failed"),
+        (_fail_the_launcher, RuntimeError, "injected: operand"),
+        (_fail_the_downloader, RuntimeError, "injected: read-back"),
+        (_fail_the_decode, Exception, "decode mismatch"),
+        (_fail_the_close, Exception, "writer close failed"),
+    ],
+    ids=["reader", "launcher", "downloader", "decode", "close"],
+)
+def test_a_failure_in_any_box_fails_the_merge_and_leaves_nothing(
+    tmp_dir, monkeypatch, inject, error, message
+):
+    """Whichever box fails, on whichever thread: the merge raises that
+    error on the calling thread within seconds, no file of the output
+    is left, every block is back in the pool, both launch permits can
+    be taken, no span is open, and the next merge of the same inputs
+    is whole.  (The writer, the bloom write and a refused launch:
+    test_a_failed_writer_leaves_nothing_leased,
+    test_a_failed_bloom_write_..., test_launch_slots_come_back_...)"""
+    import time
+
+    from dbeel_tpu.ops import bitonic
+    from dbeel_tpu.ops import pipeline as pipeline_mod
+    from dbeel_tpu.storage import native
+    from dbeel_tpu.storage.compaction import compaction_stats
+
+    monkeypatch.setattr(DeviceMergeStrategy, "PIPELINE_MIN_BYTES", 0)
+    idxs = _write_random_runs(tmp_dir, 90)
+    want = _merge("native", tmp_dir, idxs, 101)
+    before = _pipeline_stages()
+    t0 = time.monotonic()
+    with monkeypatch.context() as m:
+        inject(m, pipeline_mod, bitonic, native.require())
+        with pytest.raises(error, match=message):
+            _merge("device", tmp_dir, idxs, 103)
+    assert time.monotonic() - t0 < 20
+    _one_merge_closed(*before)
+    for ext in OUTPUT_EXTS:
+        assert not os.path.exists(f"{tmp_dir}/{file_name(103, ext)}"), ext
+    assert compaction_stats.stats()["pool"]["leased_bytes"] == 0
+    _both_launch_slots_are_free()
+    assert not _pipeline_threads_alive()
+    before = _pipeline_stages()
+    assert _merge("device", tmp_dir, idxs, 105) == want
+    _one_merge_closed(*before)
+    assert compaction_stats.stats()["pool"]["leased_bytes"] == 0
+
+
+def _pipeline_threads_alive():
+    import threading
+
+    return sorted(
+        t.name
+        for t in threading.enumerate()
+        if t.name.startswith("dbeel-pipeline-")
+    )
+
+
+def test_a_merge_starts_its_named_threads_and_leaves_none(
+    tmp_dir, monkeypatch
+):
+    """The boxes of ops/pipeline.py's docstring, by the threads that
+    run them: two readers, upload, download, writer, bloom, close —
+    those and no other, and none outlives the merge."""
+    import threading
+
+    monkeypatch.setattr(DeviceMergeStrategy, "PIPELINE_MIN_BYTES", 0)
+    idxs = _write_random_runs(tmp_dir, 91)
+    want = _merge("native", tmp_dir, idxs, 101)
+    assert _merge("device", tmp_dir, idxs, 103) == want  # compiled
+    started = []
+    real_start = threading.Thread.start
+
+    def start(self):
+        started.append(self.name)
+        real_start(self)
+
+    with monkeypatch.context() as m:
+        m.setattr(threading.Thread, "start", start)
+        assert _merge("device", tmp_dir, idxs, 105) == want
+    assert sorted(started) == [
+        "dbeel-pipeline-bloom",
+        "dbeel-pipeline-close",
+        "dbeel-pipeline-download",
+        "dbeel-pipeline-read_0",
+        "dbeel-pipeline-read_1",
+        "dbeel-pipeline-upload",
+        "dbeel-pipeline-writer",
+    ]
+    assert not _pipeline_threads_alive()
 
 
 # ---- stage spans and stage-second counters (ops/spans.py) ------------
