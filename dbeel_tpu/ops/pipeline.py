@@ -38,14 +38,21 @@ What each box is, which thread runs it, and what crosses each arrow:
   bit-packed run-id sequence.  Out, in partition order: (partition,
   its packed words) on ``_Launches.results``, then None.
 * **decode** (``_decode``; the calling thread).  One C pass rebuilds
-  the permutation and flags entries equal under the DEVICE key; those
-  tie blocks (shift collisions, shared 8-byte prefixes, long keys) are
-  re-ordered by one lexsort over padded key words — (full key asc,
-  newest ts, newest src), the reference's merge order
-  (/root/reference/src/storage_engine/lsm_tree.rs:1038-1066) — and
-  deduplicated; tombstones are dropped where the merge drops them.
-  Out: a ``_Job`` — run, offset, key size and full size per surviving
-  entry, in the partition set it was given.
+  the permutation and flags entries equal under the DEVICE key.  The
+  device key is a prefix and the device leaves ties in (run, position)
+  order, so versions of one key in several runs ALWAYS tie, as do
+  shift collisions, shared 8-byte prefixes and long keys: a second C
+  pass (``dbeel_pipe_resolve_ties``) sorts every tie block where its
+  records lie, in the runs' buffers, by (full key asc, newest ts,
+  newest src) — the reference's merge order
+  (/root/reference/src/storage_engine/lsm_tree.rs:1038-1066); the
+  timestamp is read from the record, never inferred from the run —
+  and marks a key's older versions.  It declines no block, so nothing
+  is left to numpy (``_tie_fixup_numpy``, the lexsort it replaced, is
+  what the tests hold it to).  Tombstones are dropped where the merge
+  drops them, the survivors compressed.  Out: a ``_Job`` — run,
+  offset, key size and full size per surviving entry, in the partition
+  set it was given.
 * **output** (``_Output``; writer, bloom and close threads).  The
   writer gather-writes each job through the native handle (O_DIRECT
   stream, page CRCs accumulated inline); the bloom thread builds the
@@ -270,6 +277,7 @@ class _Inputs:
     fs_cat: np.ndarray  # u32 record sizes
     pf_cat: np.ndarray  # u64 native-endian 8-byte key prefixes
     run_ptrs: ctypes.Array  # each run's data, for C to gather from
+    run_sizes: np.ndarray  # u64 logical bytes of each run's data
     total_rows: int
     total_bytes: int
 
@@ -331,9 +339,10 @@ def _read_inputs(lib, sources: Sequence, mem: Leases, span) -> _Inputs:
     run_ptrs = (_u8p * max(1, len(runs)))(
         *[r.data.ctypes.data_as(_u8p) for r in runs]
     )
+    run_sizes = np.array([r.size for r in runs], dtype=np.uint64)
     return _Inputs(
         runs, run_base, off_cat, ks_cat, fs_cat, pf_cat, run_ptrs,
-        total_rows, int(sum(r.size for r in runs)),
+        run_sizes, total_rows, int(run_sizes.sum()),
     )
 
 
@@ -626,8 +635,7 @@ class _PartSet:
         self.rids32 = mem.array(rows, np.uint32)  # and their runs
         self.tieb = mem.array(rows, np.uint8)  # device-key tie flags
         self.keep = mem.array(rows, np.bool_)
-        # Scratch: the tie blocks' members, then the tombstones.
-        self.mask = mem.array(rows, np.bool_)
+        self.mask = mem.array(rows, np.bool_)  # scratch: tombstones
         self.sel = mem.array(rows, np.int64)  # gidx[keep]
         self.src_run = mem.array(rows, np.uint32)  # rids32[keep]
         self.src_off = mem.array(rows, np.uint64)
@@ -671,7 +679,8 @@ def pipeline_merge(
     nested ``stage_prefixes`` (in ``read_stage``) and ``tie_fixup`` (in
     ``decode``) overlap them and say what the caller was waiting on.
     The merge's shape (launches, partitions, rows launched and real,
-    runs, tie entries) is counted under ``get_stats.compaction.shape``."""
+    runs, tie entries, entries written) is counted under
+    ``get_stats.compaction.shape``."""
     shape: dict = {}
     mem = _POOL.leases()
     threads: List[threading.Thread] = []
@@ -1015,6 +1024,45 @@ def _gather_tie_arrays(runs, run_base, off_cat, ks_cat, sel, lpad):
     return kwords, ~ts, ~ri.astype(np.uint32)
 
 
+def _tie_fixup_numpy(inputs: _Inputs, gidx, rids32, tieb, keep) -> int:
+    """What ``dbeel_pipe_resolve_ties`` does, in numpy, as the pipeline
+    did it until ISSUE 34: the tie blocks gathered into padded key
+    words and timestamps and ordered by one lexsort a key width
+    (``columnar.tie_block_sort``).  Nothing on the merge path calls it
+    any more (~1.2 us an entry on the caller's thread); the tests hold
+    the C pass to it.  In place in ``gidx``, ``rids32`` and ``keep``;
+    returns the entries in tie blocks."""
+    runs, run_base = inputs.runs, inputs.run_base
+    off_cat, ks_cat = inputs.off_cat, inputs.ks_cat
+    keep.fill(True)
+    positions, block_id = columnar.tie_positions_and_blocks(
+        tieb[1:].view(np.bool_)
+    )
+    if positions.size:
+        sel_t = gidx[positions]
+        ks_t = ks_cat[sel_t]
+        ent_w = columnar.tie_block_widths(block_id, ks_t)
+        for w in np.unique(ent_w):
+            bm = ent_w == w
+            kwords, inv_ts, inv_src = _gather_tie_arrays(
+                runs, run_base, off_cat, ks_cat, sel_t[bm], int(w)
+            )
+            order, dup = columnar.tie_block_sort(
+                block_id[bm], kwords, ks_t[bm], inv_ts, inv_src
+            )
+            gidx[positions[bm]] = sel_t[bm][order]
+            # The reorder moved entries across runs: refresh the
+            # run-id column at exactly those positions.
+            rids32[positions[bm]] = (
+                np.searchsorted(
+                    run_base, gidx[positions[bm]], side="right"
+                )
+                - 1
+            ).astype(np.uint32)
+            keep[positions[bm]] = ~dup
+    return int(positions.size)
+
+
 def _gather_timestamps(runs, run_base, off_cat, sel):
     """Per-record int64-ns timestamps (as u64 bit views) for the
     GLOBAL indices ``sel`` — gathered lazily, because the pipeline
@@ -1066,8 +1114,8 @@ def _decode(
     tombstone_drop_before: "int | None",
 ):
     """One partition from the downloader's packed run-ids to the
-    writer's job, in ``pset``.  Returns (job, entries the host tie
-    fix-up took); the job is None where no entry survives.  Span
+    writer's job, in ``pset``.  Returns (job, entries the device
+    order left tied); the job is None where no entry survives.  Span
     ``tie_fixup``, nested in the caller's ``decode``."""
     runs, run_base = inputs.runs, inputs.run_base
     off_cat, ks_cat = inputs.off_cat, inputs.ks_cat
@@ -1103,37 +1151,25 @@ def _decode(
         raise _PipelineError("packed run-id decode mismatch")
 
     # Tie blocks: adjacent entries equal under the DEVICE sort key
-    # (shifted u32 or exact 8B prefix) are re-ordered by (full key,
-    # newest ts, newest src) — one vectorized lexsort — and duplicate
-    # keys are marked for dedup.
+    # (shifted u32 or exact 8B prefix) — versions of one key above
+    # all — are put in the reference's order and older versions marked,
+    # in one C pass over the runs' own bytes (GIL released).
     keep = pset.keep[:n_p]
-    keep.fill(True)
     with span("tie_fixup", part=part.p):
-        positions, block_id = columnar.tie_positions_and_blocks(
-            tieb[1:].view(np.bool_), pset.mask
+        ties = lib.dbeel_pipe_resolve_ties(
+            n_p,
+            tieb.ctypes.data_as(_u8p),
+            gidx.ctypes.data_as(_i64p),
+            rids32.ctypes.data_as(_u32p),
+            inputs.run_ptrs,
+            inputs.run_sizes.ctypes.data_as(_u64p),
+            off_cat.ctypes.data_as(_u64p),
+            ks_cat.ctypes.data_as(_u32p),
+            ENTRY_HEADER_SIZE,
+            keep.view(np.uint8).ctypes.data_as(_u8p),
         )
-        if positions.size:
-            sel_t = gidx[positions]
-            ks_t = ks_cat[sel_t]
-            ent_w = columnar.tie_block_widths(block_id, ks_t)
-            for w in np.unique(ent_w):
-                bm = ent_w == w
-                kwords, inv_ts, inv_src = _gather_tie_arrays(
-                    runs, run_base, off_cat, ks_cat, sel_t[bm], int(w)
-                )
-                order, dup = columnar.tie_block_sort(
-                    block_id[bm], kwords, ks_t[bm], inv_ts, inv_src
-                )
-                gidx[positions[bm]] = sel_t[bm][order]
-                # The reorder moved entries across runs: refresh
-                # the run-id column at exactly those positions.
-                rids32[positions[bm]] = (
-                    np.searchsorted(
-                        run_base, gidx[positions[bm]], side="right"
-                    )
-                    - 1
-                ).astype(np.uint32)
-                keep[positions[bm]] = ~dup
+    if ties < 0:
+        raise _PipelineError("a tied entry's key lies outside its run")
 
     if plan.tomb_cat is not None:
         # (mode="clip": numpy buffers ``out`` under "raise".)
@@ -1151,7 +1187,6 @@ def _decode(
             drop[cand[cand_ts >= np.uint64(tombstone_drop_before)]] = False
         np.logical_not(drop, out=drop)
         keep &= drop
-    ties = int(positions.size)
     m = int(np.count_nonzero(keep))
     if m == 0:
         return None, ties
@@ -1552,5 +1587,6 @@ def _pipeline_merge_impl(
         rows_real=inputs.total_rows,
         runs_in=len(inputs.runs),
         tie_entries=tie_entries,
+        entries_out=int(entries),
     )
     return MergeResult(int(entries), int(data_size), wrote_bloom)

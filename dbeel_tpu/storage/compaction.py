@@ -75,7 +75,9 @@ MERGE_PATHS = (
 # merges it produced: kernel launches and keyspace partitions, the rows
 # the launches held (batch x padded runs x padded rows a run, of every
 # launch) against the entries that were really there, the runs merged,
-# and the entries the host tie fix-up had to re-order.
+# the entries the device order left tied for the host to re-order, and
+# the entries written (entries in less older versions and dropped
+# tombstones).
 PIPELINE_SHAPE = (
     "launches",
     "partitions",
@@ -83,6 +85,7 @@ PIPELINE_SHAPE = (
     "rows_real",
     "runs_in",
     "tie_entries",
+    "entries_out",
 )
 
 # The pipeline's block pool (ops/block_pool.py).  Counters: leases,

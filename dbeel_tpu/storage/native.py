@@ -247,6 +247,19 @@ def _load() -> Optional[ctypes.CDLL]:
         ctypes.POINTER(ctypes.c_uint32),
         u8p,
     ]
+    lib.dbeel_pipe_resolve_ties.restype = ctypes.c_int64
+    lib.dbeel_pipe_resolve_ties.argtypes = [
+        ctypes.c_uint64,
+        u8p,
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_uint32),
+        ctypes.POINTER(u8p),
+        ctypes.POINTER(ctypes.c_uint64),
+        ctypes.POINTER(ctypes.c_uint64),
+        ctypes.POINTER(ctypes.c_uint32),
+        ctypes.c_uint64,
+        u8p,
+    ]
     lib.dbeel_writer_put.restype = ctypes.c_int64
     lib.dbeel_writer_put.argtypes = [
         ctypes.c_void_p,

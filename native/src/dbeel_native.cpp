@@ -871,6 +871,201 @@ int dbeel_pipe_decode(const uint32_t* packed, uint64_t n_p,
 
 }  // extern "C"
 
+namespace {
+
+// One member of a tie block: its key's first sixteen bytes as two
+// big-endian words (zero-padded), where the key and its timestamp lie
+// in its run's buffer, and what the decode wrote for it.
+struct TieItem {
+  uint64_t word0, word1;
+  const uint8_t* key;
+  int64_t ts;
+  int64_t g;
+  uint32_t key_len;
+  uint32_t rid;
+};
+
+inline uint64_t tie_key_word(const uint8_t* key, uint32_t len) {
+  uint64_t w = 0;
+  if (len >= 8) {
+    std::memcpy(&w, key, 8);
+    return __builtin_bswap64(w);
+  }
+  for (uint32_t j = 0; j < len; j++) w |= (uint64_t)key[j] << (56 - 8 * j);
+  return w;
+}
+
+// memcmp order, shorter first.  Two keys of at most sixteen bytes
+// whose padded words agree are a prefix of one another, so their
+// lengths decide; longer ones are compared where they lie.
+inline int tie_key_cmp(const TieItem& a, const TieItem& b) {
+  if (a.word0 != b.word0) return a.word0 < b.word0 ? -1 : 1;
+  if (a.word1 != b.word1) return a.word1 < b.word1 ? -1 : 1;
+  if (a.key_len > 16 && b.key_len > 16) {
+    const uint32_t n = (a.key_len < b.key_len ? a.key_len : b.key_len) - 16;
+    const int c = std::memcmp(a.key + 16, b.key + 16, n);
+    if (c != 0) return c;
+  }
+  return a.key_len == b.key_len ? 0 : (a.key_len < b.key_len ? -1 : 1);
+}
+
+// item_greater's order, as a "less": (key asc, ts DESC, src DESC); an
+// impossible full tie keeps the device's order (position in the run).
+inline bool tie_less(const TieItem& a, const TieItem& b) {
+  const int c = tie_key_cmp(a, b);
+  if (c != 0) return c < 0;
+  if (a.ts != b.ts) return a.ts > b.ts;
+  if (a.rid != b.rid) return a.rid > b.rid;
+  return a.g < b.g;
+}
+
+// What dbeel_pipe_resolve_ties was handed, for its workers.
+struct TieColumns {
+  uint64_t n_p;
+  const uint8_t* tie;
+  int64_t* gidx;
+  uint32_t* rid;
+  const uint8_t* const* run_ptrs;
+  const uint64_t* run_sizes;
+  const uint64_t* off_cat;
+  const uint32_t* ks_cat;
+  uint64_t entry_header;
+  uint8_t* keep;
+};
+
+// Sorts and marks the tie blocks that START in [lo, hi) — one that
+// starts there is finished there, however far it runs.  Returns the
+// entries in those blocks, or -1 for a key outside its run's buffer.
+int64_t resolve_tie_blocks(const TieColumns& c, uint64_t lo,
+                           uint64_t hi) {
+  const uint64_t n_p = c.n_p;
+  const uint8_t* tie = c.tie;
+  // Records are fetched a little ahead of the block that needs them:
+  // a block's members lie in as many run buffers as it has members.
+  constexpr uint64_t kAhead = 64;
+  uint64_t ahead = 0;
+  std::vector<TieItem> items;
+  int64_t tied = 0;
+  uint64_t i = lo;
+  // The tail of a block that started before ``lo`` is not ours.
+  while (i < hi && tie[i]) i++;
+  while (i < hi) {
+    if (i + 1 >= n_p || !tie[i + 1]) {
+      i++;
+      continue;
+    }
+    uint64_t end = i + 2;
+    while (end < n_p && tie[end]) end++;
+    if (ahead < i) ahead = i;
+    for (const uint64_t to = end + kAhead < n_p ? end + kAhead : n_p;
+         ahead < to; ahead++) {
+      if (tie[ahead] || (ahead + 1 < n_p && tie[ahead + 1])) {
+        const uint8_t* rec =
+            c.run_ptrs[c.rid[ahead]] + c.off_cat[c.gidx[ahead]];
+        // Header and a 16-byte key: a second cache line half the time.
+        __builtin_prefetch(rec);
+        __builtin_prefetch(rec + c.entry_header + 15);
+      }
+    }
+    items.clear();
+    for (uint64_t j = i; j < end; j++) {
+      const int64_t g = c.gidx[j];
+      const uint64_t off = c.off_cat[g];
+      TieItem it;
+      it.key_len = c.ks_cat[g];
+      it.rid = c.rid[j];
+      it.g = g;
+      if (off + c.entry_header + it.key_len > c.run_sizes[it.rid])
+        return -1;
+      const uint8_t* rec = c.run_ptrs[it.rid] + off;
+      std::memcpy(&it.ts, rec + 8, 8);
+      it.key = rec + c.entry_header;
+      it.word0 = tie_key_word(it.key, it.key_len);
+      it.word1 = it.key_len > 8
+                     ? tie_key_word(it.key + 8, it.key_len - 8)
+                     : 0;
+      items.push_back(it);
+    }
+    std::sort(items.begin(), items.end(), tie_less);
+    for (uint64_t j = i; j < end; j++) {
+      const TieItem& it = items[j - i];
+      c.gidx[j] = it.g;
+      c.rid[j] = it.rid;
+      if (j > i && tie_key_cmp(items[j - i - 1], it) == 0) c.keep[j] = 0;
+    }
+    tied += (int64_t)(end - i);
+    i = end;
+  }
+  return tied;
+}
+
+// Workers of one dbeel_pipe_resolve_ties call, the caller among them,
+// and the fewest entries that are worth a worker: the pass waits on
+// memory (a record a tied entry, each in another place), which
+// several cores fetch several times as fast as one.
+constexpr uint64_t kTieWorkers = 4;
+constexpr uint64_t kTieWorkerMin = 1u << 15;
+
+}  // namespace
+
+extern "C" {
+
+// The host half of the device merge's order, after dbeel_pipe_decode:
+// the device sorted by a key that is narrower than the full key (a
+// shifted u32 or the 8-byte prefix), ties in (run, position) order,
+// so every maximal block of ``tie`` flags — versions of one key in
+// several runs, keys that share the prefix, shift collisions — is
+// sorted here by the reference's order (full key asc, newest
+// timestamp, newest source: item_greater), in place in ``gidx`` and
+// ``rid``.  Keys and timestamps are read where they lie, in the runs'
+// buffers (record = u32 key size, u32 value size, i64 timestamp, key,
+// value): no key matrix, no timestamp column.  ``keep_out`` gets 1
+// for every entry of the partition but a block's later entries of an
+// equal full key (older versions), which get 0.  Returns the number
+// of entries in tie blocks, or -1 where an index column points a key
+// outside its run's buffer.
+int64_t dbeel_pipe_resolve_ties(uint64_t n_p, const uint8_t* tie,
+                                int64_t* gidx, uint32_t* rid,
+                                const uint8_t* const* run_ptrs,
+                                const uint64_t* run_sizes,
+                                const uint64_t* off_cat,
+                                const uint32_t* ks_cat,
+                                uint64_t entry_header,
+                                uint8_t* keep_out) {
+  std::memset(keep_out, 1, n_p);
+  const TieColumns c{n_p,       tie,     gidx,   rid,          run_ptrs,
+                     run_sizes, off_cat, ks_cat, entry_header, keep_out};
+  uint64_t workers = n_p / kTieWorkerMin;
+  if (workers > kTieWorkers) workers = kTieWorkers;
+  if (workers < 2) return resolve_tie_blocks(c, 0, n_p);
+  // Blocks are disjoint and each belongs to the slice it starts in,
+  // so the slices share nothing they write.
+  std::vector<int64_t> tied(workers, 0);
+  std::vector<std::thread> threads;
+  for (uint64_t w = 0; w < workers; w++) {
+    const uint64_t lo = n_p * w / workers, hi = n_p * (w + 1) / workers;
+    if (w + 1 == workers) {
+      tied[w] = resolve_tie_blocks(c, lo, hi);
+      break;
+    }
+    try {
+      threads.emplace_back(
+          [&c, &tied, w, lo, hi] { tied[w] = resolve_tie_blocks(c, lo, hi); });
+    } catch (const std::system_error&) {
+      tied[w] = resolve_tie_blocks(c, lo, hi);  // no thread to be had
+    }
+  }
+  for (auto& t : threads) t.join();
+  int64_t total = 0;
+  for (const int64_t t : tied) {
+    if (t < 0) return -1;
+    total += t;
+  }
+  return total;
+}
+
+}  // extern "C"
+
 // ---------------------------------------------------------------------
 // Arena red-black memtable.  Role parity with the reference's
 // rbtree_arena crate (/root/reference/rbtree_arena/src/lib.rs:308-649):

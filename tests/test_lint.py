@@ -897,6 +897,7 @@ def test_pipeline_probes_nothing_and_its_library_exports_what_it_calls():
     # neither probes it for a symbol nor reads an environment knob; and
     # every symbol it calls — the list is taken from its source — is
     # one the built library exports.
+    import ctypes
     import re
 
     from dbeel_tpu.storage import native
@@ -906,9 +907,18 @@ def test_pipeline_probes_nothing_and_its_library_exports_what_it_calls():
     for banned in ("hasattr(lib", "os.environ", "getenv"):
         assert banned not in source, banned
     called = set(re.findall(r"lib\.(dbeel_\w+)", source))
-    assert {"dbeel_pipe_decode", "dbeel_writer_close2"} <= called
+    assert {
+        "dbeel_pipe_decode", "dbeel_pipe_resolve_ties", "dbeel_writer_close2",
+    } <= called
     lib = native.require()
     assert [name for name in sorted(called) if not hasattr(lib, name)] == []
+    # ISSUE 34: the decode's tie pass is bound as the rest of the
+    # pipeline's symbols are, with its argument types and no probe.
+    with open(os.path.join(REPO_ROOT, "dbeel_tpu/storage/native.py")) as f:
+        binding = f.read()
+    assert "lib.dbeel_pipe_resolve_ties.argtypes" in binding
+    assert 'hasattr(lib, "dbeel_pipe_resolve_ties")' not in binding
+    assert lib.dbeel_pipe_resolve_ties.restype is ctypes.c_int64
 
 
 def test_stats_schema_escape_comment(tmp_path):
