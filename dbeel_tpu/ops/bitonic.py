@@ -7,10 +7,40 @@ doesn't need a full sort anyway — its inputs are K *already-sorted* runs
 (SSTables are sorted by construction).  A bitonic merge network does the
 k-way merge in ``log2(K)`` batched pairwise rounds of ``log2(L)``
 elementwise compare-exchange stages: only static reshapes, compares and
-selects — tiny HLO, fast compile, HBM-bandwidth-bound execution.  This is
-the "batched bitonic merge expressed in jax.jit" the north star names
-(BASELINE.json), replacing the reference's per-entry heap loop
+selects.  This is the "batched bitonic merge
+expressed in jax.jit" the north star names (BASELINE.json), replacing the
+reference's per-entry heap loop
 (/root/reference/src/storage_engine/lsm_tree.rs:1038-1066).
+
+The layout rule.  The network does no arithmetic to speak of; a launch
+costs the bytes its temporaries move, and the chip pads every
+temporary's two minor dimensions to (8, 128) tiles.  So: a row's
+columns travel column axis first, row axis minor (a trailing column
+axis of 2, 3 or 9 was padded to 128), and a stage splits only major
+axes.  A level of 2^14 rows or more (``_VIEW_ROWS``) takes its columns
+apart, one array each, and runs its large strides in a view that keeps
+the low index bits (one tile's worth, ``_TILE_BITS``) and the batch in
+the two minor dimensions, then its small strides in the transposed view
+that keeps the high bits there: one transpose in, one between, one out,
+and no stage leaves a minor dimension under 128 elements or a
+second-minor under 8.  Shorter levels split the row axis as it lies,
+all columns in one array (one select a comparator whatever the width:
+the sort kernel and small merges compile as fast as they did; their
+strides under 128 are padded).  Two stages share one split and one
+stack (``_STAGES_PER_PASS``).  The rule reads static shapes only, and
+the sequence of compare-exchanges — same pairs, same direction, swap
+iff lo > hi — is the textbook one whatever view a stage runs in, so
+the output is too, ties among equal rows included
+(tests/test_bitonic_network.py).  The compiler's own count for one
+launch of the one-word kernel on a v5e (tests/test_tpu_compile.py holds
+it): 19.1 GB accessed and 0.54 GB of temporaries at (4, 64, 2^14),
+10.1 GB and 0.27 GB at (4, 8, 2^17); the row-major network this
+replaced counted 166.7 and 106.0 GB, 3.2 GB of temporaries, and ran at
+those bytes over 819 GB/s (PERF.md §3, §6).  The ideal is 105 stages x
+64 MB = 6.7 GB: a kernel that keeps a block's stages in VMEM is the
+next step.  Write a stage so that XLA's CPU backend materialises it
+(split, select, stack): ``where(swap, flip(x), x)`` is one pass on the
+chip and exponential recomputation on the CPU the tests run on.
 
 Row format is the 9-column uint32 entry stack of parallel/dist_merge.py:
   cols 0-3 k0..k3 (16B big-endian key prefix), 4 key_len,
@@ -38,55 +68,145 @@ NUM_EQ_COLS = 5  # key identity = prefix words + key_len
 SENTINEL = np.uint32(0xFFFFFFFF)
 
 
-def _lex_gt(a: jnp.ndarray, b: jnp.ndarray, ncmp: int = NUM_KEY_COLS):
+# The chip lays an array's two minor dimensions out in (8, 128) tiles.
+_LANES = 128
+_LANE_BITS = _LANES.bit_length() - 1
+# A level this long fills 128 lanes in both tiled views below.
+_VIEW_ROWS = _LANES * _LANES
+# log2 of one tile's elements: the index bits a tiled view keeps minor.
+_TILE_BITS = 10
+# Compare-exchange stages between two materialisations of the columns.
+_STAGES_PER_PASS = 2
+
+
+def _lex_gt(a, b, ncmp: int):
     """a > b lexicographically over the first ``ncmp`` columns.
-    a, b: (..., C)."""
-    gt = jnp.zeros(a.shape[:-1], dtype=bool)
-    eq = jnp.ones(a.shape[:-1], dtype=bool)
-    for c in range(ncmp):
-        ac, bc = a[..., c], b[..., c]
-        gt = gt | (eq & (ac > bc))
-        eq = eq & (ac == bc)
+    a, b: sequences of columns of one shape."""
+    gt = a[ncmp - 1] > b[ncmp - 1]
+    for c in range(ncmp - 2, -1, -1):
+        gt = (a[c] > b[c]) | ((a[c] == b[c]) & gt)
     return gt
 
 
-def _bitonic_to_sorted(x: jnp.ndarray, ncmp: int) -> jnp.ndarray:
-    """(B, L, C) rows that are bitonic along axis 1 → ascending rows.
-    Classic bitonic merge: stages with strides L/2, L/4, …, 1, each a
-    static reshape + compare-exchange."""
-    b, l, c = x.shape
-    s = l // 2
-    while s >= 1:
-        y = x.reshape(b, l // (2 * s), 2, s, c)
-        lo, hi = y[:, :, 0], y[:, :, 1]
-        swap = _lex_gt(lo, hi, ncmp)[..., None]
-        nlo = jnp.where(swap, hi, lo)
-        nhi = jnp.where(swap, lo, hi)
-        x = jnp.stack([nlo, nhi], axis=2).reshape(b, l, c)
-        s //= 2
-    return x
+def _columns(groups):
+    return [g[c] for g in groups for c in range(g.shape[0])]
 
 
-def _merge_level(x: jnp.ndarray, ncmp: int = NUM_KEY_COLS) -> jnp.ndarray:
-    """(K, P, C) sorted runs → (K/2, 2P, C) sorted runs: concat each even
-    run with its odd neighbour reversed (ascending+descending = bitonic),
-    then merge — all K/2 pairs in one batched op."""
-    a = x[0::2]
-    b_rev = x[1::2][:, ::-1]
-    return _bitonic_to_sorted(
-        jnp.concatenate([a, b_rev], axis=1), ncmp
+def _compare_exchange(lo, hi, ncmp: int):
+    """One comparator over rows held as groups of columns (each group
+    one array, column axis first): swap iff lo > hi."""
+    swap = _lex_gt(_columns(lo), _columns(hi), ncmp)
+    return (
+        tuple(jnp.where(swap, h, l) for l, h in zip(lo, hi)),
+        tuple(jnp.where(swap, l, h) for l, h in zip(lo, hi)),
     )
 
 
+def _stages(groups, count: int, ncmp: int):
+    """Groups shaped (columns, batch, rows, *tile): the ``count``
+    bitonic-merge stages of strides rows/2, rows/4, … along the row
+    axis, in that order.  Only the row axis is ever split, so whatever
+    ``tile`` is stays the minor dimensions of every temporary.
+    ``_STAGES_PER_PASS`` stages share one split and one stack: the same
+    comparators in the same order, fewer passes over the columns."""
+    batch, rows = groups[0].shape[1:3]
+    tile = groups[0].shape[3:]
+    done = 0
+    while done < count:
+        r = min(_STAGES_PER_PASS, count - done)
+        fan = 1 << r
+        split = (batch << done, fan, (rows >> done) // fan) + tile
+        parts = [
+            tuple(g.reshape(g.shape[:1] + split)[:, :, i] for g in groups)
+            for i in range(fan)
+        ]
+        d = fan // 2
+        while d:
+            for i in range(fan):
+                if not i & d:
+                    parts[i], parts[i + d] = _compare_exchange(
+                        parts[i], parts[i + d], ncmp
+                    )
+            d //= 2
+        groups = tuple(
+            jnp.stack(part, axis=2).reshape(g.shape)
+            for g, part in zip(groups, zip(*parts))
+        )
+        done += r
+    return groups
+
+
+def _bitonic_to_sorted(groups, ncmp: int):
+    """Groups (columns, B, L), each row bitonic → ascending rows.
+    Classic bitonic merge: compare-exchange stages of strides L/2, L/4,
+    …, 1.
+
+    The layout rule (module docstring) reads only static shapes: a
+    level shorter than ``_VIEW_ROWS`` splits the row axis as it lies,
+    its columns in whatever groups they came in; a longer one takes
+    its columns apart (they stay apart) and runs its large strides in
+    a view whose tiles hold the low index bits, and its small strides
+    in the transposed view whose tiles hold the high ones — one
+    transpose in, one between, one out."""
+    _, b, l = groups[0].shape
+    n = l.bit_length() - 1
+    if l < _VIEW_ROWS:
+        return _stages(groups, n, ncmp)
+    m = min(_TILE_BITS, n - _LANE_BITS)
+    low, high = 1 << m, l >> m
+    # Index bits >= m major, (batch x the rest of a tile, 128) minor.
+    cols = tuple(
+        c.reshape(b, high, low // _LANES, _LANES)
+        .transpose(1, 0, 2, 3)
+        .reshape(1, 1, high, b * low // _LANES, _LANES)
+        for c in _columns(groups)
+    )
+    cols = _stages(cols, n - m, ncmp)
+    # Index bits < m major, (batch x high / 128, 128) minor.
+    cols = tuple(
+        c.reshape(high, b, low)
+        .transpose(2, 1, 0)
+        .reshape(1, 1, low, b * high // _LANES, _LANES)
+        for c in cols
+    )
+    cols = _stages(cols, m, ncmp)
+    return tuple(
+        c.reshape(low, b, high).transpose(1, 2, 0).reshape(1, b, l)
+        for c in cols
+    )
+
+
+def _merge_level(groups, ncmp: int):
+    """Groups (columns, K, P) of sorted runs → (columns, K/2, 2P):
+    concat each even run with its odd neighbour reversed
+    (ascending+descending = bitonic), then merge — all K/2 pairs in one
+    batched op."""
+    return _bitonic_to_sorted(
+        tuple(
+            jnp.concatenate(
+                [g[:, 0::2], jnp.flip(g[:, 1::2], axis=2)], axis=2
+            )
+            for g in groups
+        ),
+        ncmp,
+    )
+
+
+def _merge_runs(cols, ncmp: int):
+    """Columns (K, P) of sorted runs → columns (K*P,), sorted."""
+    groups = (jnp.stack(cols),)
+    while groups[0].shape[1] > 1:
+        groups = _merge_level(groups, ncmp)
+    return tuple(c[0] for c in _columns(groups))
+
+
 def _merged_with_same(stacks: jnp.ndarray):
-    x = stacks
-    while x.shape[0] > 1:
-        x = _merge_level(x, NUM_KEY_COLS)
-    out = x[0]
-    eq = jnp.ones(out.shape[0] - 1, dtype=bool)
+    out = _merge_runs(
+        tuple(stacks[:, :, c] for c in range(NUM_COLS)), NUM_KEY_COLS
+    )
+    eq = out[4][1:] != SENTINEL
     for c in range(NUM_EQ_COLS):
-        eq = eq & (out[1:, c] == out[:-1, c])
-    eq = eq & (out[1:, 4] != SENTINEL)
+        eq = eq & (out[c][1:] == out[c][:-1])
     same = jnp.concatenate([jnp.zeros((1,), bool), eq])
     return out, same
 
@@ -97,7 +217,8 @@ def merge_runs_kernel(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(K, P, NUM_COLS) sorted (sentinel-padded) runs, K and P powers of
     two → (K*P, NUM_COLS) globally sorted stack + same-key flags."""
-    return _merged_with_same(stacks)
+    out, same = _merged_with_same(stacks)
+    return jnp.stack(out, axis=1), same
 
 
 @jax.jit
@@ -107,7 +228,7 @@ def merge_runs_perm_kernel(
     """Like merge_runs_kernel but returns only (sorted entry indices,
     same flags) — a ~9x smaller device→host transfer."""
     out, same = _merged_with_same(stacks)
-    return out[:, 8], same
+    return out[8], same
 
 
 def sort_stack_kernel(stack: jnp.ndarray):
@@ -132,20 +253,22 @@ def sort_stack_kernel(stack: jnp.ndarray):
 # ----------------------------------------------------------------------
 
 
+def _merged_order(keys, counts: jnp.ndarray):
+    """Key columns (K, P) of sorted runs, ``counts`` valid rows a run →
+    (K*P,) packed positions (run * P + row) in merged order; rows past a
+    run's count carry SENTINEL and sort last."""
+    k, p = keys[0].shape
+    row = jnp.arange(p, dtype=jnp.uint32)[None, :]
+    iota = jnp.arange(k, dtype=jnp.uint32)[:, None] * jnp.uint32(p) + row
+    idx = jnp.where(row < counts[:, None], iota, SENTINEL)
+    return _merge_runs((*keys, idx), len(keys) + 1)[-1]
+
+
 def _prefix_merge_body(
     prefixes: jnp.ndarray, counts: jnp.ndarray, out_rows: int
 ):
-    k, p, _ = prefixes.shape
-    iota = (
-        jnp.arange(k, dtype=jnp.uint32)[:, None] * jnp.uint32(p)
-        + jnp.arange(p, dtype=jnp.uint32)[None, :]
-    )
-    valid = jnp.arange(p, dtype=jnp.uint32)[None, :] < counts[:, None]
-    idx = jnp.where(valid, iota, jnp.uint32(0xFFFFFFFF))
-    x = jnp.concatenate([prefixes, idx[:, :, None]], axis=2)
-    while x.shape[0] > 1:
-        x = _merge_level(x, ncmp=3)
-    return x[0, :out_rows, 2]
+    order = _merged_order((prefixes[:, :, 0], prefixes[:, :, 1]), counts)
+    return order[:out_rows]
 
 
 @functools.partial(jax.jit, static_argnames=("out_rows",))
@@ -201,33 +324,17 @@ def _pack_rids(idx_sorted: jnp.ndarray, logp: int, pack_bits: int):
 def _prefix32_packed_body(
     vals: jnp.ndarray, counts: jnp.ndarray, pack_bits: int
 ):
-    k, p = vals.shape
-    iota = (
-        jnp.arange(k, dtype=jnp.uint32)[:, None] * jnp.uint32(p)
-        + jnp.arange(p, dtype=jnp.uint32)[None, :]
-    )
-    valid = jnp.arange(p, dtype=jnp.uint32)[None, :] < counts[:, None]
-    idx = jnp.where(valid, iota, SENTINEL)
-    x = jnp.stack([vals, idx], axis=2)
-    while x.shape[0] > 1:
-        x = _merge_level(x, ncmp=2)
-    return _pack_rids(x[0, :, 1], p.bit_length() - 1, pack_bits)
+    p = vals.shape[1]
+    order = _merged_order((vals,), counts)
+    return _pack_rids(order, p.bit_length() - 1, pack_bits)
 
 
 def _prefix64_packed_body(
     prefixes: jnp.ndarray, counts: jnp.ndarray, pack_bits: int
 ):
-    k, p, _ = prefixes.shape
-    iota = (
-        jnp.arange(k, dtype=jnp.uint32)[:, None] * jnp.uint32(p)
-        + jnp.arange(p, dtype=jnp.uint32)[None, :]
-    )
-    valid = jnp.arange(p, dtype=jnp.uint32)[None, :] < counts[:, None]
-    idx = jnp.where(valid, iota, SENTINEL)
-    x = jnp.concatenate([prefixes, idx[:, :, None]], axis=2)
-    while x.shape[0] > 1:
-        x = _merge_level(x, ncmp=3)
-    return _pack_rids(x[0, :, 2], p.bit_length() - 1, pack_bits)
+    p = prefixes.shape[1]
+    order = _merged_order((prefixes[:, :, 0], prefixes[:, :, 1]), counts)
+    return _pack_rids(order, p.bit_length() - 1, pack_bits)
 
 
 @functools.partial(jax.jit, static_argnames=("pack_bits",))

@@ -93,6 +93,35 @@ def test_pipeline_rebased_u32_kernel(one_chip, k, p):
     _fits(compiled, in_flight=2)
 
 
+@pytest.mark.parametrize(
+    "k,p,ceiling_gb",
+    # ~25 % above what the compiler counts for the kernel as committed
+    # (PERF.md §3); the row-major network of PR 27-34 read 166.7 and
+    # 106.0 GB here, 3.2 GB of temporaries each.
+    [(64, 1 << 14, 24.0), (8, 1 << 17, 12.5)],
+    ids=["wide-64", "major-10m"],
+)
+def test_cells_launch_moves_no_more_than_its_layout_needs(
+    one_chip, k, p, ceiling_gb
+):
+    # The one-word kernel at the two shapes the benchmark's cells launch.
+    # A stage that leaves a minor dimension under 128 elements is padded
+    # to the (8, 128) tiling and shows here as bytes: the compiler's own
+    # count of one launch, no chip needed.
+    from dbeel_tpu.ops import bitonic, pipeline
+
+    j = pipeline._LAUNCH_BATCH
+    assert k * p == pipeline._MAX_KP and p == pipeline.max_partition_rows(k)
+    compiled = bitonic.merge_runs_prefix32_packed_batch_kernel.lower(
+        _spec((j, k, p), one_chip),
+        _spec((j, k), one_chip),
+        pack_bits=bitonic.rid_pack_bits(k),
+    ).compile()
+    _fits(compiled, in_flight=2)
+    assert compiled.cost_analysis()["bytes accessed"] < ceiling_gb * 1e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
 def test_wide_merge_never_chooses_the_refused_shape(one_chip):
     # The negative case behind _MAX_KP: the chip's compiler refuses the
     # exact-prefix kernel at (4, 64, 2^17, 2) ("Program hbm requirement
