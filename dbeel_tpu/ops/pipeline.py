@@ -18,12 +18,16 @@ What each box is, which thread runs it, and what crosses each arrow:
 * **plan** (``_make_plan``; the calling thread).  Keyspace partitions
   cut at sampled prefixes so that every run's slice of a partition
   fits the kernel's rows — equal prefixes, hence equal keys, hence
-  every dedup decision never cross a cut — the launch width and, on a
-  mesh, the shardings of the launch-batch axis (partitions are
-  disjoint sorted ranges: pure data parallelism, no exchange), the
-  tombstone column, the output's paths.  Out: ``_Plan``, immutable, or
-  None where one equal-prefix group is larger than the kernel's rows
-  (the caller of the pipeline then takes the single-shot path).
+  every dedup decision never cross a cut; a run need not spread over
+  the keyspace (a table loaded in key order lies in a sliver of it),
+  so wherever a slice still overflows, the partition is cut further,
+  evenly, until all fit — the launch width and, on a mesh, the
+  shardings of the launch-batch axis (partitions are disjoint sorted
+  ranges: pure data parallelism, no exchange), the tombstone column
+  and its count (the column is dropped again where the merge read no
+  tombstone), the output's paths.  Out: ``_Plan``, immutable, or None
+  where one equal-prefix group is larger than the kernel's rows (the
+  caller of the pipeline then takes the single-shot path).
 * **launcher** (``_Launches._upload``; the upload thread).  Per
   partition: each run's prefixes rebased to the partition's minimum
   and right-shifted until the span fits 32 bits — an order-preserving
@@ -49,8 +53,15 @@ What each box is, which thread runs it, and what crosses each arrow:
   timestamp is read from the record, never inferred from the run —
   and marks a key's older versions.  It declines no block, so nothing
   is left to numpy (``_tie_fixup_numpy``, the lexsort it replaced, is
-  what the tests hold it to).  Tombstones are dropped where the merge
-  drops them, the survivors compressed.  Out: a ``_Job`` — run,
+  what the tests hold it to).  Where the merge drops tombstones and
+  read any, a third C pass (``dbeel_pipe_drop_tombstones``, span
+  ``tomb_gc``) decides every key's newest version that is a delete:
+  dropped, unless the gc-grace cutoff still holds it — its timestamp,
+  read where the record lies, is at or above
+  ``tombstone_drop_before`` (``compaction.drop_tombstones_mask``'s
+  rule; in a collection used as a queue that is asked of half the
+  entries).  The survivors are compressed.  Spans: ``decode`` and,
+  nested in it, ``tie_fixup`` and ``tomb_gc``.  Out: a ``_Job`` — run,
   offset, key size and full size per surviving entry, in the partition
   set it was given.
 * **output** (``_Output``; writer, bloom and close threads).  The
@@ -432,35 +443,39 @@ def _choose_partitions(runs: List[_Run], launch_batch: int = None):
         ]
 
     bounds = bounds_for(splitters)
-    # Split any partition whose largest run-slice overflows p2.  The
-    # split point is a median prefix inside the overflowing slice; if
+    # Split every partition in which a run's slice overflows p2, until
+    # none does: runs need not spread evenly over the keyspace (a table
+    # loaded in key order holds all its entries in a sliver of it), so
+    # the sampled cuts are only where the splitting starts.  A slice
+    # that needs q kernels' rows is cut at q - 1 evenly spaced prefixes
+    # inside it (its median where it overflows by less than double),
+    # the slice being that of the first run that overflows there; if
     # no strictly-interior cut exists the range is one equal-prefix
     # group — unsplittable at this kernel size.
-    for _ in range(64):
-        overflow = None
-        for r, b in zip(runs, bounds):
-            cnt = np.diff(b)
-            too_big = np.flatnonzero(cnt > p2)
-            if too_big.size:
-                overflow = (r, b, int(too_big[0]))
-                break
-        if overflow is None:
+    while True:
+        counts = np.stack([np.diff(b) for b in bounds])
+        over = counts > p2
+        crowded = np.flatnonzero(over.any(axis=0))
+        if not crowded.size:
             break
-        r, b, p = overflow
-        lo, hi = int(b[p]), int(b[p + 1])
-        uniq = np.unique(r.prefix64[lo:hi])
-        if uniq.size < 2:
-            return None  # one equal-prefix group > kernel rows
-        # side="right" cuts put entries <= splitter left, so any value
-        # strictly below the slice maximum splits it into two nonempty
-        # halves.
-        mid = uniq[(uniq.size - 1) // 2]
+        cuts = set()
+        for p in crowded.tolist():
+            ri = int(np.argmax(over[:, p]))
+            b = bounds[ri]
+            uniq = np.unique(runs[ri].prefix64[int(b[p]) : int(b[p + 1])])
+            if uniq.size < 2:
+                return None  # one equal-prefix group > kernel rows
+            # side="right" cuts put entries <= splitter left, so any
+            # value strictly below the slice maximum leaves both sides
+            # nonempty.
+            q = -(-int(counts[ri, p]) // p2)
+            cuts.update(
+                uniq[np.arange(1, q) * (uniq.size - 1) // q].tolist()
+            )
         splitters = np.array(
-            sorted(set(splitters.tolist()) | {int(mid)}), dtype=np.uint64
+            sorted(set(splitters.tolist()) | cuts), dtype=np.uint64
         )
         bounds = bounds_for(splitters)
-    else:
-        return None
     return splitters, bounds, p2
 
 
@@ -479,7 +494,10 @@ class _Plan:
     p2: int  # kernel rows per (run, partition)
     k2: int  # kernel runs: pow2 of the run count
     pack_bits: int  # bits a run-id in the kernel's result
-    tomb_cat: Optional[np.ndarray]  # None where tombstones are kept
+    # Which entries are tombstones; None where the merge keeps them or
+    # reads none, so that no partition has tombstone work.
+    tomb_cat: Optional[np.ndarray]
+    tombstones_in: int  # how many, where the merge may drop them
     max_np: int  # most entries of one partition over all runs
     dir_path: str
     output_index: int
@@ -526,6 +544,7 @@ def _make_plan(
     k2 = _pow2(max(1, len(inputs.runs)))
 
     tomb_cat = None
+    tombstones_in = 0
     if not keep_tombstones:
         tomb_cat = mem.array(inputs.total_rows, np.bool_)
         # In steps whose temporaries stay small enough for the heap.
@@ -537,6 +556,9 @@ def _make_plan(
                 inputs.ks_cat[lo:hi] + hdr,
                 out=tomb_cat[lo:hi],
             )
+        tombstones_in = int(np.count_nonzero(tomb_cat))
+        if not tombstones_in:
+            tomb_cat = None
     return _Plan(
         launch_j=launch_j,
         shard32=shard32,
@@ -548,6 +570,7 @@ def _make_plan(
         k2=k2,
         pack_bits=rid_pack_bits(k2),
         tomb_cat=tomb_cat,
+        tombstones_in=tombstones_in,
         max_np=(
             int(sum(np.diff(b) for b in bounds).max()) if n_parts else 0
         ),
@@ -626,7 +649,7 @@ class _PartSet:
     yet to consume the partition's raw pointers."""
 
     __slots__ = (
-        "gidx", "rids32", "tieb", "keep", "mask", "sel", "src_run",
+        "gidx", "rids32", "tieb", "keep", "sel", "src_run",
         "src_off", "ks_sel", "fs_sel", "holders",
     )
 
@@ -635,7 +658,6 @@ class _PartSet:
         self.rids32 = mem.array(rows, np.uint32)  # and their runs
         self.tieb = mem.array(rows, np.uint8)  # device-key tie flags
         self.keep = mem.array(rows, np.bool_)
-        self.mask = mem.array(rows, np.bool_)  # scratch: tombstones
         self.sel = mem.array(rows, np.int64)  # gidx[keep]
         self.src_run = mem.array(rows, np.uint32)  # rids32[keep]
         self.src_off = mem.array(rows, np.uint64)
@@ -676,10 +698,11 @@ def pipeline_merge(
     outer span ``merge``; the other threads' (``read_run``,
     ``operand``, ``slot_wait``, ``h2d_dispatch``, ``d2h``,
     ``gather_write``, ``fsync``, ``bloom_hash``, ``bloom_set``) and the
-    nested ``stage_prefixes`` (in ``read_stage``) and ``tie_fixup`` (in
-    ``decode``) overlap them and say what the caller was waiting on.
-    The merge's shape (launches, partitions, rows launched and real,
-    runs, tie entries, entries written) is counted under
+    nested ``stage_prefixes`` (in ``read_stage``), ``tie_fixup`` and
+    ``tomb_gc`` (in ``decode``) overlap them and say what the caller
+    was waiting on.  The merge's shape (launches, partitions, rows
+    launched and real, runs, tie entries, entries written, tombstones
+    read and tombstones the grace kept) is counted under
     ``get_stats.compaction.shape``."""
     shape: dict = {}
     mem = _POOL.leases()
@@ -1063,31 +1086,6 @@ def _tie_fixup_numpy(inputs: _Inputs, gidx, rids32, tieb, keep) -> int:
     return int(positions.size)
 
 
-def _gather_timestamps(runs, run_base, off_cat, sel):
-    """Per-record int64-ns timestamps (as u64 bit views) for the
-    GLOBAL indices ``sel`` — gathered lazily, because the pipeline
-    never materializes a full timestamp column; only gc_grace needs
-    them, and only for drop-candidate tombstones (a small fraction)."""
-    ri = (
-        np.searchsorted(run_base, sel, side="right") - 1
-    ).astype(np.int64)
-    off = off_cat[sel]
-    ts = np.zeros(sel.size, dtype=np.uint64)
-    w8 = np.uint64(1) << (
-        np.arange(8, dtype=np.uint64) * np.uint64(8)
-    )
-    for r in np.unique(ri):
-        msk = ri == r
-        tpos = (off[msk] + np.uint64(8))[:, None] + np.arange(
-            8, dtype=np.uint64
-        )
-        ts[msk] = (
-            runs[r].data[tpos.astype(np.int64)].astype(np.uint64)
-            @ w8
-        )
-    return ts
-
-
 class _Job(NamedTuple):
     """What the decode hands the output: partition ``p``'s ``m``
     surviving entries in output order — run, offset in the run, key
@@ -1115,8 +1113,9 @@ def _decode(
 ):
     """One partition from the downloader's packed run-ids to the
     writer's job, in ``pset``.  Returns (job, entries the device
-    order left tied); the job is None where no entry survives.  Span
-    ``tie_fixup``, nested in the caller's ``decode``."""
+    order left tied, tombstones the grace kept); the job is None
+    where no entry survives.  Spans ``tie_fixup`` and ``tomb_gc``,
+    nested in the caller's ``decode``."""
     runs, run_base = inputs.runs, inputs.run_base
     off_cat, ks_cat = inputs.off_cat, inputs.ks_cat
     n_p = int(part.counts.sum())
@@ -1171,25 +1170,33 @@ def _decode(
     if ties < 0:
         raise _PipelineError("a tied entry's key lies outside its run")
 
+    # Tombstones: a key's newest version that is a delete is dropped,
+    # unless the gc-grace cutoff still holds it (its timestamp, read
+    # where the record lies, is at or above the cutoff; a cutoff of
+    # None or 0 holds none: compaction.drop_tombstones_mask's rule).
+    # One C pass, GIL released.
+    tomb_kept = 0
     if plan.tomb_cat is not None:
-        # (mode="clip": numpy buffers ``out`` under "raise".)
-        drop = np.take(
-            plan.tomb_cat, gidx, out=pset.mask[:n_p], mode="clip"
-        )
-        if tombstone_drop_before and drop.any():
-            # gc_grace: tombstones younger than the cutoff survive
-            # the drop.  Timestamps are gathered only for the drop
-            # candidates.
-            cand = np.flatnonzero(drop)
-            cand_ts = _gather_timestamps(
-                runs, run_base, off_cat, gidx[cand]
+        with span("tomb_gc", part=part.p):
+            tomb_kept = lib.dbeel_pipe_drop_tombstones(
+                n_p,
+                gidx.ctypes.data_as(_i64p),
+                rids32.ctypes.data_as(_u32p),
+                inputs.run_ptrs,
+                inputs.run_sizes.ctypes.data_as(_u64p),
+                off_cat.ctypes.data_as(_u64p),
+                plan.tomb_cat.view(np.uint8).ctypes.data_as(_u8p),
+                0 if tombstone_drop_before else 1,
+                max(0, tombstone_drop_before or 0),
+                keep.view(np.uint8).ctypes.data_as(_u8p),
             )
-            drop[cand[cand_ts >= np.uint64(tombstone_drop_before)]] = False
-        np.logical_not(drop, out=drop)
-        keep &= drop
+        if tomb_kept < 0:
+            raise _PipelineError(
+                "a tombstone's header lies outside its run"
+            )
     m = int(np.count_nonzero(keep))
     if m == 0:
-        return None, ties
+        return None, ties, tomb_kept
     if m != n_p:
         sel = np.compress(keep, gidx, out=pset.sel[:m])
         src_run = np.compress(keep, rids32, out=pset.src_run[:m])
@@ -1205,6 +1212,7 @@ def _decode(
             ks_sel, fs_sel,
         ),
         ties,
+        tomb_kept,
     )
 
 
@@ -1543,7 +1551,7 @@ def _pipeline_merge_impl(
         return None
     launches.start()
     output.start()
-    tie_entries = 0
+    tie_entries = tombstones_kept = 0
     try:
         while True:
             at.to("wait_device")
@@ -1558,11 +1566,12 @@ def _pipeline_merge_impl(
                 at.to("wait_writer", part=part.p)
             pset = output.take_set()
             at.to("decode", part=part.p)
-            job, ties = _decode(
+            job, ties, tomb_kept = _decode(
                 lib, inputs, plan, part, packed, pset, span,
                 tombstone_drop_before,
             )
             tie_entries += ties
+            tombstones_kept += tomb_kept
             if job is None:
                 output.give_set(pset)
                 continue
@@ -1588,5 +1597,7 @@ def _pipeline_merge_impl(
         runs_in=len(inputs.runs),
         tie_entries=tie_entries,
         entries_out=int(entries),
+        tombstones_in=plan.tombstones_in,
+        tombstones_kept=tombstones_kept,
     )
     return MergeResult(int(entries), int(data_size), wrote_bloom)

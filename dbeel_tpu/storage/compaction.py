@@ -75,9 +75,11 @@ MERGE_PATHS = (
 # merges it produced: kernel launches and keyspace partitions, the rows
 # the launches held (batch x padded runs x padded rows a run, of every
 # launch) against the entries that were really there, the runs merged,
-# the entries the device order left tied for the host to re-order, and
-# the entries written (entries in less older versions and dropped
-# tombstones).
+# the entries the device order left tied for the host to re-order, the
+# entries written (entries in less older versions and dropped
+# tombstones), the tombstones among the entries read by merges that
+# may drop them (a merge that keeps them all does not look), and the
+# tombstones such merges wrote because the gc-grace cutoff held them.
 PIPELINE_SHAPE = (
     "launches",
     "partitions",
@@ -86,6 +88,8 @@ PIPELINE_SHAPE = (
     "runs_in",
     "tie_entries",
     "entries_out",
+    "tombstones_in",
+    "tombstones_kept",
 )
 
 # The pipeline's block pool (ops/block_pool.py).  Counters: leases,

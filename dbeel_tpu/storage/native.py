@@ -260,6 +260,19 @@ def _load() -> Optional[ctypes.CDLL]:
         ctypes.c_uint64,
         u8p,
     ]
+    lib.dbeel_pipe_drop_tombstones.restype = ctypes.c_int64
+    lib.dbeel_pipe_drop_tombstones.argtypes = [
+        ctypes.c_uint64,
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_uint32),
+        ctypes.POINTER(u8p),
+        ctypes.POINTER(ctypes.c_uint64),
+        ctypes.POINTER(ctypes.c_uint64),
+        u8p,
+        ctypes.c_int,
+        ctypes.c_uint64,
+        u8p,
+    ]
     lib.dbeel_writer_put.restype = ctypes.c_int64
     lib.dbeel_writer_put.argtypes = [
         ctypes.c_void_p,

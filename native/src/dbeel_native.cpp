@@ -999,12 +999,44 @@ int64_t resolve_tie_blocks(const TieColumns& c, uint64_t lo,
   return tied;
 }
 
-// Workers of one dbeel_pipe_resolve_ties call, the caller among them,
-// and the fewest entries that are worth a worker: the pass waits on
-// memory (a record a tied entry, each in another place), which
-// several cores fetch several times as fast as one.
-constexpr uint64_t kTieWorkers = 4;
-constexpr uint64_t kTieWorkerMin = 1u << 15;
+// Workers of one pass over a decoded partition (the tie pass, the
+// tombstone pass), the caller among them, and the fewest entries that
+// are worth a worker: such a pass waits on memory (a record an entry,
+// each in another place), which several cores fetch several times as
+// fast as one.
+constexpr uint64_t kPartWorkers = 4;
+constexpr uint64_t kPartWorkerMin = 1u << 15;
+
+// ``pass(lo, hi)`` over [0, n) in up to kPartWorkers slices at once.
+// Returns the sum of what the slices return, or -1 if one returned a
+// negative.  The slices must share nothing they write.
+template <typename Pass>
+int64_t over_slices(uint64_t n, const Pass& pass) {
+  uint64_t workers = n / kPartWorkerMin;
+  if (workers > kPartWorkers) workers = kPartWorkers;
+  if (workers < 2) return pass(0, n);
+  std::vector<int64_t> got(workers, 0);
+  std::vector<std::thread> threads;
+  for (uint64_t w = 0; w < workers; w++) {
+    const uint64_t lo = n * w / workers, hi = n * (w + 1) / workers;
+    if (w + 1 == workers) {
+      got[w] = pass(lo, hi);
+      break;
+    }
+    try {
+      threads.emplace_back([&pass, &got, w, lo, hi] { got[w] = pass(lo, hi); });
+    } catch (const std::system_error&) {
+      got[w] = pass(lo, hi);  // no thread to be had
+    }
+  }
+  for (auto& t : threads) t.join();
+  int64_t total = 0;
+  for (const int64_t g : got) {
+    if (g < 0) return -1;
+    total += g;
+  }
+  return total;
+}
 
 }  // namespace
 
@@ -1035,33 +1067,91 @@ int64_t dbeel_pipe_resolve_ties(uint64_t n_p, const uint8_t* tie,
   std::memset(keep_out, 1, n_p);
   const TieColumns c{n_p,       tie,     gidx,   rid,          run_ptrs,
                      run_sizes, off_cat, ks_cat, entry_header, keep_out};
-  uint64_t workers = n_p / kTieWorkerMin;
-  if (workers > kTieWorkers) workers = kTieWorkers;
-  if (workers < 2) return resolve_tie_blocks(c, 0, n_p);
   // Blocks are disjoint and each belongs to the slice it starts in,
   // so the slices share nothing they write.
-  std::vector<int64_t> tied(workers, 0);
-  std::vector<std::thread> threads;
-  for (uint64_t w = 0; w < workers; w++) {
-    const uint64_t lo = n_p * w / workers, hi = n_p * (w + 1) / workers;
-    if (w + 1 == workers) {
-      tied[w] = resolve_tie_blocks(c, lo, hi);
-      break;
+  return over_slices(n_p, [&c](uint64_t lo, uint64_t hi) {
+    return resolve_tie_blocks(c, lo, hi);
+  });
+}
+
+}  // extern "C"
+
+namespace {
+
+// What dbeel_pipe_drop_tombstones was handed, for its workers.
+struct TombColumns {
+  const int64_t* gidx;
+  const uint32_t* rid;
+  const uint8_t* const* run_ptrs;
+  const uint64_t* run_sizes;
+  const uint64_t* off_cat;
+  const uint8_t* tomb_cat;
+  int drop_all;
+  uint64_t cutoff;
+  uint8_t* keep;
+};
+
+// Entries [lo, hi) of the partition.  Returns the tombstones of the
+// slice that stay, or -1 for a record header outside its run's buffer.
+int64_t drop_tombstones_slice(const TombColumns& c, uint64_t lo,
+                              uint64_t hi) {
+  // A tombstone's header lies in one of as many run buffers as the
+  // merge has runs: fetched a little ahead, as the tie pass does.
+  constexpr uint64_t kAhead = 32;
+  int64_t kept = 0;
+  for (uint64_t i = lo; i < hi; i++) {
+    if (!c.drop_all && i + kAhead < hi && c.keep[i + kAhead]) {
+      const int64_t ga = c.gidx[i + kAhead];
+      if (c.tomb_cat[ga])
+        __builtin_prefetch(c.run_ptrs[c.rid[i + kAhead]] + c.off_cat[ga] + 8);
     }
-    try {
-      threads.emplace_back(
-          [&c, &tied, w, lo, hi] { tied[w] = resolve_tie_blocks(c, lo, hi); });
-    } catch (const std::system_error&) {
-      tied[w] = resolve_tie_blocks(c, lo, hi);  // no thread to be had
+    if (!c.keep[i]) continue;  // an older version: gone already
+    const int64_t g = c.gidx[i];
+    if (!c.tomb_cat[g]) continue;
+    if (c.drop_all) {
+      c.keep[i] = 0;
+      continue;
     }
+    const uint64_t off = c.off_cat[g];
+    const uint32_t r = c.rid[i];
+    if (off + 16 > c.run_sizes[r]) return -1;
+    uint64_t ts;
+    std::memcpy(&ts, c.run_ptrs[r] + off + 8, 8);
+    if (ts < c.cutoff)
+      c.keep[i] = 0;
+    else
+      kept++;
   }
-  for (auto& t : threads) t.join();
-  int64_t total = 0;
-  for (const int64_t t : tied) {
-    if (t < 0) return -1;
-    total += t;
-  }
-  return total;
+  return kept;
+}
+
+}  // namespace
+
+extern "C" {
+
+// The tombstones of one decoded partition, after
+// dbeel_pipe_resolve_ties: of the entries ``keep`` still holds (a
+// key's newest version each), every tombstone (``tomb_cat`` by global
+// index) is decided where its record lies — dropped (keep = 0) if
+// ``drop_all``, else if its timestamp (the 8 bytes at offset 8 of the
+// record, compared as u64 like storage/compaction.py's
+// drop_tombstones_mask) is below ``cutoff``; one at or above the
+// cutoff stays, so that a replica that missed the delete cannot bring
+// the row back.  No timestamp column, no per-run pass.  Returns the
+// tombstones that stay, or -1 where an index column points a record's
+// header outside its run's buffer.
+int64_t dbeel_pipe_drop_tombstones(uint64_t n_p, const int64_t* gidx,
+                                   const uint32_t* rid,
+                                   const uint8_t* const* run_ptrs,
+                                   const uint64_t* run_sizes,
+                                   const uint64_t* off_cat,
+                                   const uint8_t* tomb_cat, int drop_all,
+                                   uint64_t cutoff, uint8_t* keep) {
+  const TombColumns c{gidx,    rid,      run_ptrs, run_sizes, off_cat,
+                      tomb_cat, drop_all, cutoff,   keep};
+  return over_slices(n_p, [&c](uint64_t lo, uint64_t hi) {
+    return drop_tombstones_slice(c, lo, hi);
+  });
 }
 
 }  // extern "C"

@@ -75,9 +75,9 @@ def _vs_heap(tmp_dir, idxs, keep_tomb=False, drop_before=None):
     real = pipeline_mod._decode
 
     def spy(lib, inputs, plan, part, *rest):
-        job, ties = real(lib, inputs, plan, part, *rest)
+        job, ties, tomb_kept = real(lib, inputs, plan, part, *rest)
         decoded.append((part, job, ties))
-        return job, ties
+        return job, ties, tomb_kept
 
     results = {}
     with pytest.MonkeyPatch.context() as m:

@@ -62,12 +62,19 @@ def _fits(compiled, in_flight: int = 1) -> int:
     return need
 
 
-def test_pipeline_exact_prefix_kernel_at_production_shape(one_chip):
+@pytest.mark.parametrize(
+    "k", [8, 64], ids=["major-10m", "neworder-64"]
+)
+def test_pipeline_exact_prefix_kernel_at_production_shape(one_chip, k):
     # (J, K, P) = (4, 8, 2^17): what ops/pipeline.py launches for the
-    # 10M-key, 8-run major compaction; two launches are kept in flight.
+    # 10M-key, 8-run major compaction where a partition takes the
+    # exact operand; (4, 64, 2^14): what neworder-64.merge launches for
+    # every one of its 58 partitions (dense ordered keys: a shift would
+    # collapse neighbouring order numbers).  Two launches are kept in
+    # flight.
     from dbeel_tpu.ops import bitonic, pipeline
 
-    j, k, p = pipeline._LAUNCH_BATCH, 8, pipeline._MAX_P2
+    j, p = pipeline._LAUNCH_BATCH, pipeline.max_partition_rows(k)
     assert k * p == pipeline._MAX_KP
     compiled = bitonic.merge_runs_prefix64_packed_batch_kernel.lower(
         _spec((j, k, p, 2), one_chip),
